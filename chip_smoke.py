@@ -1,0 +1,744 @@
+#!/usr/bin/env python
+"""Bring-up smoke test: the train and serve paths on ONE TPU process.
+
+Drives the repo's two main paths once, through the entry points a user
+would call, at the full width of models the repo supports:
+
+- **train**  — ``zoo.ResNet50`` 224x224, B=128, bf16, ``net.fit`` on the
+  default execution plan; loss band, loss decrease, device placement,
+  zero recompiles after step 1, HBM gauges, and the sync-honesty timing
+  (``block_until_ready`` on the donated step outputs vs a scalar fetch).
+- **flash**  — ``zoo.TextGenerationTransformer`` (V=2048, E=512, 6 layers,
+  4 heads of 128, rope, bf16) ``fit`` for 2 steps at T=1024: the Pallas
+  flash-attention forward and its custom VJP, asserted from the lowered
+  step (a Mosaic custom call, not the scan fallback).
+- **serve**  — ``GenerationEngine`` over that transformer with the default
+  ``PagedKVConfig(page_size=16)``: warmup, 12 staggered greedy requests,
+  decode on the Mosaic-compiled paged-attention kernel, zero compiles
+  after warmup, and two numeric checks against XLA read paths.
+- **multichip** — with >= 4 devices, ``ParallelWrapper`` allreduce over a
+  4-chip mesh (one process drives all four); otherwise ``not run``.
+
+Usage::
+
+    python chip_smoke.py                 # the chip check (exit 0 = pass)
+    python chip_smoke.py --kernels       # compile every other pallas_call
+    python chip_smoke.py --dry-run-cpu   # tiny shapes on CPU, never a PASS
+
+Output: one JSON line per phase, then a final summary line. The default
+mode refuses any backend but ``tpu`` (jax with libtpu and no chip drops
+to CPU with a warning — that must not pass) and no phase is wrapped in a
+handler that lets the run finish green: any exception or failed check is
+a non-zero exit. Timings printed here are observations of ONE run
+("smoke, one run"), not measurements. ``--kernels`` is the one reporting
+mode: it compiles each remaining kernel at its real shape, prints one
+line per kernel with the compiler's message, and exits non-zero if any
+failed.
+"""
+
+from __future__ import annotations
+
+import argparse
+import importlib.metadata
+import json
+import math
+import sys
+import time
+from dataclasses import dataclass
+
+import numpy as np
+
+
+@dataclass(frozen=True)
+class Sizes:
+    """Every size the phases use. ``CHIP`` is the contract; ``DRY`` only
+    proves the control flow on a CPU."""
+    image: int
+    classes: int
+    batch: int
+    train_warm_steps: int
+    train_timed_steps: int
+    vocab: int
+    embed: int
+    heads: int
+    layers: int
+    max_length: int
+    fit_batch: int
+    slots: int
+    requests: int
+    prompt_min: int
+    prompt_max: int
+    gen_steps: int
+    multichip_batch: int
+    multichip_steps: int
+    #: 4-chip vs 1-chip step-1 loss on the same global batch (first chip
+    #: run: 7.9153 vs 7.9147, 8e-5 relative; 2 rows per virtual CPU
+    #: device in the dry run are far noisier in bf16)
+    multichip_loss_rtol: float
+
+
+CHIP = Sizes(image=224, classes=1000, batch=128, train_warm_steps=3,
+             train_timed_steps=10, vocab=2048, embed=512, heads=4,
+             layers=6, max_length=1024, fit_batch=4, slots=8,
+             requests=12, prompt_min=8, prompt_max=200, gen_steps=32,
+             multichip_batch=512, multichip_steps=3,
+             multichip_loss_rtol=5e-3)
+DRY = Sizes(image=32, classes=10, batch=8, train_warm_steps=1,
+            train_timed_steps=2, vocab=64, embed=64, heads=2, layers=2,
+            max_length=64, fit_batch=2, slots=4, requests=4,
+            prompt_min=4, prompt_max=24, gen_steps=8,
+            multichip_batch=16, multichip_steps=2,
+            multichip_loss_rtol=5e-2)
+
+PAGE_SIZE = 16
+STAGGER_S = 0.05
+#: Nesterov momentum 0.9 at this rate drives the loss down on one
+#: repeated batch from a fresh init with no warm-up schedule (first chip
+#: run: 7.91 -> 3.60 in 24 steps)
+TRAIN_LR = 0.02
+#: fresh-init loss band, in nats relative to ln(classes). A fresh softmax
+#: head is never better than uniform by much, and the relu-init head over
+#: un-zeroed residual branches starts above it (first chip run: 7.91 for
+#: 1000 classes = +1.0, 7.53 for the 2048-token LM = -0.09; the tiny
+#: dry-run ResNet starts +2.2 above)
+FIRST_LOSS_BAND = (-0.5, 4.0)
+#: block_until_ready may not return earlier than this share of the
+#: scalar-fetch-synced time for the same steps
+SYNC_AGREE = 0.8
+#: bf16 attention outputs (|o| <~ 1) — a wrong page or mask is O(0.1+)
+KERNEL_ATOL = 3e-2
+#: a served greedy token must be the dense-XLA reference's argmax or a
+#: near-tie: within this many nats of the reference's best log-prob
+#: (first chip run: worst gap 0.009 over 384 tokens)
+TOKEN_LOGPROB_TOL = 0.05
+#: ... and most tokens must be the exact argmax (bf16 near-ties flip
+#: some: 376 of 384 agreed on the first chip run)
+TOKEN_ARGMAX_SHARE = 0.9
+
+
+class Run:
+    """Per-run context: the device stamp every line carries, the compile
+    counters, and the JSON printer."""
+
+    def __init__(self, dry: bool):
+        import jax
+        import jax.monitoring as jm
+
+        from deeplearning4j_tpu import monitoring
+        from deeplearning4j_tpu.monitoring import runtime
+
+        self.sz = DRY if dry else CHIP
+        devs = jax.devices()
+        self.device = devs[0]
+        self.stamp = {
+            "platform": self.device.platform,
+            "device_kind": self.device.device_kind,
+            "device_count": len(devs),
+            "jax": jax.__version__,
+            "jaxlib": importlib.metadata.version("jaxlib"),
+            "libtpu": importlib.metadata.version("libtpu"),
+        }
+        monitoring.ensure_started()
+        self.compiles = monitoring.global_registry().get(
+            runtime.COMPILE_COUNTER)
+        self.cache_events = {"hits": 0, "misses": 0}
+
+        def on_event(event, **kw):
+            if event == "/jax/compilation_cache/cache_hits":
+                self.cache_events["hits"] += 1
+            elif event == "/jax/compilation_cache/cache_misses":
+                self.cache_events["misses"] += 1
+
+        jm.register_event_listener(on_event)
+
+    def emit(self, phase: str, **fields) -> None:
+        print(json.dumps({"phase": phase, **fields, **self.stamp}),
+              flush=True)
+
+
+def check(cond: bool, what: str) -> None:
+    """A failed check ends the run: raise, never record-and-continue."""
+    if not cond:
+        raise AssertionError(f"chip_smoke check failed: {what}")
+
+
+def check_first_loss(loss: float, classes: int) -> None:
+    lo, hi = (math.log(classes) + b for b in FIRST_LOSS_BAND)
+    check(math.isfinite(loss) and lo <= loss <= hi,
+          f"fresh-init loss {loss:.3f} outside [{lo:.2f}, {hi:.2f}] "
+          f"around ln({classes})")
+
+
+def synthetic_images(sz: Sizes, batch: int, seed: int = 0):
+    rng = np.random.default_rng(seed)
+    x = rng.standard_normal((batch, 3, sz.image, sz.image)) \
+        .astype(np.float32)
+    y = np.zeros((batch, sz.classes), np.float32)
+    y[np.arange(batch), rng.integers(0, sz.classes, batch)] = 1.0
+    return x, y
+
+
+def build_resnet(sz: Sizes):
+    from deeplearning4j_tpu.nn.updater import Nesterovs
+    from deeplearning4j_tpu.zoo import ResNet50
+    net = ResNet50(num_classes=sz.classes, height=sz.image,
+                   width=sz.image, data_format="NHWC",
+                   updater=Nesterovs(TRAIN_LR, momentum=0.9)).init()
+    net.conf.dtype = "bfloat16"
+    return net
+
+
+# ----------------------------------------------------------------------
+# phase: native
+# ----------------------------------------------------------------------
+def phase_native(run: Run) -> None:
+    """How each native library was obtained on this machine. A fresh
+    checkout has no native/build/, so anything but "built" there means
+    the toolchain is missing and the numpy fallbacks are in use."""
+    from deeplearning4j_tpu.native import library_origins
+    run.emit("native", passed=True, libraries=library_origins())
+
+
+# ----------------------------------------------------------------------
+# phase: train
+# ----------------------------------------------------------------------
+def phase_train(run: Run) -> float:
+    """ResNet50 through ComputationGraph.fit. Returns the first-step
+    loss (the multichip phase compares against it)."""
+    import jax
+
+    from deeplearning4j_tpu import monitoring
+    from deeplearning4j_tpu.monitoring import runtime
+    from deeplearning4j_tpu.optimize.listeners import TrainingListener
+
+    sz = run.sz
+    net = build_resnet(sz)
+    x, y = synthetic_images(sz, sz.batch)
+
+    t0 = time.perf_counter()
+    net.fit(x, y, epochs=1, batch_size=sz.batch)
+    first_loss = float(net.score_value)
+    compile_s = time.perf_counter() - t0
+    compiles_after_first = run.compiles.total()
+    check_first_loss(first_loss, sz.classes)
+
+    net.fit(x, y, epochs=sz.train_warm_steps, batch_size=sz.batch)
+
+    class SyncStamp(TrainingListener):
+        """Stamp the clock when step `last` is known finished, by
+        block_until_ready on the step's donated outputs or by fetching
+        the scalar loss."""
+
+        def __init__(self, mode, last):
+            self.mode, self.last, self.t_end = mode, last, None
+
+        def iteration_done(self, model, iteration, score):
+            if iteration != self.last:
+                return
+            if self.mode == "block_until_ready":
+                jax.block_until_ready(
+                    (model.params, model.state, model.updater_state))
+            else:
+                float(score)
+            self.t_end = time.perf_counter()
+
+    ms_per_step = {}
+    n = sz.train_timed_steps
+    for mode in ("block_until_ready", "scalar_fetch"):
+        stamp = SyncStamp(mode, net.iteration_count + n - 1)
+        net.set_listeners(stamp)
+        t0 = time.perf_counter()
+        net.fit(x, y, epochs=n, batch_size=sz.batch)
+        check(stamp.t_end is not None, f"{mode} window never stamped")
+        ms_per_step[mode] = (stamp.t_end - t0) * 1e3 / n
+    net.set_listeners()
+    check(ms_per_step["block_until_ready"]
+          >= SYNC_AGREE * ms_per_step["scalar_fetch"],
+          f"block_until_ready returned early: {ms_per_step}")
+
+    last_loss = float(net.score_value)
+    steps = net.iteration_count
+    check(math.isfinite(last_loss) and last_loss < first_loss,
+          f"loss did not fall on one repeated batch: {first_loss:.3f} "
+          f"-> {last_loss:.3f} after {steps} steps at lr {TRAIN_LR}")
+    check(run.compiles.total() == compiles_after_first,
+          f"recompiled after step 1: {compiles_after_first} -> "
+          f"{run.compiles.total()}")
+    leaves = jax.tree_util.tree_leaves((net.params, net.updater_state))
+    check(all(leaf.devices() == {run.device} for leaf in leaves),
+          f"param/updater leaves not all on {run.device}")
+
+    obs = {}
+    if run.device.platform == "tpu":
+        ms = run.device.memory_stats()
+        check(ms is not None and ms["peak_bytes_in_use"] > 0
+              and ms["bytes_limit"] > 0, f"memory_stats() = {ms}")
+        runtime.refresh()
+        gauge = monitoring.global_registry().get(
+            "dl4jtpu_device_peak_bytes_in_use").value(
+                device=f"tpu:{run.device.id}")
+        check(gauge >= ms["peak_bytes_in_use"] > 0,
+              f"peak-bytes gauge {gauge} vs {ms['peak_bytes_in_use']}")
+        # on this runtime a compiled program's scratch is counted under
+        # bytes_reserved, not bytes_in_use: the high-water mark of the
+        # step is the sum of the two peaks
+        obs = {"peak_bytes_in_use": ms["peak_bytes_in_use"],
+               "peak_bytes_reserved": ms["peak_bytes_reserved"],
+               "hbm_limit_bytes": ms["bytes_limit"]}
+    run.emit("train", passed=True, model="ResNet50", image=sz.image,
+             batch=sz.batch, dtype="bfloat16", lr=TRAIN_LR, steps=steps,
+             first_loss=round(first_loss, 4),
+             last_loss=round(last_loss, 4),
+             compiles_after_step1=0, label="smoke, one run",
+             first_step_incl_compile_s=round(compile_s, 2),
+             ms_per_step={k: round(v, 2) for k, v in ms_per_step.items()},
+             sync_agree=round(ms_per_step["block_until_ready"]
+                              / ms_per_step["scalar_fetch"], 3), **obs)
+    return first_loss
+
+
+# ----------------------------------------------------------------------
+# phase: flash (transformer fit) + serve
+# ----------------------------------------------------------------------
+def build_transformer(sz: Sizes):
+    from deeplearning4j_tpu.zoo import TextGenerationTransformer
+    net = TextGenerationTransformer(
+        vocab_size=sz.vocab, embed_dim=sz.embed, n_heads=sz.heads,
+        n_layers=sz.layers, max_length=sz.max_length,
+        positional="rope").init()
+    net.conf.dtype = "bfloat16"
+    return net
+
+
+def one_hot_tokens(ids: np.ndarray, vocab: int) -> np.ndarray:
+    """[B, T] token ids -> the zoo transformer's [B, V, T] input."""
+    b, t = ids.shape
+    x = np.zeros((b, vocab, t), np.float32)
+    x[np.arange(b)[:, None], ids, np.arange(t)[None, :]] = 1.0
+    return x
+
+
+def phase_flash(run: Run, net) -> None:
+    """Two fit steps at T = max_length: on a TPU any non-streaming
+    attention call takes the Pallas flash kernel and its custom VJP."""
+    import jax
+    import jax.numpy as jnp
+
+    from deeplearning4j_tpu.resilience.sentinel import effective_policy
+
+    sz = run.sz
+    rng = np.random.default_rng(1)
+    ids = rng.integers(0, sz.vocab, (sz.fit_batch, sz.max_length))
+    x = one_hot_tokens(ids, sz.vocab)
+    y = np.roll(x, -1, axis=2)
+    t0 = time.perf_counter()
+    net.fit(x, y, epochs=2, batch_size=sz.fit_batch)
+    loss = float(net.score_value)
+    fit_s = time.perf_counter() - t0
+    check_first_loss(loss, sz.vocab)
+
+    # the step fit() just ran, lowered again on the same arguments
+    step = net._get_train_step(False, effective_policy(net))
+    lowered = step.lower(
+        net.params, net.state, net.updater_state,
+        {net.conf.network_inputs[0]: jnp.asarray(x)},
+        {net.conf.network_outputs[0]: jnp.asarray(y)},
+        jax.random.PRNGKey(0), None, None).as_text()
+    mosaic_calls = lowered.count("tpu_custom_call")
+    if run.device.platform == "tpu":
+        # the forward, dq and dk/dv kernels
+        check(mosaic_calls >= 3,
+              f"lowered train step holds {mosaic_calls} Mosaic custom "
+              f"calls; expected the flash kernels, got the scan fallback")
+    run.emit("flash", passed=True, model="TextGenerationTransformer",
+             T=sz.max_length, batch=sz.fit_batch, steps=2,
+             loss=round(loss, 4), mosaic_custom_calls=mosaic_calls,
+             attention_path=("pallas-flash" if mosaic_calls
+                             else "scan (no TPU backend)"),
+             label="smoke, one run",
+             two_steps_incl_compile_s=round(fit_s, 2))
+
+
+def check_paged_kernel(run: Run, interpret: bool) -> float:
+    """The paged-attention kernel against the dense-gather XLA
+    reference, at the serve phase's pool shape, on a shuffled page table
+    with ragged lengths — a wrong page or mask shows as O(0.1+)."""
+    import jax.numpy as jnp
+
+    from deeplearning4j_tpu.serving.paged_kernel import (
+        paged_attention, paged_ref_attention)
+
+    sz = run.sz
+    d = sz.embed // sz.heads
+    n_max = sz.max_length // PAGE_SIZE
+    n_pages = sz.slots * n_max + 1
+    rng = np.random.default_rng(2)
+
+    def bf16(*shape):
+        return jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
+
+    q = bf16(sz.slots, sz.heads, 1, d)
+    k_pool = bf16(n_pages, sz.heads, PAGE_SIZE, d)
+    v_pool = bf16(n_pages, sz.heads, PAGE_SIZE, d)
+    table = jnp.asarray(
+        rng.permutation(np.arange(1, n_pages)).reshape(sz.slots, n_max),
+        jnp.int32)
+    lengths = jnp.asarray(
+        rng.integers(1, sz.max_length + 1, sz.slots), jnp.int32)
+    got = paged_attention(q, k_pool, v_pool, table, lengths,
+                          query_width=1, interpret=interpret)
+    want = paged_ref_attention(q, k_pool, v_pool, table, lengths,
+                               query_width=1)
+    err = float(jnp.max(jnp.abs(got.astype(jnp.float32)
+                                - want.astype(jnp.float32))))
+    check(err <= KERNEL_ATOL,
+          f"paged kernel vs XLA reference: max abs err {err}")
+    return err
+
+
+def check_tokens_against_dense(net, outs, prompts, sz: Sizes) -> dict:
+    """Teacher-force every served sequence through the net's DENSE
+    streaming path (``rnn_time_step`` — contiguous XLA KV cache, no page
+    table, no Pallas) in one left-padded bucketed dispatch, and require
+    each served greedy token to be that reference's argmax or within
+    TOKEN_LOGPROB_TOL nats of it. Identical token streams are not
+    demanded: bf16 argmax near-ties legitimately flip."""
+    bucket = 1
+    while bucket < sz.prompt_max + sz.gen_steps:
+        bucket *= 2
+    bucket = min(bucket, sz.max_length)
+    worst, exact, total = 0.0, 0, 0
+    for ids, prompt in zip(outs, prompts):
+        fed = np.asarray(ids[:-1])
+        pad = bucket - len(fed)
+        x = np.zeros((1, sz.vocab, bucket), np.float32)
+        x[:, :, pad:] = one_hot_tokens(fed[None, :], sz.vocab)
+        net.rnn_clear_previous_state()
+        probs = np.asarray(net.rnn_time_step(x, pad_left=pad))[0] \
+            .astype(np.float64)
+        for i in range(len(prompt), len(ids)):
+            logp = np.log(probs[:, pad + i - 1] + 1e-30)
+            gap = float(logp.max() - logp[ids[i]])
+            worst = max(worst, gap)
+            exact += int(gap == 0.0)
+            total += 1
+    net.rnn_clear_previous_state()
+    check(worst <= TOKEN_LOGPROB_TOL,
+          f"a served token sits {worst:.3f} nats below the dense-XLA "
+          f"reference's best (tolerance {TOKEN_LOGPROB_TOL})")
+    check(exact >= TOKEN_ARGMAX_SHARE * total,
+          f"only {exact}/{total} served tokens are the reference argmax")
+    return {"tokens_checked": total, "argmax_agree": exact,
+            "worst_logprob_gap": round(worst, 4)}
+
+
+def phase_serve(run: Run, net) -> None:
+    from deeplearning4j_tpu.nn.conf.layers import paged_decode_impl
+    from deeplearning4j_tpu.serving import GenerationEngine, PagedKVConfig
+
+    sz = run.sz
+    on_tpu = run.device.platform == "tpu"
+    # everything default on the chip; the CPU dry run selects the same
+    # kernel explicitly, in interpret mode
+    paging = (PagedKVConfig(page_size=PAGE_SIZE) if on_tpu else
+              PagedKVConfig(page_size=PAGE_SIZE, decode_impl="pallas",
+                            kernel_interpret=True))
+    kernel_err = check_paged_kernel(run, interpret=not on_tpu)
+
+    rng = np.random.default_rng(3)
+    lengths = np.linspace(sz.prompt_min, sz.prompt_max,
+                          sz.requests).astype(int)
+    prompts = [[int(t) for t in rng.integers(1, sz.vocab, n)]
+               for n in lengths]
+    eng = GenerationEngine(net, sz.vocab, slots=sz.slots, paging=paging)
+    try:
+        t0 = time.perf_counter()
+        eng.warmup(max_prompt_len=sz.prompt_max)
+        warmup_s = time.perf_counter() - t0
+        compiles_after_warmup = run.compiles.total()
+        eng.start()
+        t0 = time.perf_counter()
+        handles = []
+        for i, p in enumerate(prompts):
+            while time.perf_counter() < t0 + i * STAGGER_S:
+                time.sleep(0.001)
+            handles.append(eng.submit(p, steps=sz.gen_steps, top_k=1,
+                                      rng=np.random.default_rng(i)))
+        outs = [h.result(timeout=600) for h in handles]
+        serve_s = time.perf_counter() - t0
+        health = eng.health()
+    finally:
+        eng.shutdown()
+
+    decode_path = health["kv_traffic"]["decode_path"]
+    check(decode_path == "direct-pallas", f"decode_path {decode_path!r}")
+    check(paged_decode_impl() == ("pallas", not on_tpu),
+          f"live paged decode impl {paged_decode_impl()} — on a TPU the "
+          f"kernel must be Mosaic-compiled, not interpreted")
+    for out, p in zip(outs, prompts):
+        check(len(out) == len(p) + sz.gen_steps and out[:len(p)] == p,
+              f"request of {len(p)} tokens returned {len(out)} ids")
+    check(run.compiles.total() == compiles_after_warmup,
+          f"compiled after warmup(): {compiles_after_warmup} -> "
+          f"{run.compiles.total()}")
+    tokens = check_tokens_against_dense(net, outs, prompts, sz)
+    run.emit("serve", passed=True, model="TextGenerationTransformer",
+             vocab=sz.vocab, embed=sz.embed, heads=sz.heads,
+             layers=sz.layers, max_length=sz.max_length, slots=sz.slots,
+             page_size=PAGE_SIZE, requests=sz.requests,
+             prompt_lengths=[int(n) for n in lengths],
+             gen_steps=sz.gen_steps, decode_path=decode_path,
+             kernel=("mosaic" if on_tpu else "interpret"),
+             compiles_after_warmup=0,
+             kernel_vs_xla_max_abs_err=round(kernel_err, 5),
+             kernel_atol=KERNEL_ATOL, **tokens,
+             token_logprob_tol=TOKEN_LOGPROB_TOL,
+             label="smoke, one run", warmup_incl_compile_s=round(warmup_s, 2),
+             serve_wall_s=round(serve_s, 3),
+             decode_dispatch=health["decode_dispatch"])
+
+
+# ----------------------------------------------------------------------
+# phase: multichip
+# ----------------------------------------------------------------------
+def phase_multichip(run: Run, single_chip_first_loss: float) -> None:
+    import jax
+
+    from deeplearning4j_tpu.parallel.mesh import make_mesh
+    from deeplearning4j_tpu.parallel.wrapper import ParallelWrapper
+
+    devs = jax.devices()
+    if len(devs) < 4:
+        run.emit("multichip", passed=None,
+                 status=f"not run: {len(devs)} device")
+        return
+    sz = run.sz
+    devs = devs[:4]
+    mesh = make_mesh(devices=devs)
+
+    # (a) step-1 loss on the train phase's own global batch: the same
+    # init, the same rows, a quarter on each chip
+    net = build_resnet(sz)
+    pw = ParallelWrapper(net, mesh=mesh, training_mode="allreduce")
+    x, y = synthetic_images(sz, sz.batch)
+    pw.fit(x, y, epochs=1, batch_size=sz.batch)
+    loss4 = float(net.score_value)
+    check(abs(loss4 - single_chip_first_loss)
+          <= sz.multichip_loss_rtol * abs(single_chip_first_loss),
+          f"4-chip step-1 loss {loss4:.4f} vs single-chip "
+          f"{single_chip_first_loss:.4f}")
+
+    # (b) a few steps at the multichip global batch
+    xb, yb = synthetic_images(sz, sz.multichip_batch, seed=4)
+    pw.fit(xb, yb, epochs=sz.multichip_steps,
+           batch_size=sz.multichip_batch)
+    last = float(net.score_value)
+    check(math.isfinite(last), f"multichip loss {last}")
+
+    shard = pw._shard_batch(xb)     # the placement fit() gives a batch
+    per_dev = {s.device: s.data.shape[0] for s in shard.addressable_shards}
+    check(per_dev == {d: sz.multichip_batch // 4 for d in devs},
+          f"batch rows per device: {per_dev}")
+    for leaf in jax.tree_util.tree_leaves(net.params):
+        check(leaf.sharding.is_fully_replicated
+              and {s.device for s in leaf.addressable_shards} == set(devs),
+              f"param leaf {leaf.shape} not replicated on all four: "
+              f"{leaf.sharding}")
+    mem = {}
+    if devs[0].platform == "tpu":
+        for d in devs:
+            ms = d.memory_stats()
+            check(ms is not None and ms["bytes_in_use"] > 0,
+                  f"{d}: memory_stats() = {ms}")
+            mem[str(d.id)] = {k: ms[k] for k in (
+                "bytes_in_use", "peak_bytes_in_use", "peak_bytes_reserved")}
+    run.emit("multichip", passed=True, devices=4,
+             global_batch=sz.multichip_batch,
+             rows_per_device=sz.multichip_batch // 4,
+             steps=sz.multichip_steps,
+             step1_loss_4chip=round(loss4, 4),
+             step1_loss_1chip=round(single_chip_first_loss, 4),
+             loss_rtol=sz.multichip_loss_rtol, last_loss=round(last, 4),
+             params_replicated=True, per_device_memory=mem)
+
+
+# ----------------------------------------------------------------------
+# --kernels: compile every pallas_call the smoke does not reach
+# ----------------------------------------------------------------------
+def kernel_probes():
+    """``(name, thunk)`` per remaining kernel at its real shape; each
+    thunk compiles and runs once and returns arrays to block on."""
+    import jax
+    import jax.numpy as jnp
+
+    from deeplearning4j_tpu.nn.layers.fused import bn_act_conv1x1
+    from deeplearning4j_tpu.nn.layers.pallas_attention import (
+        flash_attention)
+    from deeplearning4j_tpu.nn.layers.pallas_kernels import (
+        pallas_lstm_recurrence)
+    from deeplearning4j_tpu.serving.paged_kernel import paged_attention
+    from deeplearning4j_tpu.tuning.calibrate import training_kernel_probes
+
+    rng = np.random.default_rng(5)
+
+    def arr(*shape, dtype=jnp.bfloat16, scale=1.0):
+        return jnp.asarray(rng.standard_normal(shape) * scale, dtype)
+
+    # the 16 ResNet50 bottleneck blocks (8 distinct shapes) + the stem,
+    # fwd+bwd at B=128 bf16 — the shapes execution_plan="fused" runs
+    net = build_resnet(CHIP)
+    for key, kernel, _ in training_kernel_probes(net, batch_size=CHIP.batch):
+        yield f"fused:{key}", kernel
+
+    def flash_long():
+        q, k, v = (arr(1, 4, 8192, 128) for _ in range(3))
+
+        def loss(q, k, v):
+            return jnp.sum(flash_attention(q, k, v, causal=True)
+                           .astype(jnp.float32))
+        return jax.jit(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v)
+    yield "flash_attention fwd+bwd T=8192 D=128 bf16", flash_long
+
+    def flash_wide_f32():
+        # the largest blocks the shape gate admits: float32 at D=256
+        q, k, v = (arr(1, 4, 2048, 256, dtype=jnp.float32)
+                   for _ in range(3))
+
+        def loss(q, k, v):
+            return jnp.sum(flash_attention(q, k, v, causal=True))
+        return jax.jit(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v)
+    yield "flash_attention fwd+bwd T=2048 D=256 float32", flash_wide_f32
+
+    def paged_int8():
+        ps, d, hkv = 32, 128, CHIP.heads
+        n_max = CHIP.max_length // ps
+        pages = CHIP.slots * n_max + 1
+
+        def pool():
+            return jnp.asarray(
+                rng.integers(-127, 128, (pages, hkv, ps, d)), jnp.int8)
+
+        def scales():
+            return jnp.asarray(
+                2.0 ** rng.integers(-8, -4, (pages, hkv)), jnp.float32)
+        table = jnp.asarray(rng.permutation(np.arange(1, pages))
+                            .reshape(CHIP.slots, n_max), jnp.int32)
+        lengths = jnp.asarray(rng.integers(1, CHIP.max_length + 1,
+                                           CHIP.slots), jnp.int32)
+        return paged_attention(arr(CHIP.slots, hkv, 1, d), pool(), pool(),
+                               table, lengths, query_width=1,
+                               k_scales=scales(), v_scales=scales())
+    yield (f"paged_attention int8 S={CHIP.slots} ps=32 D=128 "
+           f"L={CHIP.max_length}"), paged_int8
+
+    def bn_conv():
+        x = arr(CHIP.batch, 56, 56, 64)
+        w = arr(256, 64, 1, 1, scale=0.1)
+        ones, zeros = jnp.ones((64,), jnp.float32), \
+            jnp.zeros((64,), jnp.float32)
+
+        def loss(x, w):
+            out, _, _ = bn_act_conv1x1(x, ones, zeros, zeros, ones, w,
+                                       None, train=True,
+                                       data_format="NHWC", use_pallas=True)
+            return jnp.sum(out.astype(jnp.float32))
+        return jax.jit(jax.grad(loss, argnums=(0, 1)))(x, w)
+    yield "fused.bn_act_conv1x1 fwd+bwd [128,56,56,64]->256 bf16", bn_conv
+
+    def lstm():
+        t, n, h = 256, 256, 256      # bench_all's lstm_train shape
+        return pallas_lstm_recurrence(
+            arr(t, n, 4 * h, scale=0.1), arr(h, 4 * h, scale=0.05),
+            arr(n, h, scale=0.1), arr(n, h, scale=0.1))
+    yield "pallas_lstm_recurrence T=256 N=256 H=256 bf16", lstm
+
+
+def mode_kernels(run: Run) -> int:
+    """Reporting mode: every kernel is tried, every failure printed with
+    the compiler's message; exit non-zero if any failed."""
+    import jax
+    import jax.numpy as jnp
+
+    failed = 0
+    for name, thunk in kernel_probes():
+        t0 = time.perf_counter()
+        try:
+            out = jax.block_until_ready(thunk())
+            finite = all(
+                bool(jnp.all(jnp.isfinite(leaf.astype(jnp.float32))))
+                for leaf in jax.tree_util.tree_leaves(out))
+            status = {"compiled": True, "finite": finite}
+            failed += not finite
+        except Exception as e:  # noqa: BLE001 — this mode's job is the list
+            failed += 1
+            status = {"compiled": False,
+                      "error": f"{type(e).__name__}: {e}"[:1500]}
+        run.emit("kernel", kernel=name,
+                 seconds=round(time.perf_counter() - t0, 2), **status)
+    run.emit("kernels", passed=failed == 0, failed=failed)
+    return 1 if failed else 0
+
+
+# ----------------------------------------------------------------------
+def main(argv=None) -> int:
+    p = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    p.add_argument("--kernels", action="store_true",
+                   help="compile each remaining pallas_call at its real "
+                        "shape (builder's mode; TPU only)")
+    p.add_argument("--dry-run-cpu", action="store_true",
+                   help="tiny shapes on a CPU backend to prove the "
+                        "control flow; never prints a chip PASS")
+    args = p.parse_args(argv)
+    if args.kernels and args.dry_run_cpu:
+        p.error("--kernels needs the chip; it has no dry run")
+
+    # the repo first: in a directory holding only this file the import
+    # fails before anything is printed
+    from deeplearning4j_tpu.util.compile_cache import (
+        configure_compile_cache)
+    cache_dir = configure_compile_cache()
+    t_start = time.perf_counter()
+    run = Run(dry=args.dry_run_cpu)
+
+    platform = run.stamp["platform"]
+    want = "cpu" if args.dry_run_cpu else "tpu"
+    run.emit("device", passed=platform == want, compile_cache_dir=cache_dir,
+             dry_run=args.dry_run_cpu)
+    if platform != want:
+        print(f"chip_smoke: backend is {platform!r}, need {want!r}"
+              + ("" if args.dry_run_cpu else
+                 " (no accelerator; --dry-run-cpu exists for a CPU "
+                 "control-flow run and never passes)"), file=sys.stderr)
+        return 2
+
+    if args.kernels:
+        return mode_kernels(run)
+
+    phase_native(run)
+    first_loss = phase_train(run)
+    lm = build_transformer(run.sz)
+    phase_flash(run, lm)
+    phase_serve(run, lm)
+    phase_multichip(run, first_loss)
+
+    summary = {
+        "device": {"platform": platform,
+                   "kind": run.stamp["device_kind"],
+                   "count": run.stamp["device_count"]},
+        "versions": {k: run.stamp[k] for k in ("jax", "jaxlib", "libtpu")},
+        "compile_cache": {"dir": cache_dir, **run.cache_events},
+        "compiles_total": int(run.compiles.total()),
+        "wall_s": round(time.perf_counter() - t_start, 1),
+        "claim": None,
+    }
+    if args.dry_run_cpu:
+        # no "ok" key: a dry run can never be read as a chip PASS
+        print(json.dumps({"dry_run": True, "phases_passed": True,
+                          **summary}), flush=True)
+    else:
+        print(json.dumps({"ok": True, **summary}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -5,8 +5,7 @@ TPUs execute f64 in slow software emulation (or jax silently truncates
 to f32 with `jax_enable_x64` off, masking the intent). Either way a
 float64 literal or dtype in a module that builds jax computations is a
 hazard — except in the finite-difference gradient checker, whose whole
-point is f64 reference arithmetic, and the central x64 shim in
-util/jax_compat that gates it.
+point is f64 reference arithmetic (scoped by ``jax.enable_x64``).
 
 The int8 rule is the ISSUE 18 companion: a quantized KV pool hands
 int8 arrays to dispatch code, and jax's type promotion silently widens
@@ -26,7 +25,7 @@ from typing import Iterator
 from deeplearning4j_tpu.analysis.core import (
     Finding, ModuleInfo, Rule, SEVERITY_WARNING)
 
-_EXEMPT_PATH_PARTS = ("gradient_check", "jax_compat")
+_EXEMPT_PATH_PARTS = ("gradient_check",)
 _F64_OWNERS = ("numpy", "jax.numpy", "jax")
 
 
@@ -67,9 +66,9 @@ class DtypePromotionRule(Rule):
                         and node.args[0].value == "jax_enable_x64":
                     yield self.finding(
                         mod, node,
-                        "jax_enable_x64 toggled outside util/jax_compat: "
-                        "route through the central shim so the flag can't "
-                        "leak into production paths")
+                        "jax_enable_x64 toggled process-wide: scope f64 "
+                        "with the jax.enable_x64 context manager so the "
+                        "flag can't leak into production paths")
                 elif isinstance(node.func, ast.Attribute) \
                         and node.func.attr == "astype" and node.args \
                         and isinstance(node.args[0], ast.Constant) \

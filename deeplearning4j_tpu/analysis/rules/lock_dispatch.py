@@ -3,7 +3,7 @@
 The serving/parallel hot paths hand work between threads under
 ``threading.Lock``s. A jitted dispatch — or worse, a blocking device
 sync — made while HOLDING such a lock couples every other waiter to
-the device's latency: a stalled TPU call (dead tunnel, preempted core,
+the device's latency: a stalled TPU call (lost device, preempted core,
 a multi-second compile) under the engine lock freezes ``submit()``,
 health probes, and metrics scrapes along with it, turning one slow
 dispatch into a process-wide stall. The sanctioned shapes are (a)

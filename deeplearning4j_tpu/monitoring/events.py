@@ -57,7 +57,7 @@ EVENTS_DROPPED = "dl4jtpu_events_dropped_total"
 DEFAULT_CAPACITY = 2048
 
 #: event categories in use across the stack (open vocabulary — these
-#: are the taxonomy ARCHITECTURE.md documents, not an enum gate):
+#: are the categories ARCHITECTURE.md documents, not an enum gate):
 #: ``serving`` (engine lifecycle: rebuild/escalate/break/drain/shed/
 #: early_reject/brownout), ``fleet`` (router: replica_join/replica_dead/
 #: migration/rebalance/scale_out/scale_in/autoscale/generation),

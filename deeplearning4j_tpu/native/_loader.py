@@ -1,6 +1,7 @@
 """Shared native-library loader: locate the .so under native/build/,
-rebuild via make when the source is newer, fall back to None (callers use
-numpy fallbacks) when the toolchain is unavailable."""
+rebuild via make when it is missing or the source is newer, fall back to
+None (callers use numpy fallbacks, and a WARNING says so) when the
+toolchain is unavailable."""
 
 from __future__ import annotations
 
@@ -9,16 +10,25 @@ import logging
 import os
 import subprocess
 import threading
-from typing import Callable, Optional
+from typing import Callable, Dict, Optional
 
 log = logging.getLogger(__name__)
 
 NATIVE_DIR = os.path.join(
     os.path.dirname(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__)))), "native")
+_BUILD_DIR = os.path.join(NATIVE_DIR, "build")
 
 _build_lock = threading.Lock()
 _build_attempted = False
+_built_here: set = set()     # .so names this process's make (re)wrote
+
+
+def _so_mtimes() -> Dict[str, float]:
+    if not os.path.isdir(_BUILD_DIR):
+        return {}
+    return {n: os.path.getmtime(os.path.join(_BUILD_DIR, n))
+            for n in os.listdir(_BUILD_DIR)}
 
 
 class NativeLib:
@@ -26,11 +36,17 @@ class NativeLib:
 
     def __init__(self, so_name: str, src_name: str,
                  configure: Callable[[ctypes.CDLL], None]):
-        self.so_path = os.path.join(NATIVE_DIR, "build", so_name)
+        self.so_name = so_name
+        self.so_path = os.path.join(_BUILD_DIR, so_name)
         self.src_path = os.path.join(NATIVE_DIR, "src", src_name)
         self._configure = configure
         self._lib: Optional[ctypes.CDLL] = None
         self._lock = threading.Lock()
+        #: how load() obtained the library — "built" (make compiled it
+        #: from native/src in this process), "prebuilt" (the .so was
+        #: already on disk) or "unavailable" (numpy fallbacks); None
+        #: until load() has run
+        self.origin: Optional[str] = None
 
     def load(self) -> Optional[ctypes.CDLL]:
         global _build_attempted
@@ -47,23 +63,30 @@ class NativeLib:
                 with _build_lock:
                     if not _build_attempted:
                         _build_attempted = True
+                        before = _so_mtimes()
                         try:
                             subprocess.run(["make", "-C", NATIVE_DIR],
                                            check=True, capture_output=True,
                                            timeout=120)
-                        except Exception as e:  # noqa: BLE001
-                            log.info("native build unavailable (%s); "
-                                     "using numpy fallbacks", e)
+                        except (OSError, subprocess.SubprocessError) as e:
+                            log.warning("native build unavailable (%s); "
+                                        "using numpy fallbacks", e)
+                        _built_here.update(
+                            n for n, m in _so_mtimes().items()
+                            if before.get(n) != m)
+            self.origin = "unavailable"
             if not os.path.exists(self.so_path):
                 return None
             try:
                 lib = ctypes.CDLL(self.so_path)
             except OSError as e:
-                log.info("native lib %s load failed (%s); numpy fallbacks",
-                         self.so_path, e)
+                log.warning("native lib %s load failed (%s); numpy "
+                            "fallbacks", self.so_path, e)
                 return None
             self._configure(lib)
             self._lib = lib
+            self.origin = ("built" if self.so_name in _built_here
+                           else "prebuilt")
             return self._lib
 
     def available(self) -> bool:

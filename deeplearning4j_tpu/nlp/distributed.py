@@ -23,9 +23,8 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from deeplearning4j_tpu.util.jax_compat import shard_map
 
 
 def make_distributed_glove_step(mesh: Mesh, data_axis: str = "data"):

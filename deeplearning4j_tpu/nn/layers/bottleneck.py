@@ -67,6 +67,14 @@ from jax.experimental.pallas import tpu as pltpu
 #: whole-image blocks + weights inside a conservative VMEM budget
 _VMEM_BUDGET = 12 * 1024 * 1024
 
+#: what Mosaic may actually use per kernel. The budget model above counts
+#: each block once at its logical size; the compiler double-buffers every
+#: BlockSpec operand and pads 64-channel minor dims to the 128 lanes, so
+#: a block the model admits at 12 MiB can need ~4x that — over the 16 MiB
+#: default scoped limit (measured on v5e: the 56x56x64 3x3 backward asked
+#: for 23.7 MiB). v5e has 128 MiB of VMEM.
+_COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=64 * 1024 * 1024)
+
 
 class BnParams(NamedTuple):
     gamma: jax.Array          # [C]
@@ -277,6 +285,7 @@ def _fwd_conv_stats(x, sc, bb, w, *, taps: int, act: str,
         out_shape=[jax.ShapeDtypeStruct((n, ho, wo, k), x.dtype),
                    jax.ShapeDtypeStruct((1, k), jnp.float32),
                    jax.ShapeDtypeStruct((1, k), jnp.float32)],
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
     )(x, sc[None, :], bb[None, :], w)
     return out, s1[0], s2[0]
@@ -535,6 +544,7 @@ def _bwd_stage(yk, g, yprev, w, aff_k, aff_p, *, taps, act_prev, gmode,
         out_shape=[jax.ShapeDtypeStruct((n, h, wd, c), yprev.dtype),
                    jax.ShapeDtypeStruct(dw_shape, jnp.float32),
                    jax.ShapeDtypeStruct((2, c), jnp.float32)],
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
     )(yk, g, yprev, w, aff_k, aff_p)
     return dz, dw, sums

@@ -50,6 +50,13 @@ LN2 = float(np.log(2.0))       # exp2((s-m)*log2e) == exp(s-m) exactly, but
 DEFAULT_BLOCK_Q = 1024
 DEFAULT_BLOCK_K = 1024
 
+# 1024x1024 blocks hold [bq, bk] fp32 score/probability temporaries of
+# 4 MiB each; with float32 inputs at D=256 the kernels need more than
+# Mosaic's 16 MiB default scoped limit (measured on v5e, jax 0.9.0: the
+# forward and both backward kernels are refused at T >= 2048 without
+# this). v5e has 128 MiB of VMEM.
+_COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=64 * 1024 * 1024)
+
 
 def _causal_needed(i, j, bq, bk, window=None, q_offset=0):
     """Is KV block j visible to any query in Q block i? (block-skip test:
@@ -310,6 +317,7 @@ def _run_bwd_kernels(q, k, v, key_mask, do, lse, d_eff, *, causal, bq, bk,
         out_specs=_qkv_spec(bq, D, 2),
         out_shape=jax.ShapeDtypeStruct((B, H, T, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
     )(q, k, v, key_mask, do, lse, d_eff)
 
@@ -337,6 +345,7 @@ def _run_bwd_kernels(q, k, v, key_mask, do, lse, d_eff, *, causal, bq, bk,
                    jax.ShapeDtypeStruct((B, H, Tk, D), v.dtype)],
         scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
                         pltpu.VMEM((bk, D), jnp.float32)],
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
     )(q, k, v, key_mask, do, lse, d_eff)
     return dq, dk, dv
@@ -362,6 +371,7 @@ def _flash_fwd(q, k, v, key_mask, causal, bq, bk, first_pad, user_mask,
         scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32),
                         pltpu.VMEM((bq, 128), jnp.float32),
                         pltpu.VMEM((bq, 128), jnp.float32)],
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
     )(q, k, v, key_mask)
     return o, (q, k, v, key_mask, o, lse)

@@ -24,9 +24,8 @@ from typing import Dict
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-from deeplearning4j_tpu.util.jax_compat import shard_map
 
 
 def init_moe_params(key, embed_dim: int, ffn_dim: int, n_experts: int,

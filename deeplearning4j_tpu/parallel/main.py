@@ -51,7 +51,10 @@ def main(argv=None) -> int:
         CSVRecordReader, RecordReaderDataSetIterator)
     from deeplearning4j_tpu.parallel.wrapper import ParallelWrapper
     from deeplearning4j_tpu.util import model_serializer
+    from deeplearning4j_tpu.util.compile_cache import (
+        configure_compile_cache)
 
+    configure_compile_cache()
     net = model_serializer.restore_model(args.model)
     it = RecordReaderDataSetIterator(
         CSVRecordReader(args.data), batch_size=args.batch_size,

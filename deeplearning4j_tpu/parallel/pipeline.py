@@ -24,9 +24,8 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-from deeplearning4j_tpu.util.jax_compat import shard_map
 
 
 def shard_stage_params(stage_params: list, mesh: Mesh, axis: str = "pipe"):

@@ -28,9 +28,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from deeplearning4j_tpu.util.jax_compat import shard_map
 
 NEG_INF = -1e30  # large-negative instead of -inf: keeps exp() gradients clean
 

@@ -25,9 +25,8 @@ from typing import Dict
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-from deeplearning4j_tpu.util.jax_compat import shard_map
 
 from deeplearning4j_tpu.parallel.sequence import blockwise_attention
 

@@ -34,9 +34,9 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from deeplearning4j_tpu.util.jax_compat import shard_map
 from deeplearning4j_tpu.datasets.dataset import DataSet
 from deeplearning4j_tpu.datasets.iterators import ArrayDataSetIterator, AsyncDataSetIterator
 from deeplearning4j_tpu.monitoring.listener import (

@@ -407,7 +407,9 @@ class GenerationEngine:
                     # PERF.md: "record the crossover so auto can learn
                     # it". No entry → the kernel (the PR 10 default).
                     ok = all(paged_attention_supported(
-                        (0, 0, self._ps, l.n_out // l.n_heads), 1,
+                        (self._pool.total_pages,
+                         getattr(l, "n_kv_heads", None) or l.n_heads,
+                         self._ps, l.n_out // l.n_heads), 1,
                         kv_dtype=self._kv_dtype)
                         for l in kv_layers)
                     eligible = jax.default_backend() == "tpu" and ok

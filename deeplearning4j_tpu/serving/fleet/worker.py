@@ -114,6 +114,9 @@ def main(argv=None) -> int:
     args = p.parse_args(argv)
 
     # import late so --help stays instant even with jax in the builder
+    from deeplearning4j_tpu.util.compile_cache import (
+        configure_compile_cache)
+    configure_compile_cache()
     from deeplearning4j_tpu.serving.fleet.agent import ReplicaAgent
     from deeplearning4j_tpu.serving.fleet.pages import PageStore
     from deeplearning4j_tpu.serving.fleet.prefill import PrefillAgent

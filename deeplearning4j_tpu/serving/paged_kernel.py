@@ -265,21 +265,42 @@ def paged_attention(q, k_pool, v_pool, table, lengths, *, query_width: int,
     )(*pref, q, k_pool, v_pool)
 
 
+#: SMEM the int8 kernel may spend on its two [P, Hkv] float32 scale
+#: sidecars. They ride the scalar prefetch, and a 2-D SMEM array pads its
+#: minor dim to 128 lanes, so each costs P * 512 bytes however few kv
+#: heads there are. v5e has 1 MiB of SMEM, shared with the page table:
+#: measured there (jax 0.9.0), a 257-page pool compiles and a 1025-page
+#: pool is refused ("Used 1.01M of 1.00M smem").
+_SMEM_SCALE_BUDGET = 768 * 1024
+
+
 def paged_attention_supported(pool_shape: Tuple[int, ...],
                               query_rows: int, *,
                               kv_dtype: str = "bf16") -> bool:
     """Shape gate for the REAL-CHIP kernel path (mirrors
-    flash_attention_supported): head dim lane-tileable, page rows
-    sublane-tileable. An int8 pool tightens both (the int8 minimum
-    tile is (32, 128) vs fp32's (8, 128) — a page block must still be
-    a whole tile multiple). Interpret mode (CPU tests) has no such
-    limits — this gate only decides the ``decode_impl="auto"``
-    resolution on a TPU backend."""
+    flash_attention_supported): what Mosaic compiles on a v5e under jax
+    0.9.0, established by compiling each side of every bound there
+    (PERF.md "PR 21"). ``pool_shape`` is the pool leaf's
+    ``(P, Hkv, page_size, D)``.
+
+    - native-dtype pools (``kv_dtype="bf16"`` — float32 or bfloat16
+      storage): head dim lane-tileable, page rows a multiple of 8.
+      8-row bfloat16 page blocks compile although bf16 packs 16 rows
+      per tile.
+    - int8 pools: the (32, 128) int8 tile, and the scale sidecars must
+      fit SMEM (``_SMEM_SCALE_BUDGET``) — which bounds the POOL SIZE,
+      not only the block shape. A larger int8 pool decodes on the XLA
+      path.
+
+    Interpret mode (CPU tests) has no such limits — this gate only
+    decides the ``decode_impl="auto"`` resolution on a TPU backend."""
     if len(pool_shape) != 4:
         return False
-    _, _, ps, d = pool_shape
+    pages, hkv, ps, d = pool_shape
     if kv_dtype == "int8":
-        return d in (128, 256) and ps % 32 == 0 and query_rows >= 1
+        lanes = -(-hkv // 128) * 128
+        return (d in (128, 256) and ps % 32 == 0 and query_rows >= 1
+                and 2 * pages * lanes * 4 <= _SMEM_SCALE_BUDGET)
     return d in (64, 128, 256) and ps % 8 == 0 and query_rows >= 1
 
 

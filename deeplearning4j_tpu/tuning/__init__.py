@@ -12,7 +12,7 @@ measurement a persistent, consultable artifact:
 - ``crossover.KernelCrossoverStore`` records paired kernel-vs-fallback
   timings keyed by a stable shape/dtype/impl fingerprint and persists
   them to a committed ``KERNEL_CROSSOVER.json`` (the TPULINT_BASELINE
-  pattern: load → consult → ratchet), so ONE live TPU window calibrates
+  pattern: load → consult → ratchet), so ONE run on a TPU calibrates
   every future run. Entries carry platform + device kind — a
   CPU-calibrated entry never decides a TPU run.
 - ``plan`` resolves user-facing execution plans
@@ -21,7 +21,7 @@ measurement a persistent, consultable artifact:
   kernels become a composable plan layer on the step builders instead
   of a bench-only env flag.
 - ``calibrate`` is the explicit measurement harness that fills the
-  store from a live window (per-shape paired timings of the fused
+  store from a run on the chip (per-shape paired timings of the fused
   training kernels and the paged-decode read path).
 """
 

@@ -139,19 +139,13 @@ def winner(entry: dict) -> str:
 
 
 def _current_platform() -> str:
-    try:
-        import jax
-        return jax.default_backend()
-    except Exception:  # noqa: BLE001 — a store read must not need a backend
-        return "unknown"
+    import jax
+    return jax.default_backend()
 
 
 def _current_device_kind() -> str:
-    try:
-        import jax
-        return getattr(jax.devices()[0], "device_kind", "unknown")
-    except Exception:  # noqa: BLE001
-        return "unknown"
+    import jax
+    return jax.devices()[0].device_kind
 
 
 class KernelCrossoverStore:

@@ -174,7 +174,7 @@ lane disagg python -m pytest tests/test_fleet_pages.py tests/test_fleet_disagg.p
 # lifecycle (roundtrip/ratchet/prune/platform guard), fused==xla fit
 # equivalence with the sentinel ON (per-batch + K-step scan), zero
 # retraces on plan re-resolution, decode-impl eligibility-vs-choice,
-# stem kernel exactness, and the bench parked-record invariant
+# and stem kernel exactness
 lane autotune python -m pytest tests/test_autotune.py tests/test_stem_fused.py -q \
     -p no:cacheprovider
 

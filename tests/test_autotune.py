@@ -12,14 +12,9 @@ Contracts pinned here (ISSUE 11 acceptance):
 - fit-loop plan matrix: `net.fit(..., execution_plan="fused")` matches
   `"xla"` (params / opt-state / score trajectory) with the non-finite
   sentinel ON, including the fused K-step scan path, with zero
-  retraces after warmup;
-- bench parked-record invariant: stale module state can never become a
-  later run's record, and a parked first-leg measurement survives a
-  failing optional leg.
+  retraces after warmup.
 """
 
-import importlib
-import json
 import logging
 import os
 import sys
@@ -516,52 +511,3 @@ class TestFitPlanMatrix:
         y[np.arange(16), rng.integers(0, 2, 16)] = 1.0
         pw.fit(x, y, epochs=1, batch_size=8, execution_plan="fused")
         assert np.isfinite(float(net.score_value))
-
-
-# ---------------------------------------------------------------------
-# bench parked-record invariant (ISSUE 11 bugfix satellite)
-# ---------------------------------------------------------------------
-class TestBenchParkedRecord:
-    @pytest.fixture(autouse=True)
-    def _bench(self):
-        import bench
-        importlib.reload(bench)
-        self.bench = bench
-        yield
-        self.bench._partial.clear()
-
-    def test_main_resets_stale_module_state(self, capsys, monkeypatch):
-        """A second in-process main() must not emit (or suppress) the
-        previous run's parked record: the emitted flag and the parked
-        measurement reset BEFORE anything can fire."""
-        b = self.bench
-        b._emitted = True                       # stale: would swallow
-        b._partial.update(value=9999.0, vs=49.9, platform="tpu",
-                          extra={"plan": "unfused"})  # stale record
-        monkeypatch.setenv("BENCH_PLATFORM", "cpu")
-        monkeypatch.delenv("BENCH_ALLOW_CPU", raising=False)
-        rc = b.main()
-        out = capsys.readouterr().out.strip().splitlines()
-        assert rc == 3
-        line = json.loads(out[-1])
-        # the fresh run emitted ITS OWN failure line — not nothing
-        # (stale _emitted) and not the stale 9999 record
-        assert line["error"] == "tpu-unavailable"
-        assert line["value"] is None
-        assert not b._partial
-
-    def test_parked_record_survives_failed_calibrate_leg(self, capsys):
-        """The store-driven optional legs run parked: a deadline firing
-        mid-leg emits the completed measurement, not a null record —
-        and never a destroyed/mixed one."""
-        b = self.bench
-        b._partial.update(
-            value=2650.0, vs=13.25, platform="tpu",
-            extra={"plan": "unfused", "unfused_img_s": 2650.0})
-        emitted, had = b._emit_partial_or_fail(
-            "tpu-unavailable", "auto/calibrate leg hang")
-        assert emitted and had
-        line = json.loads(capsys.readouterr().out.strip())
-        assert line["value"] == 2650.0
-        assert line["plan"] == "unfused"
-        assert "auto/calibrate leg" in line["ab_incomplete"]
