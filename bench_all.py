@@ -1266,8 +1266,8 @@ def bench_serve_fleet():
     # the trace must OVERLOAD one replica (deep queue at 2 slots) so
     # the fleet's measured effect is queue relief; on this shared-CPU
     # A/B the replicas also contend for cores, which real fleets
-    # (one chip per replica) don't — the flat-TTFT acceptance
-    # adjudicates on a live-chip window (PERF.md "ISSUE 14")
+    # (one chip per replica) don't — flat TTFT can only be judged with
+    # one chip per replica, which neither fleet has run on yet
     V, R, STEPS, SLOTS, PS = 256, 24, 24, 2, 8
     STAGGER = 0.005
     model_kw = dict(vocab_size=V, embed_dim=64, n_heads=4, n_layers=2,
@@ -1389,7 +1389,7 @@ def bench_serve_fleet_procs():
     has been brought up on more than one chip); the record says
     ``"workers": "cpu"``. tok/s and p95 TTFT are recorded but NEVER
     asserted: on shared CPU the replica processes contend for the same
-    cores (PERF.md "ISSUE 19")."""
+    cores."""
     import shutil
     import subprocess
     import tempfile

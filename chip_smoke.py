@@ -25,10 +25,16 @@ Usage::
     python chip_smoke.py --kernels       # compile every other pallas_call
     python chip_smoke.py --dry-run-cpu   # tiny shapes on CPU, never a PASS
 
-Output: one JSON line per phase, then a final summary line. The default
-mode refuses any backend but ``tpu`` (jax with libtpu and no chip drops
-to CPU with a warning — that must not pass) and no phase is wrapped in a
-handler that lets the run finish green: any exception or failed check is
+Output: one JSON line per phase, a ``summary`` line (compile cache,
+compile count, wall time, ``"claim": null``), and as the LAST line the
+result the driver parses, with exactly these keys::
+
+    {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": 1}}
+
+The default mode refuses any backend but ``tpu`` (jax with libtpu and no
+chip drops to CPU with a warning — that must not pass; it exits 2 with no
+result line) and no phase is wrapped in a handler that lets the run
+finish green: any exception or failed check prints ``"ok": false`` and is
 a non-zero exit. Timings printed here are observations of ONE run
 ("smoke, one run"), not measurements. ``--kernels`` is the one reporting
 mode: it compiles each remaining kernel at its real shape, prints one
@@ -154,6 +160,16 @@ class Run:
     def emit(self, phase: str, **fields) -> None:
         print(json.dumps({"phase": phase, **fields, **self.stamp}),
               flush=True)
+
+
+def result_line(ok: bool, stamp: dict) -> str:
+    """The result the driver parses from the LAST line of stdout: exactly
+    the keys ``ok`` and ``device``, and in ``device`` exactly
+    ``platform``, ``kind``, ``count`` as jax reports them. Everything
+    else a run has to say goes on the phase lines before it."""
+    return json.dumps({"ok": ok, "device": {
+        "platform": stamp["platform"], "kind": stamp["device_kind"],
+        "count": stamp["device_count"]}})
 
 
 def check(cond: bool, what: str) -> None:
@@ -714,29 +730,27 @@ def main(argv=None) -> int:
     if args.kernels:
         return mode_kernels(run)
 
-    phase_native(run)
-    first_loss = phase_train(run)
-    lm = build_transformer(run.sz)
-    phase_flash(run, lm)
-    phase_serve(run, lm)
-    phase_multichip(run, first_loss)
+    def result(ok: bool) -> None:
+        # a dry run prints no result: it can never be read as a chip PASS
+        if not args.dry_run_cpu:
+            print(result_line(ok, run.stamp), flush=True)
 
-    summary = {
-        "device": {"platform": platform,
-                   "kind": run.stamp["device_kind"],
-                   "count": run.stamp["device_count"]},
-        "versions": {k: run.stamp[k] for k in ("jax", "jaxlib", "libtpu")},
-        "compile_cache": {"dir": cache_dir, **run.cache_events},
-        "compiles_total": int(run.compiles.total()),
-        "wall_s": round(time.perf_counter() - t_start, 1),
-        "claim": None,
-    }
-    if args.dry_run_cpu:
-        # no "ok" key: a dry run can never be read as a chip PASS
-        print(json.dumps({"dry_run": True, "phases_passed": True,
-                          **summary}), flush=True)
-    else:
-        print(json.dumps({"ok": True, **summary}), flush=True)
+    try:
+        phase_native(run)
+        first_loss = phase_train(run)
+        lm = build_transformer(run.sz)
+        phase_flash(run, lm)
+        phase_serve(run, lm)
+        phase_multichip(run, first_loss)
+    except BaseException:
+        result(False)       # and the exception still ends the run
+        raise
+
+    run.emit("summary", passed=True, dry_run=args.dry_run_cpu,
+             compile_cache={"dir": cache_dir, **run.cache_events},
+             compiles_total=int(run.compiles.total()),
+             wall_s=round(time.perf_counter() - t_start, 1), claim=None)
+    result(True)
     return 0
 
 

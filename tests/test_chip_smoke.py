@@ -84,6 +84,16 @@ class TestSmokeRefusesCpu:
         assert not any("ok" in l for l in lines)      # no result line
         assert "'cpu'" in r.stderr
 
+    def test_result_line_has_exactly_the_contract_keys(self, monkeypatch):
+        """The driver refuses a last line with any key beyond these."""
+        monkeypatch.syspath_prepend(REPO)
+        import chip_smoke as smoke
+        stamp = {"platform": "tpu", "device_kind": "TPU v5 lite",
+                 "device_count": 1, "jax": "0.9.0"}
+        assert json.loads(smoke.result_line(True, stamp)) == {
+            "ok": True, "device": {"platform": "tpu",
+                                   "kind": "TPU v5 lite", "count": 1}}
+
     def test_kernels_mode_has_no_dry_run(self):
         r = _run_smoke("--kernels", "--dry-run-cpu", timeout=60)
         assert r.returncode != 0 and not r.stdout.strip()
@@ -158,6 +168,7 @@ def test_dry_run_cpu_passes_but_never_prints_a_chip_pass():
         assert phases[name]["passed"] is True
         assert phases[name]["platform"] == "cpu"
     assert phases["serve"]["kernel"] == "interpret"
-    last = lines[-1]
-    assert last["dry_run"] and "ok" not in last
-    assert last["device"]["platform"] == "cpu" and last["claim"] is None
+    last = lines[-1]                # the summary; a dry run has no result
+    assert last["phase"] == "summary" and last["dry_run"]
+    assert last["platform"] == "cpu" and last["claim"] is None
+    assert not any("ok" in l for l in lines)
