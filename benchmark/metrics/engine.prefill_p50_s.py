@@ -1,0 +1,9 @@
+"""Median time from admission to first token (the prefill), from the
+requests' traces."""
+from benchmark.metrics._common import median
+
+
+def read(ctx):
+    sent = ctx["record"]["serve"]["sent"]
+    return median([r.handle.trace().breakdown()["prefill_s"]
+                   for r in sent if r.token_t])
