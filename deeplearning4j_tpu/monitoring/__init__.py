@@ -26,8 +26,8 @@ from deeplearning4j_tpu.monitoring.events import (  # noqa: F401
     Event, EventLog, emit, events_enabled, global_event_log,
     set_events_enabled)
 from deeplearning4j_tpu.monitoring.tracing import (  # noqa: F401
-    current_path, declare_default_spans, is_enabled, phase_detail,
-    record_span, set_enabled, set_phase_detail, span)
+    current_path, declare_default_spans, is_enabled, next_phase, phases,
+    record_span, set_enabled, span)
 from deeplearning4j_tpu.monitoring.exporters import (  # noqa: F401
     CONTENT_TYPE, JsonlSink, metrics_snapshot, render_prometheus)
 from deeplearning4j_tpu.monitoring.listener import (  # noqa: F401

@@ -96,16 +96,30 @@ class Counter(Metric):
         if amount < 0:
             raise ValueError(f"{self.name}: counters only go up")
         with self._lock:
-            self._child(labels)[0] += amount
+            child = self._child(labels)
+            if callable(child[0]):
+                raise ValueError(f"{self.name}: callback counter is "
+                                 "read-only")
+            child[0] += amount
+
+    def set_function(self, fn: Callable[[], float], **labels) -> None:
+        """Read the count from its owner at collection time: a total the
+        owner keeps anyway (the serving engine's health() counts) is
+        exposed without a second store, and its hot path pays no
+        registry lock."""
+        with self._lock:
+            self._child(labels)[0] = fn
 
     def value(self, **labels) -> float:
         with self._lock:
-            return self._child(labels)[0]
+            v = self._child(labels)[0]
+        return float(v()) if callable(v) else v
 
     def total(self) -> float:
         """Sum over every label combination."""
         with self._lock:
-            return sum(c[0] for c in self._children.values())
+            values = [c[0] for c in self._children.values()]
+        return sum(float(v()) if callable(v) else v for v in values)
 
 
 class Gauge(Metric):

@@ -13,18 +13,23 @@ Spans nest via a thread-local stack (`current_path()` returns e.g.
 "iteration/forward"); the histogram label stays the LEAF name so series
 cardinality is bounded by the set of span names, not call paths.
 
+`phases()` / `next_phase(name)` keep a thread in a FLAT sequence of
+spans — exactly one open at a time, each switch closing the last — for a
+cycle whose phase boundaries cross function boundaries (the serving
+engine's step: admission, prefill, decode dispatch, sampling). A profiler
+reduction that names a device-idle gap by the innermost host event over it
+then reads the program's own phase names.
+
 `set_enabled(False)` turns spans into no-ops (for overhead-sensitive
-loops); `set_phase_detail(True)` switches the fit loops from the single
-fused train step (span "step") to split forward/backward/update steps so
-the per-phase histograms carry real device timings — see
-MultiLayerNetwork._get_phase_steps for the cost tradeoff.
+loops).
 """
 
 from __future__ import annotations
 
-import os
+import contextlib
 import threading
 import time
+import weakref
 from typing import Optional
 
 from deeplearning4j_tpu.monitoring.metrics import (
@@ -33,16 +38,17 @@ from deeplearning4j_tpu.monitoring.metrics import (
 SPAN_HISTOGRAM = "dl4jtpu_span_seconds"
 SPAN_ERRORS = "dl4jtpu_span_errors_total"
 
-#: the phase names the fit loops emit; declared eagerly so the /metrics
-#: exposition always carries all per-phase series (etl/forward/backward/
-#: update populate per the phase-detail mode, "step" is the fused step)
-DEFAULT_SPANS = ("etl", "forward", "backward", "update", "step", "listener")
+#: the span names the fit loops emit; declared eagerly so the /metrics
+#: exposition always carries them ("step" is the fused train step)
+DEFAULT_SPANS = ("etl", "step", "listener")
 
 _tls = threading.local()
 _enabled = True
-_phase_detail = os.environ.get(
-    "DL4JTPU_PHASE_DETAIL", "0").strip().lower() not in (
-    "0", "", "false", "no", "off")
+
+#: registry -> {span name: bound histogram child}: a span's exit observes
+#: through a child resolved once, not through the registry's
+#: get-or-create lock (the hot-path rule of serving/health.py)
+_children: "weakref.WeakKeyDictionary" = weakref.WeakKeyDictionary()
 
 # jax.profiler.TraceAnnotation, resolved lazily: the metrics side of a
 # span must work in processes where jax never imported (bench failure
@@ -70,19 +76,6 @@ def is_enabled() -> bool:
     return _enabled
 
 
-def set_phase_detail(flag: bool) -> None:
-    """True: fit loops run split forward/backward/update jitted steps so
-    those spans measure real device time (3 dispatches, residuals
-    materialized at the seams). False (default): the single fused step
-    keeps maximum XLA fusion and records under span "step"."""
-    global _phase_detail
-    _phase_detail = bool(flag)
-
-
-def phase_detail() -> bool:
-    return _phase_detail
-
-
 def current_path() -> str:
     """Slash-joined stack of open spans on this thread ("" outside any)."""
     return "/".join(getattr(_tls, "stack", ()))
@@ -96,11 +89,19 @@ def span_histogram(registry: Optional[MetricsRegistry] = None):
         "(host-side; aligns with XPlane TraceAnnotations)", ("span",))
 
 
+def _span_child(name: str, registry: MetricsRegistry):
+    by_name = _children.setdefault(registry, {})
+    child = by_name.get(name)
+    if child is None:
+        child = by_name[name] = span_histogram(registry).labels(span=name)
+    return child
+
+
 def record_span(name: str, seconds: float,
                 registry: Optional[MetricsRegistry] = None) -> None:
     """Directly record a span observation (used by TrainingStats and any
     timer that measured the interval itself)."""
-    span_histogram(registry).observe(seconds, span=name)
+    _span_child(name, registry or global_registry()).observe(seconds)
 
 
 def declare_default_spans(registry: Optional[MetricsRegistry] = None) -> None:
@@ -149,9 +150,67 @@ class span:
                 pass
         _tls.stack.pop()
         r = self.registry or global_registry()
-        span_histogram(r).observe(dt, span=self.name)
+        _span_child(self.name, r).observe(dt)
         if exc_type is not None:
             r.counter(SPAN_ERRORS,
                       "Spans that exited via an exception",
                       ("span",)).inc(span=self.name)
         return False
+
+
+#: `_tls.phase` inside a `phases` block before its first `next_phase`
+_ARMED = object()
+
+
+class phases(contextlib.ContextDecorator):
+    """A flat sequence of spans on this thread:
+
+        with phases():
+            ...                         # no span until there is work
+            next_phase("engine.reap")   # opens the first
+            ...
+            next_phase("engine.admit")  # closes reap, opens admit
+            ...                         # callees switch on from here
+
+    From the first `next_phase` on, exactly one span of the sequence is
+    open at any time; leaving the block closes whichever is. A block that
+    never calls `next_phase` records nothing. Re-entrant: inside a running
+    sequence `phases()` does nothing and leaves the closing to the outer
+    block, so a function that may be called from inside a cycle or on its
+    own can be decorated with it."""
+
+    def __init__(self):
+        self._owner = False
+
+    def _recreate_cm(self):        # one instance per decorated call
+        return phases()
+
+    def __enter__(self):
+        if _enabled and getattr(_tls, "phase", None) is None:
+            self._owner = True
+            _tls.phase = _ARMED
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        if self._owner:
+            self._owner = False
+            s, _tls.phase = _tls.phase, None
+            if s is not _ARMED:
+                s.__exit__(exc_type, exc, tb)
+        return False
+
+
+def next_phase(name: str) -> None:
+    """Inside a `phases` sequence on this thread: close the open span, if
+    any, and open `name` (nothing if `name` is already open). Outside
+    one: nothing, so shared code names its phases only for a caller that
+    runs a cycle."""
+    cur = getattr(_tls, "phase", None)
+    if cur is None:
+        return
+    if cur is not _ARMED:
+        if cur.name == name:
+            return
+        cur.__exit__(None, None, None)
+    s = _tls.phase = span(name)
+    s.__enter__()

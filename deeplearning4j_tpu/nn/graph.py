@@ -31,7 +31,7 @@ from deeplearning4j_tpu.nn.updater import normalize_gradients
 from deeplearning4j_tpu.monitoring import ensure_started
 from deeplearning4j_tpu.monitoring.listener import (
     finalize_fit_telemetry, maybe_record_fit_iteration)
-from deeplearning4j_tpu.monitoring.tracing import phase_detail, span
+from deeplearning4j_tpu.monitoring.tracing import span
 from deeplearning4j_tpu.nn.multilayer import _strip_stream_state, _tree_sub
 from deeplearning4j_tpu.optimize.listeners import close_listeners
 from deeplearning4j_tpu.pipeline.padding import (
@@ -1004,48 +1004,6 @@ class ComputationGraph(LazyScore):
             self._jit_cache[key] = jax.jit(stepk, donate_argnums=(0, 2))
         return self._jit_cache[key]
 
-    def _get_phase_steps(self, carry_rnn: bool, policy: str = "off"):
-        """Split train step for span phase detail — the ComputationGraph
-        twin of MultiLayerNetwork._get_phase_steps (see its docstring for
-        the vjp-across-jit pattern, the fusion-cost tradeoff, and the
-        debug-path sentinel caveat)."""
-        if getattr(self, "_quantized", False):
-            raise RuntimeError(
-                "this network was quantized for inference "
-                "(quantize_for_inference) — int8 weights have no "
-                "gradient path; train the fp checkpoint and re-quantize")
-        key = ("phase", carry_rnn, self.conf.dtype, policy)
-        if key not in self._jit_cache:
-            conf = self.conf
-
-            def fwd(params, state, inputs, labels, rng, fmasks, lmasks):
-                loss, vjp_fn, new_state = jax.vjp(
-                    lambda p: self._loss(p, state, inputs, labels, rng,
-                                         fmasks, lmasks, train=True,
-                                         carry_rnn=carry_rnn),
-                    params, has_aux=True)
-                return loss, new_state, vjp_fn
-
-            def bwd(vjp_fn, loss):
-                (grads,) = vjp_fn(jnp.ones_like(loss))
-                return normalize_gradients(grads, conf.gradient_normalization,
-                                           conf.gradient_normalization_threshold)
-
-            def upd(params, grads, upd_state, loss, state, new_state):
-                steps, new_upd = conf.updater.update(grads, upd_state, params)
-                new_params = _tree_sub(params, steps)
-                if policy == "off":
-                    return new_params, new_upd, new_state
-                ok = tree_finite(loss, grads)
-                new_params, new_upd, new_state = guard_updates(
-                    ok, policy, (new_params, params),
-                    (new_upd, upd_state), (new_state, state))
-                return new_params, new_upd, new_state, ok
-
-            self._jit_cache[key] = (jax.jit(fwd), jax.jit(bwd),
-                                    jax.jit(upd, donate_argnums=(1, 2)))
-        return self._jit_cache[key]
-
     def _next_rng(self):
         self._rng, sub = jax.random.split(self._rng)
         return sub
@@ -1261,25 +1219,12 @@ class ComputationGraph(LazyScore):
             lmasks = self._as_mask_dict(ds.labels_mask,
                                         default_key=self.conf.network_outputs[0])
         policy = effective_policy(self)
-        if phase_detail() and not getattr(self, "_quantized", False):
-            # dispatch-time spans, no device barrier: see multilayer.py
-            fwd, bwd, upd = self._get_phase_steps(False, policy)
-            with span("forward"):
-                loss, new_state, vjp_fn = fwd(self.params, self.state, inputs,
-                                              labels, rng, fmasks, lmasks)
-            with span("backward"):
-                grads = bwd(vjp_fn, loss)
-            with span("update"):
-                self.params, self.updater_state, self.state = apply_step(
-                    self, policy, upd, self.params, grads,
-                    self.updater_state, loss, self.state, new_state)
-        else:
-            step = self._get_train_step(False, policy)
-            with span("step"):
-                self.params, self.state, self.updater_state, loss = \
-                    apply_step(self, policy, step, self.params, self.state,
-                               self.updater_state, inputs, labels, rng,
-                               fmasks, lmasks)
+        step = self._get_train_step(False, policy)
+        with span("step"):
+            self.params, self.state, self.updater_state, loss = \
+                apply_step(self, policy, step, self.params, self.state,
+                           self.updater_state, inputs, labels, rng,
+                           fmasks, lmasks)
         # raw device scalar: float() (the host sync) deferred to access
         self.score_value = loss
         with span("listener"):

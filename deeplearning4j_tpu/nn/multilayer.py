@@ -41,7 +41,7 @@ from deeplearning4j_tpu.nn.updater import normalize_gradients
 from deeplearning4j_tpu.monitoring import ensure_started
 from deeplearning4j_tpu.monitoring.listener import (
     finalize_fit_telemetry, maybe_record_fit_iteration)
-from deeplearning4j_tpu.monitoring.tracing import phase_detail, span
+from deeplearning4j_tpu.monitoring.tracing import span
 from deeplearning4j_tpu.optimize.listeners import close_listeners
 from deeplearning4j_tpu.pipeline.padding import (
     group_signature, num_real_examples, pad_batch, with_example_weights)
@@ -391,61 +391,6 @@ class MultiLayerNetwork(LazyScore):
             self._jit_cache[key] = jax.jit(stepk, donate_argnums=(0, 2))
         return self._jit_cache[key]
 
-    def _get_phase_steps(self, carry_rnn: bool, policy: str = "off"):
-        """Split train step for span phase detail
-        (monitoring.set_phase_detail): forward (vjp residuals), backward
-        (vjp apply + grad normalization), update (updater + constraints)
-        as three jitted calls, so the forward/backward/update spans carry
-        real device timings. Same math as _get_train_step —
-        value_and_grad IS vjp — but the seams cost cross-phase XLA fusion
-        and materialize the residuals, so the fused step stays the
-        default for production throughput.
-
-        Sentinel caveat on this debug path: the flag is computed from
-        the NORMALIZED grads (the raw ones live only inside bwd) — the
-        fused step, which tests the raw grads, is the exact-semantics
-        path. The state leg (BN running stats) IS guarded: upd receives
-        the pre/post state and where-selects it with params/opt."""
-        if getattr(self, "_quantized", False):
-            raise RuntimeError(
-                "this network was quantized for inference "
-                "(quantize_for_inference) — int8 weights have no "
-                "gradient path; train the fp checkpoint and re-quantize")
-        key = ("phase", carry_rnn, self.conf.dtype, policy)
-        if key not in self._jit_cache:
-            conf = self.conf
-
-            def fwd(params, state, x, y, rng, fmask, lmask):
-                loss, vjp_fn, new_state = jax.vjp(
-                    lambda p: self._loss(p, state, x, y, rng, fmask, lmask,
-                                         train=True, carry_rnn=carry_rnn),
-                    params, has_aux=True)
-                return loss, new_state, vjp_fn
-
-            def bwd(vjp_fn, loss):
-                (grads,) = vjp_fn(jnp.ones_like(loss))
-                return normalize_gradients(grads, conf.gradient_normalization,
-                                           conf.gradient_normalization_threshold)
-
-            def upd(params, grads, upd_state, loss, state, new_state):
-                steps, new_upd = conf.updater.update(grads, upd_state, params)
-                new_params = _tree_sub(params, steps)
-                if any(getattr(l, "constraints", None) for l in self.layers):
-                    from deeplearning4j_tpu.nn.conf.constraints import \
-                        apply_constraints
-                    new_params = apply_constraints(self.layers, new_params)
-                if policy == "off":
-                    return new_params, new_upd, new_state
-                ok = tree_finite(loss, grads)
-                new_params, new_upd, new_state = guard_updates(
-                    ok, policy, (new_params, params),
-                    (new_upd, upd_state), (new_state, state))
-                return new_params, new_upd, new_state, ok
-
-            self._jit_cache[key] = (jax.jit(fwd), jax.jit(bwd),
-                                    jax.jit(upd, donate_argnums=(1, 2)))
-        return self._jit_cache[key]
-
     def _get_output_fn(self, train: bool, carry_rnn: bool,
                        stream: bool = False, padded: bool = False,
                        donate: bool = False):
@@ -721,26 +666,11 @@ class MultiLayerNetwork(LazyScore):
             x = jnp.asarray(ds.features)
             y = jnp.asarray(ds.labels)
         policy = effective_policy(self)
-        if phase_detail() and not getattr(self, "_quantized", False):
-            # spans time DISPATCH per phase (async: the device may still
-            # be executing) — no block_until_ready here, the fit loop's
-            # steady state must never stall the pipeline
-            fwd, bwd, upd = self._get_phase_steps(carry_rnn, policy)
-            with span("forward"):
-                loss, new_state, vjp_fn = fwd(self.params, self.state, x, y,
-                                              rng, fmask, lmask)
-            with span("backward"):
-                grads = bwd(vjp_fn, loss)
-            with span("update"):
-                self.params, self.updater_state, self.state = apply_step(
-                    self, policy, upd, self.params, grads,
-                    self.updater_state, loss, self.state, new_state)
-        else:
-            step = self._get_train_step(carry_rnn, policy)
-            with span("step"):
-                self.params, self.state, self.updater_state, loss = \
-                    apply_step(self, policy, step, self.params, self.state,
-                               self.updater_state, x, y, rng, fmask, lmask)
+        step = self._get_train_step(carry_rnn, policy)
+        with span("step"):
+            self.params, self.state, self.updater_state, loss = \
+                apply_step(self, policy, step, self.params, self.state,
+                           self.updater_state, x, y, rng, fmask, lmask)
         # raw device scalar: float() (the host sync) deferred to access
         self.score_value = loss
         with span("listener"):

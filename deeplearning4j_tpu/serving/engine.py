@@ -99,6 +99,28 @@ Survivability (PR 9, ARCHITECTURE.md "Serving survivability"):
   handoff seam the supervisor also covers); ``prefill_chaos`` /
   ``seat_chaos`` receive the request as event context, so
   ``resilience.chaos.RequestFaultInjector`` can target named victims.
+
+Phases. While a cycle has work, the stepping thread is in exactly one
+``monitoring`` span at a time (``monitoring.phases``: flat, no parents;
+static names), so a profiler trace names every device-idle gap by what
+the host was doing. In cycle order: ``engine.reap`` (expiry,
+cancellation, overload control), ``engine.admit`` (from a request
+popped: page reservation, prefix lookup and install), ``prefill.input`` /
+``prefill.forward`` / ``prefill.fetch`` (``util.decoding.prime_prompt``:
+the host-built prompt tensor; upload and launch; the result coming
+back), ``engine.seat`` (first draw, arena join, page-table update),
+``decode.input`` (token vector, position mirrors, paged-view install,
+under speculation the host draft, the one-hot), ``decode.forward`` (the
+dispatch), ``decode.fetch`` (the distributions coming back, pool
+extract), ``engine.sample`` (per-row draw or acceptance walk, push,
+retire). One of each per cycle; the admission phases once per admitted
+request (chunked priming alternates input and forward per chunk). The
+names are ``PHASES``; the sequence opens when a poll finds work (a seated
+row, or a request popped), so an idle poll records nothing. ``health()``
+counts at the same boundaries: ``decode_dispatch.rows``, ``prefill``
+(tokens fed, padded widths dispatched; tokens the prefix cache served
+instead are ``prefix_cache.reused_tokens``) and ``host_io`` (bytes of
+the numpy arrays that cross around ``rnn_time_step``).
 """
 
 from __future__ import annotations
@@ -119,6 +141,7 @@ from deeplearning4j_tpu.monitoring import flightrecorder
 from deeplearning4j_tpu.monitoring.events import emit as emit_event
 from deeplearning4j_tpu.monitoring.metrics import (
     MetricsRegistry, global_registry)
+from deeplearning4j_tpu.monitoring.tracing import next_phase, phases
 from deeplearning4j_tpu.nn.conf.layers import (
     BATCHED_STREAM_KEYS, PositionalEmbeddingLayer, check_rewindable,
     paged_decode_impl, rewind_stream_state, set_paged_decode_impl,
@@ -130,10 +153,11 @@ from deeplearning4j_tpu.serving.errors import (
     ServingOverloaded, ServingQueueFull)
 from deeplearning4j_tpu.serving.health import (
     SERVING_ACTIVE_SLOTS, SERVING_BROWNOUT_LEVEL,
-    SERVING_DEADLINE_EXCEEDED, SERVING_DISPATCH_LATENCY,
-    SERVING_DRAINING, SERVING_EARLY_REJECTED, SERVING_ERRORS,
-    SERVING_KV_BYTES_MOVED, SERVING_KV_PAGES_TOTAL,
-    SERVING_KV_PAGES_USED, SERVING_PREFIX_HITS, SERVING_PREFIX_MISSES,
+    SERVING_DEADLINE_EXCEEDED, SERVING_DECODE_ROWS,
+    SERVING_DISPATCH_LATENCY, SERVING_DRAINING, SERVING_EARLY_REJECTED,
+    SERVING_ERRORS, SERVING_HOST_IO_BYTES, SERVING_KV_BYTES_MOVED,
+    SERVING_KV_PAGES_TOTAL, SERVING_KV_PAGES_USED, SERVING_PREFILL_TOKENS,
+    SERVING_PREFIX_HITS, SERVING_PREFIX_MISSES,
     SERVING_PREFIX_REUSED_TOKENS, SERVING_QUEUE_REJECTED,
     SERVING_QUEUE_WAIT, SERVING_REQUESTS, SERVING_SHED,
     SERVING_SPEC_ACCEPTANCE, SERVING_TOKENS, SERVING_TPOT, SERVING_TTFT,
@@ -153,8 +177,9 @@ from deeplearning4j_tpu.serving.request import (
     rng_state_payload)
 from deeplearning4j_tpu.serving.scheduler import AdmissionQueue
 from deeplearning4j_tpu.util.decoding import (
-    _check_seed, _stream_layers, _width_bucket, accept_proposals, draw,
-    filter_probs, prime_prompt, step_tokens, stop_reason, verify_tokens)
+    RoundTrip, _check_seed, _stream_layers, _width_bucket,
+    accept_proposals, draw, filter_probs, prime_prompt, step_tokens,
+    stop_reason, verify_tokens)
 
 log = logging.getLogger(__name__)
 
@@ -192,6 +217,48 @@ class SpeculationConfig:
                 "(ids, gamma) -> proposals, e.g. "
                 "util.decoding.prompt_lookup_proposer(); model-based "
                 "drafting stays on the one-shot speculative_sample path")
+
+
+#: the cycle's phases in order (module docstring, "Phases"): the one
+#: table of span names. ``util/decoding`` knows a round trip's three
+#: steps only; ``_HostIO`` gives them these names.
+PHASES = ("engine.reap", "engine.admit",
+          "prefill.input", "prefill.forward", "prefill.fetch",
+          "engine.seat",
+          "decode.input", "decode.forward", "decode.fetch",
+          "engine.sample")
+
+
+class _HostIO(RoundTrip):
+    """The engine's view of one kind of host round trip (``"decode"`` or
+    ``"prefill"``), handed to ``util/decoding`` as `io`: each step
+    switches the cycle to its phase (``decode.input`` …), and the numpy
+    arrays handed to ``rnn_time_step`` and fetched from it are counted
+    where they cross. ``health()["host_io"][kind]`` reads the bytes;
+    ``width`` sums the time axis of what was handed over — a prime's
+    padded bucket — where the kind asks for it."""
+
+    __slots__ = ("h2d_bytes", "d2h_bytes", "width", "_widths", "_phase")
+
+    def __init__(self, kind: str, widths: bool = False):
+        self.h2d_bytes = self.d2h_bytes = self.width = 0
+        self._widths = widths
+        self._phase = {name.split(".")[1]: name for name in PHASES
+                       if name.startswith(kind + ".")}
+
+    def step(self, name: str) -> None:
+        next_phase(self._phase[name])
+
+    def h2d(self, x: np.ndarray) -> None:
+        self.h2d_bytes += x.nbytes
+        if self._widths:
+            self.width += x.shape[-1]
+
+    def d2h(self, p: np.ndarray) -> None:
+        self.d2h_bytes += p.nbytes
+
+    def as_dict(self) -> dict:
+        return {"h2d_bytes": self.h2d_bytes, "d2h_bytes": self.d2h_bytes}
 
 
 @jax.jit
@@ -446,6 +513,12 @@ class GenerationEngine:
             check_rewindable(net, speculation.gamma + 1)
         self._admissions = 0
         self._dispatches = 0
+        #: active rows summed over dispatches; tokens the primes fed
+        #: (the padded widths they dispatched: ``_io["prefill"].width``)
+        self._dispatch_rows = 0
+        self._prefill_fed = 0
+        self._io = {"decode": _HostIO("decode"),
+                    "prefill": _HostIO("prefill", widths=True)}
         self._prefill_chaos = prefill_chaos
         self._decode_chaos = decode_chaos
         self._seat_chaos = seat_chaos
@@ -513,6 +586,32 @@ class GenerationEngine:
             SERVING_DISPATCH_LATENCY, "Wall seconds per decode/verify "
             "dispatch cycle (paged modes include the KV path around it)",
             ("model",)).labels(**lab)
+        # the cycle's counts (health() reads the same ints): one store,
+        # collected at scrape time, so the hot path takes no registry lock
+        r.counter(
+            SERVING_DECODE_ROWS, "Active rows summed over decode/verify "
+            "dispatches", ("model",)).set_function(
+            scrape_probe(self, lambda e: e._dispatch_rows), **lab)
+        tokens = r.counter(
+            SERVING_PREFILL_TOKENS, "Prompt tokens per prime: fed, and "
+            "the padded bucket dispatched", ("model", "kind"))
+        tokens.set_function(
+            scrape_probe(self, lambda e: e._prefill_fed),
+            kind="fed", **lab)
+        tokens.set_function(
+            scrape_probe(self, lambda e: e._io["prefill"].width),
+            kind="bucket", **lab)
+        io = r.counter(
+            SERVING_HOST_IO_BYTES, "Bytes of the numpy arrays handed to "
+            "and fetched from rnn_time_step", ("model", "phase",
+                                               "direction"))
+        for kind in self._io:
+            io.set_function(
+                scrape_probe(self, lambda e, k=kind: e._io[k].h2d_bytes),
+                phase=kind, direction="h2d", **lab)
+            io.set_function(
+                scrape_probe(self, lambda e, k=kind: e._io[k].d2h_bytes),
+                phase=kind, direction="d2h", **lab)
         if self._pool is not None:
             self._kv_bytes = r.counter(
                 SERVING_KV_BYTES_MOVED, "Modeled bytes the KV path "
@@ -633,7 +732,13 @@ class GenerationEngine:
                    "count": self._dispatches,
                    "mean_ms": round(
                        self._dispatch_s_total * 1e3
-                       / max(1, self._dispatches), 3)}}
+                       / max(1, self._dispatches), 3),
+                   "rows": self._dispatch_rows},
+               "prefill": {
+                   "fed_tokens": self._prefill_fed,
+                   "bucket_tokens": self._io["prefill"].width},
+               "host_io": {k: io.as_dict()
+                           for k, io in self._io.items()}}
         if self._pool is not None:
             out["kv_pages"] = {"total": self._pool.usable,
                                "used": self._pool.used_count(),
@@ -782,34 +887,43 @@ class GenerationEngine:
         with self._lock:
             if self._stop.is_set() or self._broken is not None:
                 return False
-            now = time.monotonic()
-            progress = self._reap(now) > 0
-            try:
-                if self._overload is not None:
-                    progress = self._apply_overload(now) or progress
-                if not self._draining:
-                    # admission staging (prefill buffers, first-admission
-                    # pool build, prefix-page mapping) is per-REQUEST
-                    # slot-lifecycle work, not the per-token decode
-                    # steady state this rule protects — between
-                    # admissions steps re-upload nothing (cached tables)
-                    # tpulint: disable=device-transfer-in-hot-loop
-                    progress = self._admit_ready(now) > 0 or progress
-                active = [s for s, r in enumerate(self._slots)
-                          if r is not None]
-                if not active:
-                    return progress
-                if self._speculation is not None:
-                    self._step_speculative(active)
-                else:
-                    self._step_plain(active)
-            except Exception as e:  # noqa: BLE001 — fail waiters, not hang
-                self._handles[SERVING_ERRORS].inc()
-                if self._recover(e):
-                    return True
-                self._break(e)
-                return False
-            return True
+            # the cycle is a flat sequence of monitoring spans (module
+            # docstring, "Phases"), opened where it finds work
+            with phases():
+                return self._cycle()
+
+    def _cycle(self) -> bool:
+        now = time.monotonic()
+        if self.active_slots():
+            next_phase("engine.reap")   # a decode cycle follows
+        progress = self._reap(now) > 0
+        try:
+            if self._overload is not None:
+                progress = self._apply_overload(now) or progress
+            if not self._draining:
+                # admission staging (prefill buffers, first-admission
+                # pool build, prefix-page mapping) is per-REQUEST
+                # slot-lifecycle work, not the per-token decode
+                # steady state this rule protects — between
+                # admissions steps re-upload nothing (cached tables)
+                # tpulint: disable=device-transfer-in-hot-loop
+                progress = self._admit_ready(now) > 0 or progress
+            active = [s for s, r in enumerate(self._slots)
+                      if r is not None]
+            if not active:
+                return progress
+            next_phase("decode.input")
+            if self._speculation is not None:
+                self._step_speculative(active)
+            else:
+                self._step_plain(active)
+        except Exception as e:  # noqa: BLE001 — fail waiters, not hang
+            self._handles[SERVING_ERRORS].inc()
+            if self._recover(e):
+                return True
+            self._break(e)
+            return False
+        return True
 
     def _recover(self, exc: BaseException) -> bool:
         """Hand a step-cycle fault to the supervisor (if any): True =
@@ -846,6 +960,7 @@ class GenerationEngine:
     def _step_plain(self, active) -> None:
         """One canonical [S, V, 1] decode dispatch + one draw per row."""
         probs = self._dispatch_step()
+        next_phase("engine.sample")
         now = time.monotonic()
         for s in active:
             req = self._slots[s]
@@ -910,8 +1025,10 @@ class GenerationEngine:
         self._sync_accounting()
         tp = self._run_dispatch(
             lambda: verify_tokens(self.net, chunk, self.V,
-                                  donate_state=self._donate),
+                                  donate_state=self._donate,
+                                  io=self._io["decode"]),
             width=1 + k)
+        next_phase("engine.sample")
         now = time.monotonic()
         amounts = np.full(self.slots, 1 + k, np.int32)  # free rows: all
         for s in riders:
@@ -1032,6 +1149,7 @@ class GenerationEngine:
             req = self._pending.pop(admissible=gate)
             if req is None:
                 break
+            next_phase("engine.admit")
             self._seating = req
             n += 1
             if self._fail_if_dead(req, now, "in the admission queue"):
@@ -1129,6 +1247,7 @@ class GenerationEngine:
             net._stream_pos_map = {n: hit_len
                                    for n in self._graph_vertices}
 
+    @phases()
     def _admit_one(self, req: GenerationRequest, slot: int,
                    readmit: bool = False) -> None:
         """Prefill `req` at batch 1 and join it to the arena at `slot`.
@@ -1146,6 +1265,7 @@ class GenerationEngine:
         admission once). The next dispatch recomputes the identical
         next-token distribution, so the stream continues bit-identical
         to an unperturbed run."""
+        next_phase("engine.admit")
         net = self.net
         saved_state = dict(net.state)
         saved_acct = self._save_accounting()
@@ -1171,16 +1291,14 @@ class GenerationEngine:
                 # just starts kv_pos past the shared pages, no dense
                 # gather/scatter round trip
                 self._install_prime_paged_state(table, hit_len)
-                p0 = prime_prompt(net, prime_ids[hit_len:], self.V,
-                                  padded=self._prime_padded)
             elif hit_len:
                 self._install_prefix(table, hit_len)
-                p0 = prime_prompt(net, prime_ids[hit_len:], self.V,
-                                  padded=self._prime_padded)
-            else:
-                p0 = prime_prompt(net, prime_ids, self.V,
-                                  padded=self._prime_padded)
+            self._prefill_fed += fed
+            p0 = prime_prompt(net, prime_ids[hit_len:], self.V,
+                              padded=self._prime_padded,
+                              io=self._io["prefill"])
             req.trace.record("prefill_end")
+            next_phase("engine.seat")
             primed_pos = self._net_pos(net)
         except Exception as e:  # noqa: BLE001 — per-request failure domain
             net.state = saved_state
@@ -1916,7 +2034,8 @@ class GenerationEngine:
         self._sync_accounting()
         probs = self._run_dispatch(
             lambda: step_tokens(self.net, toks, self.V,
-                                donate_state=self._donate))
+                                donate_state=self._donate,
+                                io=self._io["decode"]))
         for s, req in enumerate(self._slots):
             if req is not None:
                 self._row_pos[s] += 1
@@ -1970,6 +2089,7 @@ class GenerationEngine:
         if self._pool is not None:
             self._kv_traffic(self._kv_dispatch_bytes(width))
         self._dispatches += 1
+        self._dispatch_rows += self.active_slots()
         return out
 
     # ------------------------------------------------------------------

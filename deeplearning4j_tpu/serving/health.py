@@ -56,6 +56,18 @@ SERVING_SPEC_ACCEPTANCE = "dl4jtpu_serving_spec_acceptance_ratio"
 SERVING_KV_BYTES_MOVED = "dl4jtpu_serving_kv_bytes_moved_total"
 SERVING_DISPATCH_LATENCY = "dl4jtpu_serving_decode_dispatch_seconds"
 
+#: counts at the engine cycle's phase boundaries, read at scrape time
+#: from the totals engine.health() keeps under ``decode_dispatch.rows``,
+#: ``prefill`` and ``host_io``: active rows summed over decode dispatches
+#: (occupancy = rows / (dispatches x slots)); prompt tokens per prime by
+#: ``kind`` (fed / bucket = the padded width dispatched; tokens the prefix
+#: cache served instead: SERVING_PREFIX_REUSED_TOKENS); bytes of the numpy
+#: arrays that cross the host boundary around ``rnn_time_step``, by
+#: ``phase`` (decode / prefill) and ``direction`` (h2d / d2h)
+SERVING_DECODE_ROWS = "dl4jtpu_serving_decode_rows_total"
+SERVING_PREFILL_TOKENS = "dl4jtpu_serving_prefill_tokens_total"
+SERVING_HOST_IO_BYTES = "dl4jtpu_serving_host_io_bytes_total"
+
 #: fleet layer (serving/fleet/router.py registers these): multi-replica
 #: routing, prefix-affinity placement, ledger migration, autoscaling.
 #: ``fleet`` labels distinguish routers; ``replica`` / ``cause`` /

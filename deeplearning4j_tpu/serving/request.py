@@ -387,6 +387,12 @@ class GenerationStream:
         return list(self._ids)
 
     @property
+    def n_generated(self) -> int:
+        """Tokens generated so far, without the snapshot ``ids`` copies:
+        what a client that polls many streams reads."""
+        return len(self._ids) - len(self.prompt)
+
+    @property
     def generated(self) -> List[int]:
         """Snapshot of the tokens generated so far (prompt excluded)."""
         return list(self._ids[len(self.prompt):])

@@ -25,8 +25,42 @@ def _one_hot(rows: np.ndarray, vocab: int) -> np.ndarray:
     return x
 
 
-def _probs(out) -> np.ndarray:
-    return np.asarray(out[0] if isinstance(out, (list, tuple)) else out)
+class RoundTrip:
+    """What a caller may watch of one host round trip through
+    ``rnn_time_step``: its three steps — ``"input"`` built on the host,
+    ``"forward"`` dispatched, the result's ``"fetch"`` — and the numpy
+    arrays that cross. This one watches nothing. The serving engine hands
+    in its own as `io` (``serving.engine._HostIO``: a phase span per step,
+    the bytes counted) for the one dispatch its cycle makes, on the
+    cycling thread; any other caller on that thread (a draft net) passes
+    none and stays inside the phase that is open."""
+
+    def step(self, name: str) -> None:
+        pass
+
+    def h2d(self, x: np.ndarray) -> None:
+        pass
+
+    def d2h(self, p: np.ndarray) -> None:
+        pass
+
+
+_UNWATCHED = RoundTrip()
+
+
+def _probs(out, io: RoundTrip = _UNWATCHED) -> np.ndarray:
+    p = np.asarray(out[0] if isinstance(out, (list, tuple)) else out)
+    io.d2h(p)
+    return p
+
+
+def _forward(net, x, io: RoundTrip, **kw):
+    """``net.rnn_time_step(x)`` on a host-built input: ``io.h2d(x)`` sees
+    the numpy array handed to the device here, ``io.d2h(p)`` (in
+    ``_probs``) the numpy array that comes back."""
+    io.step("forward")
+    io.h2d(x)
+    return net.rnn_time_step(x, **kw)
 
 
 def filter_probs(probs, temperature,
@@ -234,15 +268,18 @@ def _prime_chunks(n: int, chunk_max: int = None):
     return out
 
 
-def _prime(net, ids, vocab: int, chunk_max: int = None):
+def _prime(net, ids, vocab: int, chunk_max: int = None,
+           io: RoundTrip = _UNWATCHED):
     """Feed the seed through rnn_time_step in bucketed chunks; returns
     the final chunk's output (its last position is the next-token
     distribution). Stateful streaming makes chunked == one-shot priming
-    (pinned by the streaming-vs-full-forward tests)."""
+    (pinned by the streaming-vs-full-forward tests). `io` sees input
+    and forward once per chunk."""
     at, out = 0, None
     for c in _prime_chunks(len(ids), chunk_max):
-        out = net.rnn_time_step(
-            _one_hot(np.asarray(ids[at:at + c])[None, :], vocab))
+        io.step("input")
+        x = _one_hot(np.asarray(ids[at:at + c])[None, :], vocab)
+        out = _forward(net, x, io)
         at += c
     return out
 
@@ -287,7 +324,8 @@ def _prime_bucket_cap(net):
     return cap
 
 
-def _prime_padded(net, ids, vocab: int, chunk_max: int = None):
+def _prime_padded(net, ids, vocab: int, chunk_max: int = None,
+                  io: RoundTrip = _UNWATCHED):
     """Single-dispatch priming: LEFT-pad the prompt to its power-of-two
     bucket and feed ONE rnn_time_step(pad_left=...) with packed pad
     accounting — pads never enter the streaming caches nor consume
@@ -298,21 +336,23 @@ def _prime_padded(net, ids, vocab: int, chunk_max: int = None):
     prompt longer than that capacity — legal for rolling-window streams,
     whose length is unbounded — falls back to chunked priming, which has
     no minimum chunk shape."""
+    io.step("input")
     L = len(ids)
     P = _width_bucket(L)
     cap = _prime_bucket_cap(net)
     if cap is not None and P > cap:
         if cap < L:            # no padded bucket can hold this prompt
-            return _prime(net, ids, vocab, chunk_max)
+            return _prime(net, ids, vocab, chunk_max, io)
         P = cap                # pad exactly to capacity: still one shape
     pad = P - L
     x = _one_hot(np.asarray([0] * pad + list(ids))[None, :], vocab)
     x[:, :, :pad] = 0.0       # pads carry no token (masked anyway)
-    return net.rnn_time_step(x, pad_left=pad)
+    return _forward(net, x, io, pad_left=pad)
 
 
 def prime_prompt(net, ids, vocab_size: int, padded: bool = False,
-                 chunk_max: Optional[int] = None) -> np.ndarray:
+                 chunk_max: Optional[int] = None,
+                 io: RoundTrip = _UNWATCHED) -> np.ndarray:
     """Prefill: feed the whole prompt through the carried streaming
     state and return the next-token distribution [V]. `padded=True`
     primes in ONE left-padded bucketed dispatch (_prime_padded);
@@ -320,14 +360,19 @@ def prime_prompt(net, ids, vocab_size: int, padded: bool = False,
     by the padded-prime tests. Does NOT clear previous state: the
     caller owns the stream lifecycle (sample_stream clears first; the
     serving engine primes into a fresh state it then joins to its slot
-    arena)."""
-    out = (_prime_padded(net, ids, vocab_size, chunk_max) if padded
-           else _prime(net, ids, vocab_size, chunk_max))
-    return _probs(out)[0, :, -1]
+    arena). `io` (``RoundTrip``) sees the prime as input (padding, the
+    host-built one-hot), then forward (upload and launch; chunked priming
+    alternates the two per chunk), then one fetch (the result coming
+    back), which is left for the caller to end."""
+    out = (_prime_padded(net, ids, vocab_size, chunk_max, io) if padded
+           else _prime(net, ids, vocab_size, chunk_max, io))
+    io.step("fetch")
+    return _probs(out, io)[0, :, -1]
 
 
 def step_tokens(net, tokens, vocab_size: int,
-                donate_state: bool = False) -> np.ndarray:
+                donate_state: bool = False,
+                io: RoundTrip = _UNWATCHED) -> np.ndarray:
     """One incremental decode step for a batch of rows: feed one token
     per row in a single dispatch, return the next-token distributions
     [B, V]. The per-step unit shared by sample_stream (B=1),
@@ -339,15 +384,27 @@ def step_tokens(net, tokens, vocab_size: int,
     ``net.state`` and donates them into the dispatch, so the one-token
     append updates the pool IN PLACE (TPU/GPU; a no-op on CPU). The
     caller must treat the pre-call state as consumed — the state the
-    net carries after the call is the only live copy."""
-    out = net.rnn_time_step(
-        _one_hot(np.asarray(tokens, np.int64)[:, None], vocab_size),
-        donate_state=donate_state)
-    return _probs(out)[:, :, -1]
+    net carries after the call is the only live copy.
+
+    `io` (``RoundTrip``) sees input while the one-hot is built, forward
+    around the dispatch, fetch from the result coming back — left for the
+    caller to end. A cycle passes it to ONE such call, on its own
+    thread."""
+    return _decode(net, np.asarray(tokens, np.int64)[:, None], vocab_size,
+                   donate_state, io)[:, :, -1]
+
+
+def _decode(net, rows, vocab_size: int, donate_state: bool, io):
+    io.step("input")
+    x = _one_hot(rows, vocab_size)
+    out = _forward(net, x, io, donate_state=donate_state)
+    io.step("fetch")
+    return _probs(out, io)
 
 
 def verify_tokens(net, chunks, vocab_size: int,
-                  donate_state: bool = False) -> np.ndarray:
+                  donate_state: bool = False,
+                  io: RoundTrip = _UNWATCHED) -> np.ndarray:
     """One widened verify forward for a batch of token chunks: feed
     `chunks` [B, W] (W = 1 + gamma for engine speculation) in a single
     dispatch and return ALL per-position next-token distributions
@@ -357,11 +414,10 @@ def verify_tokens(net, chunks, vocab_size: int,
     fixed-width chunk serves rows with fewer real proposals (the
     uniform-chunk trick of speculative_sample_batch). `donate_state`
     follows step_tokens' paged-state protocol — the widened chunk runs
-    the same paged append/attend path at width W."""
-    out = net.rnn_time_step(
-        _one_hot(np.asarray(chunks, np.int64), vocab_size),
-        donate_state=donate_state)
-    return _probs(out)
+    the same paged append/attend path at width W; `io` as
+    step_tokens."""
+    return _decode(net, np.asarray(chunks, np.int64), vocab_size,
+                   donate_state, io)
 
 
 def accept_proposals(proposals, p_dists, q_dists, p_bonus, rng

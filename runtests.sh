@@ -101,6 +101,13 @@ lane elastic python -m pytest tests/test_elastic.py -q -p no:cacheprovider
 # guard across staggered admissions
 lane serving python -m pytest tests/test_serving_engine.py -q -p no:cacheprovider
 
+# tier-1 phases lane: the engine cycle as flat monitoring spans
+# (monitoring.phases) and the health() counters at the same boundaries —
+# scripted runs against hand counts, no span on an idle poll, zero
+# compiles with spans on, one real profiler trace read with the
+# benchmark's reduction
+lane phases python -m pytest tests/test_serving_phases.py -q -p no:cacheprovider
+
 # tier-1 serving-survivability lane: supervised recovery (bit-identical
 # continuation after arena rebuilds), restart-budget escalation,
 # SLO shedding / early rejection / brownout, draining, and the

@@ -2,9 +2,8 @@
 
 Covers: registry thread-safety, Prometheus text exposition, span
 nesting/exception paths, the jit-recompile watcher across a forced
-retrace, the /metrics route on UIServer, the phase-detail split step's
-numerical parity with the fused step, and the no-new-retraces guard for
-the instrumented fit path.
+retrace, the /metrics route on UIServer, flat phase sequences, and the
+no-new-retraces guard for the instrumented fit path.
 """
 
 import json
@@ -72,6 +71,24 @@ class TestRegistry:
         h.observe(50.0)
         assert h.count() == 3
         assert h.sum() == 55.5
+
+    def test_a_counter_can_read_its_owners_total_at_scrape_time(self):
+        """One store: the owner keeps the count, the registry reads it
+        when collected (value, total, snapshot, exposition)."""
+        r = MetricsRegistry()
+        owned = {"n": 7}
+        c = r.counter("owned_total", "help", ("k",))
+        c.set_function(lambda: owned["n"], k="a")
+        c.inc(2, k="b")
+        assert c.value(k="a") == 7.0 and c.total() == 9.0
+        owned["n"] = 11
+        assert c.value(k="a") == 11.0
+        with pytest.raises(ValueError):
+            c.inc(k="a")                      # read-only
+        samples = r.snapshot()["owned_total"]["samples"]
+        assert {s["labels"]["k"]: s["value"] for s in samples} == \
+            {"a": 11.0, "b": 2.0}
+        assert 'owned_total{k="a"} 11' in render_prometheus(r)
 
     def test_get_or_create_is_idempotent_and_type_checked(self):
         r = MetricsRegistry()
@@ -301,31 +318,6 @@ class TestFitTelemetry:
             model="ComputationGraph") == pytest.approx(g.score_value)
 
 
-class TestPhaseDetail:
-    def test_split_spans_populate_and_match_fused_numerics(self):
-        import jax
-        x, y = make_data()
-        net_fused, net_split = make_net(7), make_net(7)
-        net_fused.fit(x, y, epochs=1, batch_size=16)
-        h = span_histogram()
-        f0, b0, u0 = (h.count(span=s)
-                      for s in ("forward", "backward", "update"))
-        monitoring.set_phase_detail(True)
-        try:
-            net_split.fit(x, y, epochs=1, batch_size=16)
-        finally:
-            monitoring.set_phase_detail(False)
-        assert h.count(span="forward") - f0 == 4
-        assert h.count(span="backward") - b0 == 4
-        assert h.count(span="update") - u0 == 4
-        # value_and_grad IS vjp: the split path must train identically
-        for a, b in zip(jax.tree_util.tree_leaves(net_fused.params),
-                        jax.tree_util.tree_leaves(net_split.params)):
-            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                       rtol=1e-6, atol=1e-7)
-        assert net_fused.score_value == pytest.approx(net_split.score_value)
-
-
 class TestNoRetraceGuard:
     """Observability must not cost recompiles: the instrumented fit path
     (spans on, default) compiles exactly what the uninstrumented path
@@ -370,8 +362,8 @@ class TestMetricsRoute:
             text = req.read().decode()
         finally:
             server.stop()
-        # per-phase span histograms (all four declared phases + fused step)
-        for phase in ("etl", "forward", "backward", "update", "step"):
+        # the fit loops' declared span histograms
+        for phase in ("etl", "step", "listener"):
             assert f'dl4jtpu_span_seconds_bucket{{span="{phase}"' in text
         assert "dl4jtpu_score{" in text
         assert "dl4jtpu_samples_per_sec{" in text

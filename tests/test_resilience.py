@@ -401,22 +401,6 @@ class TestSentinelFitLoops:
         assert params_finite(m)
         assert acct_of(m).bad_steps >= 1
 
-    def test_phase_detail_path_skips_params_state_and_counts(self):
-        """The split-step debug path (set_phase_detail) guards params,
-        optimizer state AND the forward's state update on a bad step."""
-        from deeplearning4j_tpu.monitoring import set_phase_detail
-        x, y = data(32)
-        net = mlp()
-        set_phase_detail(True)
-        try:
-            net.fit(self._poisoned(x, y, n=0), epochs=1, batch_size=16)
-        finally:
-            set_phase_detail(False)
-        assert params_finite(net)
-        assert all(bool(np.isfinite(np.asarray(v)).all())
-                   for layer in net.state.values() for v in layer.values())
-        assert acct_of(net).skipped_updates == 1
-
     def test_record_policy_counts_but_applies(self):
         x, y = data(32)
         net = mlp()
