@@ -17,7 +17,7 @@ Flagged inside a lock-holding ``with`` block:
 - calls to module-local functions decorated ``@jax.jit`` (directly or
   via ``partial(jax.jit, ...)``);
 - the repo's canonical dispatch entry points (``rnn_time_step``,
-  ``util.decoding.prime_prompt/step_tokens/verify_tokens``,
+  ``util.decoding.prime_prompt/step_tokens/step_greedy/verify_tokens``,
   ``serving.paging.gather_pages/scatter_pages``);
 - blocking device syncs: ``block_until_ready`` (function or method),
   ``jax.device_get``, ``jax.effects_barrier``.
@@ -43,6 +43,7 @@ _LOCKISH = re.compile(r"lock|mutex", re.IGNORECASE)
 _DISPATCH_CALLS = {
     "deeplearning4j_tpu.util.decoding.prime_prompt",
     "deeplearning4j_tpu.util.decoding.step_tokens",
+    "deeplearning4j_tpu.util.decoding.step_greedy",
     "deeplearning4j_tpu.util.decoding.verify_tokens",
     "deeplearning4j_tpu.serving.paging.gather_pages",
     "deeplearning4j_tpu.serving.paging.scatter_pages",

@@ -27,11 +27,14 @@ shape work (the framework-overhead lesson of arXiv:2001.04206):
   handle as each dispatch retires — TTFT is queue-wait + one prefill,
   not a batch drain.
 
-Greedy (top_k=1) per-request outputs are bit-identical to one-shot
-``sample_stream`` with the same rng (test-pinned): the arena feeds each
-request exactly the token sequence a dedicated stream would, row
-independence makes the math per-slot, and each request draws from its
-OWN rng in generation order.
+Per-request outputs are bit-identical to one-shot ``sample_stream``
+with the same rng (test-pinned): the arena feeds each request exactly
+the token sequence a dedicated stream would, row independence makes the
+math per-slot, a sampling request draws from its OWN rng in generation
+order, and a greedy one (top_k=1) follows ``util.decoding``'s greedy
+rule — the lowest-index maximum of the distribution the program
+returned, no random numbers consumed — which a plain cycle answers for
+all S rows with one on-device argmax (``util.decoding.greedy_ids``).
 
 Exactness conditions are ``sample_stream_batch``'s: recurrent (LSTM)
 state or attention with rope / no positions. Models with LEARNED
@@ -111,16 +114,28 @@ the host-built prompt tensor; upload and launch; the result coming
 back), ``engine.seat`` (first draw, arena join, page-table update),
 ``decode.input`` (token vector, position mirrors, paged-view install,
 under speculation the host draft, the one-hot), ``decode.forward`` (the
-dispatch), ``decode.fetch`` (the distributions coming back, pool
-extract), ``engine.sample`` (per-row draw or acceptance walk, push,
-retire). One of each per cycle; the admission phases once per admitted
-request (chunked priming alternates input and forward per chunk). The
-names are ``PHASES``; the sequence opens when a poll finds work (a seated
+dispatch, and the greedy argmax queued behind it), ``decode.fetch`` (the
+host waits for the device and copies: the [S] ids of a plain cycle —
+its one required sync — and the [S, V] block only when a seated request
+samples; under speculation the distributions; pool extract),
+``engine.sample`` (selection — a greedy row reads its id, a sampling
+row draws from its row of the block, speculation walks acceptance —
+push, rollup, stop test, retire). A request with ``top_k == 1`` is
+greedy whatever its temperature and top_p: nothing it emits depends on
+its Generator, whose state (``rng_state_payload`` in the ledger) stays
+what it was at submit; before PR 27 such a row went through the filter
+and consumed one ``rng.choice`` per token. One of each per cycle; the
+admission phases once per admitted request (chunked priming alternates
+input and forward per chunk). The names are ``PHASES``; the sequence opens when a poll finds work (a seated
 row, or a request popped), so an idle poll records nothing. ``health()``
-counts at the same boundaries: ``decode_dispatch.rows``, ``prefill``
-(tokens fed, padded widths dispatched; tokens the prefix cache served
-instead are ``prefix_cache.reused_tokens``) and ``host_io`` (bytes of
-the numpy arrays that cross around ``rnn_time_step``).
+counts at the same boundaries: ``decode_dispatch.rows``, ``sample``
+(``greedy_rows`` that took the device's id, ``drawn_rows`` that sampled
+from their row, ``block_fetches`` = plain cycles that fetched [S, V]),
+``prefill`` (tokens fed, padded widths dispatched; tokens the prefix
+cache served instead are ``prefix_cache.reused_tokens``) and ``host_io``
+(bytes of the numpy arrays that cross around ``rnn_time_step``).
+Between cycles, in no span: the serving loop's ``HANDOFF_WAIT_S`` park
+after a cycle that freed a slot.
 """
 
 from __future__ import annotations
@@ -152,14 +167,14 @@ from deeplearning4j_tpu.serving.errors import (
     EngineShutdown, InferenceTimeout, RequestCancelled,
     ServingOverloaded, ServingQueueFull)
 from deeplearning4j_tpu.serving.health import (
-    SERVING_ACTIVE_SLOTS, SERVING_BROWNOUT_LEVEL,
+    SERVING_ACTIVE_SLOTS, SERVING_BLOCK_FETCHES, SERVING_BROWNOUT_LEVEL,
     SERVING_DEADLINE_EXCEEDED, SERVING_DECODE_ROWS,
     SERVING_DISPATCH_LATENCY, SERVING_DRAINING, SERVING_EARLY_REJECTED,
     SERVING_ERRORS, SERVING_HOST_IO_BYTES, SERVING_KV_BYTES_MOVED,
     SERVING_KV_PAGES_TOTAL, SERVING_KV_PAGES_USED, SERVING_PREFILL_TOKENS,
     SERVING_PREFIX_HITS, SERVING_PREFIX_MISSES,
     SERVING_PREFIX_REUSED_TOKENS, SERVING_QUEUE_REJECTED,
-    SERVING_QUEUE_WAIT, SERVING_REQUESTS, SERVING_SHED,
+    SERVING_QUEUE_WAIT, SERVING_REQUESTS, SERVING_SAMPLE_ROWS, SERVING_SHED,
     SERVING_SPEC_ACCEPTANCE, SERVING_TOKENS, SERVING_TPOT, SERVING_TTFT,
     register_serving_metrics, scrape_probe)
 from deeplearning4j_tpu.serving.overload import (
@@ -177,9 +192,9 @@ from deeplearning4j_tpu.serving.request import (
     rng_state_payload)
 from deeplearning4j_tpu.serving.scheduler import AdmissionQueue
 from deeplearning4j_tpu.util.decoding import (
-    RoundTrip, _check_seed, _stream_layers, _width_bucket,
-    accept_proposals, draw, filter_probs, prime_prompt, step_tokens,
-    stop_reason, verify_tokens)
+    ArgmaxRow, RoundTrip, _check_seed, _stream_layers, _width_bucket,
+    accept_proposals, draw, filter_probs, prime_prompt, selects_one,
+    step_greedy, stop_reason, verify_tokens)
 
 log = logging.getLogger(__name__)
 
@@ -227,6 +242,19 @@ PHASES = ("engine.reap", "engine.admit",
           "engine.seat",
           "decode.input", "decode.forward", "decode.fetch",
           "engine.sample")
+
+
+#: how long the serving loop parks, after a cycle that freed a slot and
+#: with nothing queued, for the next request to arrive before it commits
+#: to a whole decode cycle without it. A caller with one request
+#: outstanding (a completion plug-in, a chat turn, a batch pipeline) sends
+#: its next the moment the last one ends — a millisecond or two after the
+#: cycle that retired it — and the admission check of the following cycle
+#: comes sooner than that now that selection no longer takes tens of
+#: milliseconds; a request that misses it waits a decode cycle, and may
+#: queue behind another caller's prime. Event-driven (it ends when a
+#: submit lands), and only ever paid with an empty queue.
+HANDOFF_WAIT_S = 0.005
 
 
 class _HostIO(RoundTrip):
@@ -517,6 +545,10 @@ class GenerationEngine:
         #: (the padded widths they dispatched: ``_io["prefill"].width``)
         self._dispatch_rows = 0
         self._prefill_fed = 0
+        #: how plain decode cycles selected (health()["sample"]): rows
+        #: that took the device's argmax, rows that sampled from their row,
+        #: cycles that fetched the [S, V] block for the latter
+        self._greedy_rows = self._drawn_rows = self._block_fetches = 0
         self._io = {"decode": _HostIO("decode"),
                     "prefill": _HostIO("prefill", widths=True)}
         self._prefill_chaos = prefill_chaos
@@ -544,6 +576,9 @@ class GenerationEngine:
         #: fault in that window can fail (or recover) it instead of
         #: stranding its handle with no terminal event
         self._seating: Optional[GenerationRequest] = None
+        #: slots freed so far: the serving loop parks after a cycle that
+        #: moved it (``HANDOFF_WAIT_S``)
+        self._retirements = 0
         #: traces of recently retired requests — the flight recorder's
         #: "last N requests" context when the engine breaks (in-flight
         #: requests' traces are read live off the slots)
@@ -592,6 +627,22 @@ class GenerationEngine:
             SERVING_DECODE_ROWS, "Active rows summed over decode/verify "
             "dispatches", ("model",)).set_function(
             scrape_probe(self, lambda e: e._dispatch_rows), **lab)
+        picked = r.counter(
+            SERVING_SAMPLE_ROWS, "Rows of plain decode dispatches by how "
+            "their token was selected: greedy (the device's argmax) or "
+            "drawn (sampled from its row of the fetched block)",
+            ("model", "kind"))
+        picked.set_function(
+            scrape_probe(self, lambda e: e._greedy_rows),
+            kind="greedy", **lab)
+        picked.set_function(
+            scrape_probe(self, lambda e: e._drawn_rows),
+            kind="drawn", **lab)
+        r.counter(
+            SERVING_BLOCK_FETCHES, "Plain decode cycles that fetched the "
+            "[S, V] distributions because a row samples",
+            ("model",)).set_function(
+            scrape_probe(self, lambda e: e._block_fetches), **lab)
         tokens = r.counter(
             SERVING_PREFILL_TOKENS, "Prompt tokens per prime: fed, and "
             "the padded bucket dispatched", ("model", "kind"))
@@ -734,6 +785,10 @@ class GenerationEngine:
                        self._dispatch_s_total * 1e3
                        / max(1, self._dispatches), 3),
                    "rows": self._dispatch_rows},
+               "sample": {
+                   "greedy_rows": self._greedy_rows,
+                   "drawn_rows": self._drawn_rows,
+                   "block_fetches": self._block_fetches},
                "prefill": {
                    "fed_tokens": self._prefill_fed,
                    "bucket_tokens": self._io["prefill"].width},
@@ -958,15 +1013,20 @@ class GenerationEngine:
         return bool(victims)
 
     def _step_plain(self, active) -> None:
-        """One canonical [S, V, 1] decode dispatch + one draw per row."""
-        probs = self._dispatch_step()
+        """One canonical [S, V, 1] decode dispatch, then one ``draw`` per
+        row: from the device's id where the request is greedy
+        (``selects_one``), from its row of the fetched block where it
+        samples."""
+        ids, probs = self._dispatch_step()
         next_phase("engine.sample")
         now = time.monotonic()
         for s in active:
             req = self._slots[s]
             if req is None:        # retired by the capacity guard
                 continue
-            tok = draw(probs[s], req.temperature, req.rng,
+            row = (ArgmaxRow(ids[s], self.V) if selects_one(req.top_k)
+                   else probs[s])
+            tok = draw(row, req.temperature, req.rng,
                        top_k=req.top_k, top_p=req.top_p)
             if req.last_token_t is not None:
                 self._tpot_hist.observe(now - req.last_token_t)
@@ -2019,28 +2079,37 @@ class GenerationEngine:
     def _dispatch_step(self):
         """ONE jitted decode dispatch advancing every active slot (free
         rows feed token 0; their outputs are discarded, their writes
-        drop). Slots at streaming capacity retire first — they cannot
-        consume another position."""
+        drop), with the greedy selection queued behind it on the device.
+        Returns ``(ids, probs)``: the [S] ids every cycle, the [S, V]
+        block only when a seated request samples (else None — it stays
+        on the device). Slots at streaming capacity retire first — they
+        cannot consume another position."""
         if self._cap is not None:
             for s, req in enumerate(self._slots):
                 if req is not None and self._row_pos[s] >= self._cap:
                     self._retire(s, "capacity")
         toks = np.zeros(self.slots, np.int64)
+        live = drawn = 0
         for s, req in enumerate(self._slots):
             if req is not None:
                 toks[s] = req.pending_token
-        if not any(r is not None for r in self._slots):
-            return None     # everything retired at the capacity guard
+                live += 1
+                drawn += not selects_one(req.top_k)
+        if not live:
+            return None, None   # everything retired at the capacity guard
         self._sync_accounting()
-        probs = self._run_dispatch(
-            lambda: step_tokens(self.net, toks, self.V,
+        picked = self._run_dispatch(
+            lambda: step_greedy(self.net, toks, self.V,
                                 donate_state=self._donate,
-                                io=self._io["decode"]))
+                                io=self._io["decode"], block=drawn > 0))
+        self._greedy_rows += live - drawn
+        self._drawn_rows += drawn
+        self._block_fetches += drawn > 0
         for s, req in enumerate(self._slots):
             if req is not None:
                 self._row_pos[s] += 1
         self._sync_accounting()
-        return probs
+        return picked
 
     def _run_dispatch(self, fn, width: int = 1):
         """The ONE paged/chaos/retry wrapper around a decode or verify
@@ -2280,6 +2349,7 @@ class GenerationEngine:
         req = self._slots[slot]
         self._slots[slot] = None
         self._row_pos[slot] = 0
+        self._retirements += 1
         if self._pool is not None:
             # pages return to the pool immediately; blocks the prefix
             # cache also references stay resident at the cache's own
@@ -2508,6 +2578,7 @@ class GenerationEngine:
         return self
 
     def _engine_loop(self):
+        retired = self._retirements
         try:
             while not self._stop.is_set():
                 if not self.step():
@@ -2517,6 +2588,10 @@ class GenerationEngine:
                         time.sleep(0.02)
                     else:
                         self._pending.wait(0.02)
+                elif self._retirements != retired:
+                    retired = self._retirements
+                    if not self._draining:
+                        self._pending.wait(HANDOFF_WAIT_S)
         except Exception as e:  # noqa: BLE001 — strand no waiters
             log.exception("GenerationEngine loop died")
             self._break(e)

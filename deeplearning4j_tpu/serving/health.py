@@ -67,6 +67,14 @@ SERVING_DISPATCH_LATENCY = "dl4jtpu_serving_decode_dispatch_seconds"
 SERVING_DECODE_ROWS = "dl4jtpu_serving_decode_rows_total"
 SERVING_PREFILL_TOKENS = "dl4jtpu_serving_prefill_tokens_total"
 SERVING_HOST_IO_BYTES = "dl4jtpu_serving_host_io_bytes_total"
+#: how plain decode cycles selected their tokens (engine.health() keeps
+#: the totals under ``sample``): rows by ``kind`` — greedy (top_k == 1:
+#: the id came from the on-device argmax) or drawn (sampled by
+#: ``util.decoding.draw`` from its row of the block) — and the cycles
+#: that fetched the ``[S, V]`` distributions to the host because some
+#: row samples
+SERVING_SAMPLE_ROWS = "dl4jtpu_serving_sample_rows_total"
+SERVING_BLOCK_FETCHES = "dl4jtpu_serving_block_fetches_total"
 
 #: fleet layer (serving/fleet/router.py registers these): multi-replica
 #: routing, prefix-affinity placement, ledger migration, autoscaling.

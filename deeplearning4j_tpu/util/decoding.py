@@ -6,12 +6,27 @@ rnnTimeStep-based generation, MultiLayerNetwork.java rnnTimeStep) and
 attention KV caches alike. Beams ride the batch dimension; pruning
 gathers the carried state with reorder_stream_state so surviving beams
 continue from their parent's caches.
+
+The greedy rule. A row whose filter leaves one token (``top_k == 1``;
+temperature is monotone and ``top_p`` keeps the first of any sorted
+distribution, so neither changes which entry that is) takes the
+LOWEST-INDEX MAXIMUM of the distribution the program returned and
+consumes no random numbers: ``selects_one`` is the test, ``draw``
+answers it with ``np.argmax``, ``filter_probs`` with that one-hot, and
+``step_greedy`` with ``jnp.argmax`` over the same float32 array on the
+device (``greedy_ids``) — the same index on the same values, so the
+one-shot decoders, the speculative acceptance walk and the serving
+engine agree token for token, exact ties included. Nothing a greedy row
+emits depends on its Generator, whose state stays what the caller
+handed in.
 """
 
 from __future__ import annotations
 
 from typing import List, Optional, Tuple
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from deeplearning4j_tpu.nn.conf.layers import reorder_stream_state
@@ -67,7 +82,8 @@ def filter_probs(probs, temperature,
                  top_k=None, top_p=None) -> np.ndarray:
     """The sampling distribution actually drawn from: temperature
     rescales first, then `top_k` keeps exactly the k most probable
-    tokens, then `top_p` (nucleus) keeps the smallest prefix of the
+    tokens (k = 1: the lowest-index maximum of `probs`, the module's
+    greedy rule), then `top_p` (nucleus) keeps the smallest prefix of the
     sorted distribution whose mass reaches p (always at least one
     token); survivors renormalize. Shared by draw() and the
     speculative-decoding acceptance rule (which needs the filtered
@@ -132,21 +148,34 @@ def _filter_rows(p2, temperature, top_k, top_p):
             # hot path, so stay O(V): partition out the top kmax
             # candidates, sort only that slice, then cut each row at its
             # own k. Off rows bypass bit-exactly: keep all, divide by 1.
-            kmax = int(krow[row_on].max())
-            part = np.argpartition(p, V - kmax, axis=-1)[:, V - kmax:]
-            vals = np.take_along_axis(p, part, axis=-1)
-            order = np.take_along_axis(
-                part, np.argsort(vals, axis=-1)[:, ::-1], axis=-1)
+            one = krow == 1
             keep = np.zeros((B, V), bool)
-            np.put_along_axis(
-                keep, order,
-                np.arange(kmax)[None, :] < krow[:, None], axis=-1)
+            many = row_on & ~one
+            if many.any():
+                kmax = int(krow[many].max())
+                part = np.argpartition(p, V - kmax, axis=-1)[:, V - kmax:]
+                vals = np.take_along_axis(p, part, axis=-1)
+                order = np.take_along_axis(
+                    part, np.argsort(vals, axis=-1)[:, ::-1], axis=-1)
+                np.put_along_axis(
+                    keep, order,
+                    np.arange(kmax)[None, :] < krow[:, None], axis=-1)
+            if one.any():
+                # the greedy rule (module docstring): the lowest-index
+                # maximum of the distribution as handed in — the rescaled
+                # p may round neighbours into a tie, and argpartition's
+                # pick among ties is not the first
+                keep[one] = False
+                keep[one, p2[one].argmax(axis=-1)] = True
             keep |= ~row_on[:, None]
             p = np.where(keep, p, 0.0)
             denom = np.where(row_on, p.sum(axis=-1), 1.0)
             p = p / denom[:, None]
     if top_p is not None:
         tp, tp_rows = _row_array(top_p, B, "top_p")
+        # host numpy over [B] thresholds (this module's one device
+        # program is greedy_ids); f64 so 1.0 compares exactly
+        # tpulint: disable=dtype-promotion
         tp = np.asarray(tp, np.float64)
         if tp_rows:
             if (tp > 1.0).any():
@@ -195,30 +224,77 @@ def per_row_param(v, b: int):
     return int(x) if np.issubdtype(a.dtype, np.integer) else float(x)
 
 
+def selects_one(top_k) -> bool:
+    """Whether a row with this scalar `top_k` is greedy: its filter
+    leaves one token, whatever its temperature and `top_p` (the module's
+    greedy rule). What the serving engine asks of each request to decide
+    whether the cycle needs the ``[S, V]`` block on the host at all."""
+    return top_k is not None and np.ndim(top_k) == 0 and int(top_k) == 1
+
+
+class ArgmaxRow:
+    """A row of width `n` of which only the lowest-index maximum is
+    known: what ``greedy_ids`` brings back in place of the ``[V]``
+    distribution. ``draw`` returns ``first`` for it, so a caller whose
+    argmax ran on the device (the serving engine) still takes every
+    row's token from ``draw``; only a greedy row (``selects_one``) can
+    be answered from one."""
+
+    __slots__ = ("first", "n")
+
+    def __init__(self, first: int, n: int):
+        self.first, self.n = int(first), int(n)
+
+    def __len__(self) -> int:
+        return self.n
+
+
 def draw(probs, temperature, rng,
          top_k=None, top_p=None):
     """Sample token ids from softmax distributions (the single draw
     implementation shared by every sampler); see filter_probs for the
     temperature/top_k/top_p semantics (incl. the per-row array forms).
-    top_k=1 is greedy decoding regardless of temperature.
+
+    top_k=1 is greedy decoding regardless of temperature and top_p, by
+    the module's greedy rule: the row takes ``np.argmax(probs)`` — the
+    lowest index among exact ties — and its Generator is NOT consumed
+    (its ``bit_generator.state`` is unchanged on return; `temperature`
+    and `top_p` are not read). This replaced, in PR 27, the filter's
+    answer for such rows (``argpartition``'s pick among exact ties, one
+    ``rng.choice`` per token). Every other row draws exactly as before.
+    A greedy row may be handed as an ``ArgmaxRow`` where the argmax was
+    already taken on the device.
 
     One row [V] returns an int. A batch [B, V] returns a list of ints;
-    `rng` is then either one Generator (consumed row-major) or a
-    sequence of one Generator per row — independent per-request
-    streams. (The serving engine itself draws row-by-row through the
-    single-row form so each request's rng consumption is positionally
-    identical to its one-shot sample_stream run; both forms share ONE
-    filter kernel, `_filter_rows`.)"""
+    `rng` is then either one Generator (consumed row-major, greedy rows
+    skipped) or a sequence of one Generator per row — independent
+    per-request streams. (The serving engine itself draws row-by-row
+    through the single-row form so each request's rng consumption is
+    positionally identical to its one-shot sample_stream run; both forms
+    share ONE filter kernel, `_filter_rows`.)"""
+    if isinstance(probs, ArgmaxRow):
+        if not selects_one(top_k):
+            raise ValueError("only a top_k=1 row can be drawn from its "
+                             "argmax alone")
+        return probs.first
     probs = np.asarray(probs)
     if probs.ndim == 2:
-        p = filter_probs(probs, temperature, top_k, top_p)
+        B, V = probs.shape
         rngs = (list(rng) if isinstance(rng, (list, tuple))
-                else [rng] * len(p))
-        if len(rngs) != len(p):
+                else [rng] * B)
+        if len(rngs) != B:
             raise ValueError(f"need one rng per row "
-                             f"({len(rngs)} != {len(p)})")
-        return [int(r.choice(p.shape[1], p=row))
-                for r, row in zip(rngs, p)]
+                             f"({len(rngs)} != {B})")
+        k, k_rows = _row_array(top_k, B, "top_k")
+        one = (np.asarray(k) == 1 if k_rows
+               else np.full(B, selects_one(top_k)))
+        first = probs.argmax(axis=-1) if one.any() else None
+        p = (None if one.all()
+             else filter_probs(probs, temperature, top_k, top_p))
+        return [int(first[b]) if one[b] else int(rngs[b].choice(V, p=p[b]))
+                for b in range(B)]
+    if selects_one(top_k):
+        return int(np.argmax(probs))
     p = filter_probs(probs, temperature, top_k, top_p)
     return int(rng.choice(len(p), p=p))
 
@@ -394,12 +470,48 @@ def step_tokens(net, tokens, vocab_size: int,
                    donate_state, io)[:, :, -1]
 
 
-def _decode(net, rows, vocab_size: int, donate_state: bool, io):
+def _dispatch(net, rows, vocab_size: int, donate_state: bool, io):
     io.step("input")
     x = _one_hot(rows, vocab_size)
-    out = _forward(net, x, io, donate_state=donate_state)
+    return _forward(net, x, io, donate_state=donate_state)
+
+
+def _decode(net, rows, vocab_size: int, donate_state: bool, io):
+    out = _dispatch(net, rows, vocab_size, donate_state, io)
     io.step("fetch")
     return _probs(out, io)
+
+
+@jax.jit
+def greedy_ids(out):
+    """The greedy rule on the device: per row of the head's ``[B, V, T]``
+    output, the lowest-index maximum at the last position, as int32 —
+    the index ``np.argmax`` gives on the same values once fetched. Its
+    own program (``jit_greedy_ids``), queued behind the forward; the
+    streaming forward stays the one program named ``fwd``."""
+    return jnp.argmax(out[:, :, -1], axis=-1).astype(jnp.int32)
+
+
+def step_greedy(net, tokens, vocab_size: int,
+                donate_state: bool = False,
+                io: RoundTrip = _UNWATCHED,
+                block: bool = False
+                ) -> Tuple[np.ndarray, Optional[np.ndarray]]:
+    """step_tokens for a caller whose rows are greedy (``selects_one``):
+    the same dispatch, with ``greedy_ids`` queued behind it over the very
+    array step_tokens would have fetched. Returns ``(ids, probs)`` — the
+    ``[B]`` int32 ids (4·B bytes to the host) and, only where `block`
+    asks for it because some row samples, the ``[B, V]`` distributions
+    as step_tokens returns them; otherwise None, and the block never
+    leaves the device. `io` and `donate_state` as step_tokens."""
+    out = _dispatch(net, np.asarray(tokens, np.int64)[:, None], vocab_size,
+                    donate_state, io)
+    out = out[0] if isinstance(out, (list, tuple)) else out
+    ids = greedy_ids(out)
+    io.step("fetch")
+    ids = np.asarray(ids)
+    io.d2h(ids)
+    return ids, (_probs(out, io)[:, :, -1] if block else None)
 
 
 def verify_tokens(net, chunks, vocab_size: int,

@@ -108,6 +108,12 @@ lane serving python -m pytest tests/test_serving_engine.py -q -p no:cacheprovide
 # benchmark's reduction
 lane phases python -m pytest tests/test_serving_phases.py -q -p no:cacheprovider
 
+# tier-1 greedy-selection lane: util/decoding's greedy rule (top_k == 1 =
+# lowest-index argmax, no rng consumed) and the engine's on-device argmax:
+# all-greedy, mixed and rebuilt arenas against one-shot sample_stream,
+# health()["sample"] and the 4*S-byte fetch, zero compiles across mixes
+lane greedy python -m pytest tests/test_serving_greedy_select.py -q -p no:cacheprovider
+
 # tier-1 serving-survivability lane: supervised recovery (bit-identical
 # continuation after arena rebuilds), restart-budget escalation,
 # SLO shedding / early rejection / brownout, draining, and the

@@ -260,9 +260,10 @@ class TestScriptedRun:
         _, _, _, _, h1, _, cycles = self._run(net)
         f32 = 4
         assert h1["host_io"] == {
-            # [S, V, 1] float32 one-hot up, [S, V, 1] probabilities down
+            # [S, V, 1] float32 one-hot up; every row is greedy, so the
+            # [S] int32 ids come down and the [S, V, 1] block stays put
             "decode": {"h2d_bytes": cycles * 2 * V * f32,
-                       "d2h_bytes": cycles * 2 * V * f32},
+                       "d2h_bytes": cycles * 2 * 4},
             # [1, V, P] up and [1, V, P] down per prime
             "prefill": {"h2d_bytes": (8 + 16 + 2) * V * f32,
                         "d2h_bytes": (8 + 16 + 2) * V * f32}}
