@@ -6,7 +6,18 @@ mix or a per-layer metric adds files and entries and edits nothing here.
 - configuration: the ``file`` its ``configs`` entry names; its ``model``
                  names ``<path>/models/<model>.py``, which builds the
                  program's net from the file's sizes, and the plain
-                 reference beside it, ``<path>/reference/<model>.py``
+                 reference beside it, ``<path>/reference/<model>.py``.
+                 The file holds the sizes as they are run. ``reduced``
+                 (the entry's list, again) names each key whose value
+                 is the chip's share and not the source's: the depth,
+                 the experts or heads held here, the slice of the
+                 vocabulary; never a width. Where it is not empty,
+                 ``published`` holds the source's value of just those
+                 keys, and ``deployment`` says in one line over how
+                 many chips each layer is divided and how, and what
+                 this chip holds. A file with nothing reduced has no
+                 ``published`` (tests/benchmark/test_benchmark_contract.py
+                 holds a file to this; nothing here reads the three keys)
 - traffic mix:   ``<path>/traffic/<traffic>.json`` under a directory of
                  ``paths``; its ``kind`` names the one general generator
                  that reads it, ``<path>/runners/<kind>.py`` with

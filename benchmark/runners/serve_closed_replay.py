@@ -9,6 +9,43 @@ a warm-up of exactly the prefill buckets the table uses and the decode
 arena, then the lead-in that puts every client in flight. The plain
 reference runs over a sample of the finished requests once the window has
 closed, the peak has been read and the engine is gone.
+
+What a served model brings (two files and a configuration, found by the
+configuration's ``model``; nothing here knows a model's name):
+
+- ``models/<model>.py``: ``build_shell(cfg, max_length) -> (net, shapes)``,
+  a ``ComputationGraph`` that takes one-hot ``[N, V, T]`` and streams
+  through ``rnn_time_step``, initialised without drawing a weight, and the
+  shapes of its parameter tree (vertex -> leaf -> shape);
+- ``reference/<model>.py``: ``param_specs(cfg)``, the leaves as ``(name,
+  shape, mean, std)`` with ``name`` = ``<vertex>/<leaf>`` of that tree, and
+  ``logits_at(cfg, params, ids, positions, low=False)``, the plain forward
+  pass (``low``: the 8-bit control of ``reference/quant.py``). The
+  ``*_step.mfu`` readers also want ``prefill_flops`` and ``decode_flops``;
+- the configuration's keys ``vocab_size`` (the slice of the vocabulary
+  held here: ``prompt_ids`` draws from it and the logits are over it),
+  ``departures.served_max_context`` (no context of the table may pass
+  it) and ``engine.{slots, page_size, total_pages, kv_dtype, decode_impl,
+  prefix_cache, queue_limit}``. ``decode_impl`` also says which path
+  ``health()["kv_traffic"]["decode_path"]`` has to read after the window:
+  ``direct-xla`` for ``xla`` (a decoder whose cache the grouped-query
+  Mosaic kernel cannot read says that), ``direct-pallas`` for ``auto`` and
+  ``pallas``; a run that fell back to another path is not correct.
+
+What a mix of this kind holds (``traffic/<traffic>.json``; the rules are
+``tests/benchmark/test_benchmark_replay.py``'s, over every such cell):
+``loop`` ``"closed"`` and ``think_time_s`` 0.0 (the one loop this runner
+drives); the parameters the table was drawn from (``generator_seed``,
+``prompt_tokens`` and ``output_tokens``, each a ``dist`` with ``min`` and
+``max``, ``table.{clients, requests_per_client}``) and what
+``traffic/draw_table.py`` made of them, once: ``clients`` (per client an
+ordered list of ``[prompt tokens, output tokens]``, at least 40, one
+client a slot at the most, no context over ``served_max_context``, never
+more pages than the pool has) and ``drawn``; ``latency_sample`` (``sent``:
+wait for the first token of every request sent in the window;
+``finished``) and ``checked_requests`` (how many finished requests the
+reference follows, beside the longest). ``dry_run`` may hold a smaller
+table for a test run.
 """
 
 from __future__ import annotations
@@ -49,11 +86,18 @@ class Program:
             net.params[vertex] = leaves
         self.net = net
         e = cfg["engine"]
+        # off the TPU ``auto`` would fall back to XLA: the kernel runs
+        # there in interpret mode, so that a dry run takes the path the
+        # chip takes; a configuration that says ``xla`` takes it everywhere
+        impl = e["decode_impl"]
+        if not on_tpu and impl != "xla":
+            impl = "pallas"
+        #: what ``health()`` has to report: ``direct-<the impl that ran>``
+        self.decode_path = "direct-" + ("xla" if impl == "xla" else "pallas")
         paging = PagedKVConfig(
             page_size=e["page_size"], total_pages=e["total_pages"],
             prefix_cache=e["prefix_cache"], kv_dtype=e["kv_dtype"],
-            decode_impl=e["decode_impl"] if on_tpu else "pallas",
-            kernel_interpret=not on_tpu)
+            decode_impl=impl, kernel_interpret=not on_tpu)
         self.engine = GenerationEngine(
             net, cfg["vocab_size"], slots=e["slots"],
             queue_limit=e["queue_limit"], paging=paging)
@@ -222,7 +266,7 @@ def run(cell, args, devices, clock0: float, tracer=None,
         compare.Check("compiles_in_window", compiled_in_window, 0,
                       exact=True),
         compare.Check("decode_path_not_direct_pallas",
-                      int(decode_path != "direct-pallas"), 0, exact=True),
+                      int(decode_path != prog.decode_path), 0, exact=True),
         compare.Check("max_context_positions", max_context, prog.cap),
     ]
     record["checked"] = {"requests": len(sample), "positions": n_pos}

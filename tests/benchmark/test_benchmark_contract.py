@@ -31,14 +31,17 @@ def test_top_level_keys_are_exactly_the_contracts():
     assert os.path.getsize(os.path.join(ROOT, "BENCHMARK.json")) <= 65536
 
 
-@pytest.mark.parametrize("section,keys,optional", [
+ENTRY_KEYS = [
     ("configs", {"name", "source", "file", "reduced", "why"}, set()),
     ("workloads", {"name", "config", "traffic", "chips", "why"}, set()),
     ("end_to_end", {"name", "unit", "better", "bound", "source"},
      {"workloads"}),
     ("per_layer", {"name", "unit", "better", "source", "layer", "moves"},
      {"workloads"}),
-])
+]
+
+
+@pytest.mark.parametrize("section,keys,optional", ENTRY_KEYS)
 def test_entries_have_just_the_keys_shown(section, keys, optional):
     names = [e["name"] for e in BENCH[section]]
     assert len(names) == len(set(names))
@@ -73,7 +76,7 @@ def test_metrics_units_sources_and_bounds():
 
 @pytest.mark.parametrize("name", CELLS)
 def test_every_cell_resolves_to_files_of_its_own(name):
-    cell = harness.Cell(BENCH, name)
+    cell = harness.Cell(BENCH, name, root=ROOT)
     # the runner, the model and its plain reference are found by name
     assert callable(cell.runner().run)
     assert cell.model() is cell.model()
@@ -93,10 +96,28 @@ def test_every_cell_resolves_to_files_of_its_own(name):
 
 @pytest.mark.parametrize("cfg", BENCH["configs"], ids=lambda c: c["name"])
 def test_configuration_files(cfg):
+    """A configuration cut to one chip's share says so: what ``reduced``
+    names is a key of the file, ``published`` holds the source's value of
+    just those keys, each other than the value held, and ``deployment``
+    says in one line over how many chips each layer is divided, and how.
+    A whole model has no ``published``. (The floors of a cut, a period and
+    four layers, 8 experts, an eighth of the vocabulary, are the issue's
+    and the reviewer's to hold: this cannot know a model's key names.)"""
     assert any(cfg["file"].startswith(p + "/") for p in BENCH["paths"])
     data = harness._load_json(os.path.join(ROOT, cfg["file"]))
     assert data["source"] == cfg["source"]
-    assert data["reduced"] == cfg["reduced"] == []
+    assert data["reduced"] == cfg["reduced"]
+    if cfg["reduced"]:
+        assert len(set(cfg["reduced"])) == len(cfg["reduced"]) <= 16
+        assert set(cfg["reduced"]) <= set(data), "reduced names no key"
+        assert set(data.get("published", {})) == set(cfg["reduced"])
+        for key in cfg["reduced"]:
+            assert data["published"][key] != data[key], key
+        line = data.get("deployment")
+        assert isinstance(line, str) and 1 <= len(line) <= 200 \
+            and "\n" not in line and "\t" not in line
+    else:
+        assert "published" not in data
     assert data["assumed"]
     assert any(w["config"] == cfg["name"] for w in BENCH["workloads"])
     files = [c["file"] for c in BENCH["configs"]]
@@ -206,6 +227,185 @@ def run(cell, args, devices, clock0, tracer=None, control=False):
             "checks": [compare.Check("pings_lost", 0, cell.limits["lost"])]}
 '''
 
+#: a decoder the serving runner has never seen, cut to a chip's share: one
+#: parallel block (a single norm feeds attention and the FFN side by side),
+#: one key/value head, no bias on any position-wise product, ReLU. Not the
+#: zoo transformer's shape; it borrows only the weightless init
+DUMMY_DECODER = '''
+def build_shell(cfg, max_length):
+    from deeplearning4j_tpu.nn.conf.graph_conf import ElementWiseVertex
+    from deeplearning4j_tpu.nn.conf.inputs import InputType
+    from deeplearning4j_tpu.nn.conf.layers import (
+        Convolution1DLayer, LayerNormalization, RnnOutputLayer,
+        SelfAttentionLayer)
+    from deeplearning4j_tpu.nn.conf.network import NeuralNetConfiguration
+    from deeplearning4j_tpu.nn.graph import ComputationGraph
+    from benchmark.models.starcoder2 import _shell_init
+
+    e = cfg["hidden_size"]
+
+    def linear(n_out, act="identity"):
+        return Convolution1DLayer(n_out=n_out, kernel=1, has_bias=False,
+                                  convolution_mode="same", activation=act)
+
+    g = (NeuralNetConfiguration.Builder().seed(1).graph_builder()
+         .add_inputs("in").set_input_types(
+             InputType.recurrent(cfg["vocab_size"], max_length)))
+    g.add_layer("embed", linear(e), "in")
+    prev = "embed"
+    for n in range(cfg["num_hidden_layers"]):
+        g.add_layer(f"ln{n}", LayerNormalization(), prev)
+        g.add_layer(f"attn{n}", SelfAttentionLayer(
+            n_out=e, n_heads=cfg["num_attention_heads"], n_kv_heads=1,
+            causal=True, rope=True, rope_base=cfg["rope_theta"],
+            cache_length=max_length, activation="identity"), f"ln{n}")
+        g.add_layer(f"up{n}", linear(cfg["intermediate_size"], "relu"),
+                    f"ln{n}")
+        g.add_layer(f"down{n}", linear(e), f"up{n}")
+        g.add_vertex(f"res{n}", ElementWiseVertex(op="add"), prev,
+                     f"attn{n}", f"down{n}")
+        prev = f"res{n}"
+    g.add_layer("ln_f", LayerNormalization(), prev)
+    g.add_layer("out", RnnOutputLayer(n_out=cfg["vocab_size"],
+                                      loss="mcxent",
+                                      activation="softmax"), "ln_f")
+    conf = g.set_outputs("out").build()
+    conf.dtype = cfg["torch_dtype"]
+    net = ComputationGraph(conf)
+    return net, _shell_init(net)
+'''
+
+#: ... and its plain reference: x + attn(ln(x)) + ffn(ln(x)), float32
+DUMMY_DECODER_REFERENCE = '''
+import functools
+import math
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from benchmark.reference.quant import stored
+
+HI = lax.Precision.HIGHEST
+
+
+def param_specs(cfg):
+    e, v, i = (cfg["hidden_size"], cfg["vocab_size"],
+               cfg["intermediate_size"])
+    d = e // cfg["num_attention_heads"]
+    specs = [("embed/W", (e, v, 1), 0.0, 0.5)]
+    for n in range(cfg["num_hidden_layers"]):
+        specs += [(f"ln{n}/gamma", (e,), 1.0, 0.02),
+                  (f"ln{n}/beta", (e,), 0.0, 0.02)]
+        for p, shape in (("q", (e, e)), ("k", (e, d)), ("v", (e, d)),
+                         ("o", (e, e))):
+            specs += [(f"attn{n}/W{p}", shape, 0.0, 1 / math.sqrt(e)),
+                      (f"attn{n}/b{p}", shape[1:], 0.0, 0.02)]
+        specs += [(f"up{n}/W", (i, e, 1), 0.0, 1 / math.sqrt(e)),
+                  (f"down{n}/W", (e, i, 1), 0.0, 1 / math.sqrt(i))]
+    return specs + [("ln_f/gamma", (e,), 1.0, 0.02),
+                    ("ln_f/beta", (e,), 0.0, 0.02),
+                    ("out/W", (e, v), 0.0, 1 / math.sqrt(e)),
+                    ("out/b", (v,), 0.0, 0.02)]
+
+
+def _norm(x, p, name):
+    mean = x.mean(-1, keepdims=True)
+    var = ((x - mean) ** 2).mean(-1, keepdims=True)
+    return (x - mean) * lax.rsqrt(var + 1e-5) * p[name + "/gamma"] \\
+        + p[name + "/beta"]
+
+
+def _rope(x, base):
+    t, half = x.shape[1], x.shape[2] // 2
+    inv = base ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    ang = jnp.arange(t, dtype=jnp.float32)[:, None] * inv
+    x1, x2 = x[..., :half], x[..., half:]
+    return jnp.concatenate([x1 * jnp.cos(ang) - x2 * jnp.sin(ang),
+                            x1 * jnp.sin(ang) + x2 * jnp.cos(ang)], -1)
+
+
+def logits_at(cfg, params, ids, positions, low=False):
+    return _forward(params, jnp.asarray(ids), jnp.asarray(positions),
+                    heads=cfg["num_attention_heads"],
+                    base=cfg["rope_theta"],
+                    layers=cfg["num_hidden_layers"], low=low)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("heads", "base", "layers", "low"))
+def _forward(params, ids, positions, *, heads, base, layers, low):
+    p = {k: v.astype(jnp.float32) for k, v in params.items()}
+
+    def mm(x, w):
+        return stored(jnp.matmul(stored(x, low), stored(w, low),
+                                 precision=HI), low)
+
+    x = stored(p["embed/W"][:, ids, 0].T, low)                   # [T, E]
+    t, e = x.shape
+    d = e // heads
+    causal = jnp.tril(jnp.ones((t, t), bool))
+    for n in range(layers):
+        h = _norm(x, p, f"ln{n}")
+        a = f"attn{n}/"
+        q = (mm(h, p[a + "Wq"]) + p[a + "bq"]).reshape(t, heads, d)
+        q = _rope(q.transpose(1, 0, 2), base)                   # [H, T, D]
+        k = _rope((mm(h, p[a + "Wk"]) + p[a + "bk"])[None], base)[0]
+        v = mm(h, p[a + "Wv"]) + p[a + "bv"]            # one key/value head
+        s = jnp.einsum("htd,sd->hts", stored(q, low), stored(k, low),
+                       precision=HI) / math.sqrt(d)
+        w = jax.nn.softmax(jnp.where(causal, s, -jnp.inf), axis=-1)
+        o = jnp.einsum("hts,sd->htd", stored(w, low), stored(v, low),
+                       precision=HI).transpose(1, 0, 2).reshape(t, e)
+        attn = mm(o, p[a + "Wo"]) + p[a + "bo"]
+        up = jax.nn.relu(mm(h, p[f"up{n}/W"][:, :, 0].T))
+        x = stored(x + attn + mm(up, p[f"down{n}/W"][:, :, 0].T), low)
+    h = _norm(x[positions], p, "ln_f")
+    return jnp.matmul(stored(h, low), stored(p["out/W"], low),
+                      precision=HI) + p["out/b"]
+'''
+
+#: its configuration: two keys hold the chip's share, and the file says so
+DUMMY_DECODER_CONFIG = {
+    "kind": "serve", "model": "tinydecoder", "source": "nowhere",
+    "reduced": ["num_hidden_layers", "vocab_size"],
+    "published": {"num_hidden_layers": 12, "vocab_size": 4096},
+    "deployment": "each layer on 8 chips, the vocabulary split over them; "
+                  "this chip holds 1 of 12 layers and 512 of 4,096 rows",
+    "assumed": {"all": "of it"},
+    "hidden_size": 32, "intermediate_size": 64, "num_hidden_layers": 1,
+    "num_attention_heads": 2, "vocab_size": 512, "rope_theta": 10000.0,
+    "torch_dtype": "bfloat16",
+    "departures": {"served_max_context": 64},
+    "engine": {"slots": 3, "page_size": 8, "total_pages": 32,
+               "kv_dtype": "bf16", "decode_impl": "xla",
+               "prefix_cache": True, "queue_limit": 8}}
+
+#: its mix, as ``runners/serve_closed_replay.py`` states a mix of that
+#: kind: the parameters, and the table ``traffic/draw_table.py`` draws
+#: from them (three clients, prompts in three prefill buckets; long enough
+#: for a CPU that serves a request in a few milliseconds)
+DUMMY_DECODER_TRAFFIC = {
+    "kind": "serve_closed_replay", "why": "test", "loop": "closed",
+    "think_time_s": 0.0, "decoding": "greedy (top_k=1), no stop tokens",
+    "generator_seed": 28,
+    "prompt_tokens": {"dist": "uniform", "min": 5, "max": 30},
+    "output_tokens": {"dist": "uniform", "min": 4, "max": 8},
+    "latency_sample": "finished", "checked_requests": 40,
+    "table": {"clients": 3, "requests_per_client": 600}}
+
+
+def _draw_table(path):
+    """What a later PR does with a mix it has written the parameters of:
+    ``python3 benchmark/traffic/draw_table.py <path>``."""
+    import importlib.util
+    spec = importlib.util.spec_from_file_location(
+        "draw_table", os.path.join(ROOT, "benchmark", "traffic",
+                                   "draw_table.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    mod.main(str(path))
+
 
 def _copy_with_dummies(tmp_path):
     """A temporary copy of the benchmark beside a directory of a later
@@ -214,6 +414,7 @@ def _copy_with_dummies(tmp_path):
     root = tmp_path / "copy"
     shutil.copytree(os.path.join(ROOT, "benchmark"), root / "benchmark",
                     ignore=shutil.ignore_patterns("__pycache__"))
+    (root / "tests" / "benchmark").mkdir(parents=True)
     extra = root / "extra_bench"
     for d in ("configs", "traffic", "metrics", "limits", "models",
               "reference", "runners"):
@@ -246,15 +447,52 @@ def _copy_with_dummies(tmp_path):
     bench["configs"].append({"name": "dummy", "source": "nowhere",
                              "file": "extra_bench/configs/dummy.json",
                              "reduced": [], "why": "test"})
+    e2e = {m["name"]: m for m in bench["end_to_end"]}
     for traffic in ("fit_dummy", "ping_dummy"):
         bench["workloads"].append({
             "name": "dummy." + traffic, "config": "dummy",
             "traffic": traffic, "chips": 1, "why": "test"})
-        bench["end_to_end"][0]["workloads"].append("dummy." + traffic)
+        e2e["train_samples_per_s"]["workloads"].append("dummy." + traffic)
     bench["per_layer"].append({
         "name": "dummy.answer", "unit": "ms", "better": "lower",
         "source": "program_counter", "layer": "fit loop",
-        "moves": "train_samples_per_s", "workloads": ["dummy.fit_dummy"]})
+        "moves": "train_samples_per_s",
+        "workloads": ["dummy.fit_dummy", "dummy.ping_dummy"]})
+    # the cut decoder: a configuration, a model, a reference, a mix of the
+    # serving kind, limits, and a per-layer metric of its cell
+    (extra / "configs" / "dummydec.json").write_text(
+        json.dumps(DUMMY_DECODER_CONFIG))
+    (extra / "models" / "tinydecoder.py").write_text(DUMMY_DECODER)
+    (extra / "reference" / "tinydecoder.py").write_text(
+        DUMMY_DECODER_REFERENCE)
+    (extra / "traffic" / "serve_dummy.json").write_text(json.dumps(
+        DUMMY_DECODER_TRAFFIC))
+    _draw_table(extra / "traffic" / "serve_dummy.json")
+    # over the ~250 served positions of 40 requests, on the CPU, 16 seeds:
+    # the program reads 0.0026-0.0239, the 8-bit control 0.18-0.45
+    (extra / "limits" / "dummydec.serve_dummy.json").write_text(json.dumps(
+        {"limits": {"served_token_gap_max": 0.07}}))
+    (extra / "metrics" / "dummydec.rows_per_dispatch.py").write_text(
+        "from benchmark.metrics._spans import health_delta\n\n\n"
+        "def read(ctx):\n"
+        "    rows = health_delta(ctx, 'decode_dispatch', 'rows')\n"
+        "    n = health_delta(ctx, 'decode_dispatch', 'count')\n"
+        "    return rows / n if rows is not None and n else None\n")
+    bench["configs"].append({
+        "name": "dummydec", "source": "nowhere",
+        "file": "extra_bench/configs/dummydec.json",
+        "reduced": DUMMY_DECODER_CONFIG["reduced"], "why": "test"})
+    bench["workloads"].append({
+        "name": "dummydec.serve_dummy", "config": "dummydec",
+        "traffic": "serve_dummy", "chips": 1, "why": "test"})
+    e2e["serve_out_tokens_per_s"]["workloads"].append(
+        "dummydec.serve_dummy")
+    bench["per_layer"].append({
+        "name": "dummydec.rows_per_dispatch", "unit": "rows",
+        "better": "higher", "source": "program_counter",
+        "layer": "serving engine", "moves": "serve_out_tokens_per_s",
+        "workloads": ["dummydec.serve_dummy"]})
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
     return str(root), bench
 
 
@@ -276,11 +514,11 @@ def test_a_cell_a_configuration_and_a_metric_are_added_by_files_alone(
     assert harness.Cell(bench, CELLS[0], root=root).per_layer
 
 
-def _cpu_run(cell, seed=3, control=False):
+def _cpu_run(cell, seed=3, control=False, seconds=0.3):
     import argparse
     import time
     import jax
-    args = argparse.Namespace(seed=seed, seconds=0.3)
+    args = argparse.Namespace(seed=seed, seconds=seconds)
     return cell.runner().run(cell, args, jax.devices()[:1],
                              time.perf_counter(), None, control=control)
 
@@ -312,6 +550,199 @@ def test_a_model_of_another_architecture_runs_by_files_alone(tmp_path):
     rate = record["end_to_end"]["train_samples_per_s"]
     assert cell.reader("train_step.mfu")(ctx) == pytest.approx(
         100 * 6 * (12 * 16 + 16 * 5) * rate / 197e12)
+
+
+@pytest.fixture(scope="module")
+def dummies(tmp_path_factory):
+    """One temporary copy for the tests that only read it (or add files of
+    their own to it): ``(root, bench)``."""
+    return _copy_with_dummies(tmp_path_factory.mktemp("dummies"))
+
+
+@pytest.fixture(scope="module")
+def cut_decoder(dummies):
+    """The dummy cut decoder's cell, and one run of it through the serving
+    runner on the CPU (``control`` read beside it)."""
+    root, bench = dummies
+    cell = harness.Cell(bench, "dummydec.serve_dummy", root=root)
+    return root, bench, cell, _cpu_run(cell, control=True, seconds=1.0)
+
+
+def test_a_cut_decoder_runs_through_the_serving_runner_by_files_alone(
+        cut_decoder):
+    """What ``tinymlp`` proves of the training runner: the general serving
+    runner takes a decoder it has never seen (another graph than the zoo
+    transformer's, a cache the Mosaic kernel's path is not asked for)
+    through ``GenerationEngine`` by the contract its docstring states, and
+    compares what was served with that decoder's own plain reference."""
+    root, bench, cell, record = cut_decoder
+    assert cell.runner().__file__.startswith(root)
+    assert cell.model().__file__.endswith(
+        "extra_bench/models/tinydecoder.py")
+    assert cell.config["reduced"] == ["num_hidden_layers", "vocab_size"]
+    assert [c.line() for c in record["checks"] if not c.ok] == []
+    assert [c.name for c in record["checks"]] == [
+        "served_token_gap_max", "requests_failed",
+        "output_length_mismatches", "compiles_in_window",
+        "decode_path_not_direct_pallas", "max_context_positions"]
+    health = record["serve"]["health1"]
+    assert health["kv_traffic"]["decode_path"] == "direct-xla"
+    assert health["slots"] == 3
+    assert record["attempted"] > 10 and record["failed"] == 0
+    assert record["checked"]["requests"] >= 10
+    # the served tokens are the reference's, not one token for ever
+    assert record["readings"]["program"]["distinct_served_tokens"] >= 5
+    # ... and the same reference in 8 bits, at the same positions, is not
+    # correct: its widest gap lies well over the limit the program is under
+    control = compare.Check(
+        "served_token_gap_max",
+        record["readings"]["control_fp8"]["served_token_gap_max"],
+        cell.limits["served_token_gap_max"])
+    assert not control.ok
+    assert control.value > 1.5 * control.limit
+    # ids are drawn from the slice of the vocabulary held here
+    assert max(t for r in record["serve"]["finished"]
+               for t in r.prompt) < cell.config["vocab_size"] == 512
+    out = harness.result(cell, record, [_Dev()])
+    assert out["correct"] is True
+    assert set(out["metrics"]) == {"setup_s", "serve_out_tokens_per_s"}
+    # its own per-layer metric, against a hand count on its counters
+    ctx = {"record": {"serve": {
+        "health0": {"decode_dispatch": {"rows": 10, "count": 5}},
+        "health1": {"decode_dispatch": {"rows": 40, "count": 17}}}}}
+    assert harness.per_layer_metrics(cell, ctx) == {
+        "dummydec.rows_per_dispatch": {"value": 2.5, "unit": "rows"}}
+    got = harness.per_layer_metrics(cell, {"record": record})
+    assert 1.0 <= got["dummydec.rows_per_dispatch"]["value"] <= 3.0
+
+
+def test_a_run_on_another_decode_path_than_its_decode_impl_is_not_correct(
+        cut_decoder, monkeypatch):
+    """``engine.decode_impl`` says which path the window has to have run
+    on: ``xla`` is held to ``direct-xla`` (the run above), ``auto`` and
+    ``pallas`` to ``direct-pallas``, and no key lets a configuration say
+    otherwise. An engine that fell back to another path, here to the
+    round trip through the host, fails that one check and no other."""
+    from deeplearning4j_tpu.serving import GenerationEngine
+    root, bench = cut_decoder[:2]
+    cell = harness.Cell(bench, "dummydec.serve_dummy", root=root)
+    health = GenerationEngine.health
+
+    def fell_back(self):
+        out = health(self)
+        out["kv_traffic"]["decode_path"] = "roundtrip"
+        return out
+
+    monkeypatch.setattr(GenerationEngine, "health", fell_back)
+    record = _cpu_run(cell)
+    assert [c.name for c in record["checks"] if not c.ok] == [
+        "decode_path_not_direct_pallas"]
+    assert harness.result(cell, record, [_Dev()])["correct"] is False
+
+
+def _write_config(root, bench, name, tag, changes):
+    """The entry ``name`` again, for a changed copy of its file (``None``
+    drops a key)."""
+    entry = next(c for c in bench["configs"] if c["name"] == name)
+    data = harness._load_json(os.path.join(root, entry["file"]))
+    for k, v in changes.items():
+        if v is None:
+            data.pop(k, None)
+        else:
+            data[k] = v
+    path = entry["file"].replace(".json", f".{tag}.json")
+    with open(os.path.join(root, path), "w") as f:
+        json.dump(data, f)
+    return dict(entry, file=path)
+
+
+CUT_CASES = [
+    ("a whole model", "dummy", {}, True),
+    ("a cut model that says what it cut", "dummydec", {}, True),
+    ("published lacks a reduced key", "dummydec",
+     {"published": {"num_hidden_layers": 12}}, False),
+    ("published holds a key that is not reduced", "dummydec",
+     {"published": {"num_hidden_layers": 12, "vocab_size": 4096,
+                    "hidden_size": 64}}, False),
+    ("a published value equals the one held", "dummydec",
+     {"published": {"num_hidden_layers": 12, "vocab_size": 512}}, False),
+    ("no published", "dummydec", {"published": None}, False),
+    ("no deployment", "dummydec", {"deployment": None}, False),
+    ("a deployment of two lines", "dummydec",
+     {"deployment": "8 chips\na layer"}, False),
+    ("a deployment of 201 characters", "dummydec",
+     {"deployment": "x" * 201}, False),
+    ("reduced names no key of the file", "dummydec",
+     {"vocab_size": None}, False),
+    ("the file's list is not the entry's", "dummydec",
+     {"reduced": ["num_hidden_layers"]}, False),
+    ("a whole model with a published", "dummy",
+     {"published": {"hidden": 32}}, False),
+]
+
+
+@pytest.mark.parametrize("case,config,changes,ok", CUT_CASES,
+                         ids=[c[0].replace(" ", "_") for c in CUT_CASES])
+def test_a_cut_configuration_says_what_it_cut(dummies, monkeypatch, case,
+                                              config, changes, ok):
+    root, bench = dummies
+    entry = _write_config(root, bench, config, case.replace(" ", "_"),
+                          changes)
+    monkeypatch.setattr(sys.modules[__name__], "ROOT", root)
+    monkeypatch.setattr(sys.modules[__name__], "BENCH", bench)
+    if ok:
+        test_configuration_files(entry)
+    else:
+        with pytest.raises(AssertionError):
+            test_configuration_files(entry)
+
+
+def test_a_later_prs_entries_pass_every_rule_of_this_directory(
+        dummies, monkeypatch):
+    """A later PR's configurations, cells and per-layer entries (a cut
+    decoder's among them), each with files of its own, against every test
+    of this directory that goes over the entries of ``BENCHMARK.json``
+    (the others name the accepted cells they are about): the rules of this
+    file, of a ``serve_closed_replay`` mix (``test_benchmark_replay.py``)
+    and of the two reader tables. None needs an edit to a file that is
+    there."""
+    import test_benchmark_replay
+    import test_benchmark_spans
+    import test_benchmark_xplane
+    root, bench = dummies
+    assert len(bench["per_layer"]) == len(BENCH["per_layer"]) + 2
+    assert len(bench["workloads"]) == len(BENCH["workloads"]) + 3
+    me = sys.modules[__name__]
+    for module in (me, test_benchmark_replay):
+        monkeypatch.setattr(module, "ROOT", root)
+        monkeypatch.setattr(module, "BENCH", bench)
+    monkeypatch.setattr(me, "CELLS", [w["name"] for w in bench["workloads"]])
+    monkeypatch.setattr(harness, "load_benchmark",
+                        lambda root=root: harness._load_json(
+                            os.path.join(root, "BENCHMARK.json")))
+    test_top_level_keys_are_exactly_the_contracts()
+    for params in ENTRY_KEYS:
+        test_entries_have_just_the_keys_shown(*params)
+    test_metrics_units_sources_and_bounds()
+    for name in CELLS:
+        test_every_cell_resolves_to_files_of_its_own(name)
+    for cfg in bench["configs"]:
+        test_configuration_files(cfg)
+    test_command_names_no_file_outside_paths()
+    serving = test_benchmark_replay.serving_cells(bench, root)
+    assert serving == test_benchmark_replay.SERVING + [
+        "dummydec.serve_dummy"]
+    for name in serving:
+        test_benchmark_replay.\
+            test_the_schedule_is_data_and_stays_under_the_served_context(
+                name)
+        test_benchmark_replay.\
+            test_the_committed_table_is_what_its_recorded_parameters_draw(
+                name)
+    test_benchmark_xplane.\
+        test_every_serving_metric_of_the_benchmark_has_that_test()
+    test_benchmark_spans.\
+        test_every_reader_file_without_an_entry_is_in_that_table()
 
 
 def test_a_kind_of_traffic_is_added_by_a_runner_file_alone(tmp_path):

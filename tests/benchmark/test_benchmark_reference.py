@@ -144,6 +144,11 @@ def test_served_tokens_follow_the_reference(chat_cell):
     assert rec["checked"]["positions"] >= 10
     assert rec["attempted"] > 0 and rec["failed"] == 0
     assert checks["compiles_in_window"].value == 0
+    # ``decode_impl: auto`` is held to the Mosaic kernel's path
+    assert chat_cell.config["engine"]["decode_impl"] == "auto"
+    assert checks["decode_path_not_direct_pallas"].value == 0
+    assert rec["serve"]["health1"]["kv_traffic"]["decode_path"] == \
+        "direct-pallas"
     served = rec["readings"]["program"]["served_token_gap_max"]
     assert served <= chat_cell.limits["served_token_gap_max"]
     assert "control_fp8" in rec["readings"]

@@ -163,8 +163,9 @@ BY_HAND = {
 
 
 def _reader(cell_name, metric):
-    """Found by file name, as the harness finds an entry's reader: these
-    have no entry in ``BENCHMARK.json`` yet (PERF.md, Open questions 18)."""
+    """Found by file name, as the harness finds an entry's reader; each
+    has its entry in ``BENCHMARK.json``, on the cell this table names
+    (``test_benchmark_xplane.py`` holds the two together)."""
     return harness.Cell(harness.load_benchmark(), cell_name).reader(metric)
 
 
@@ -185,9 +186,8 @@ def test_on_a_program_without_spans_and_counters_it_reads_nothing(
 
 
 def test_every_reader_file_without_an_entry_is_in_that_table():
-    """A reader file the benchmark names is tested where the benchmark's
-    own are (``test_benchmark_xplane.py``); one it does not name yet is
-    tested here, and every name of the table is a file."""
+    """No reader file of ``benchmark/metrics`` is left without an entry
+    unless this table pins it, and every name of the table is a file."""
     import os
     bench = harness.load_benchmark()
     files = {f[:-3] for f in os.listdir(os.path.dirname(_spans.__file__))

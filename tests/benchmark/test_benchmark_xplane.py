@@ -254,13 +254,20 @@ def _by_hand(cfg):
     }
 
 
-SERVING_READERS = [
-    "engine.decode_dispatch_ms", "engine.prefill_time_share",
-    "decode_step.device_ms", "decode_step.mfu", "paged_attn.roofline",
-    "chat.device_idle_share", "chat.tpot_p50_s", "sched.queue_wait_p50_s",
-    "engine.prefill_p50_s", "prefill.device_ms_per_ktok",
-    "prefill_step.mfu", "complete.device_idle_share",
-    "complete.ttft_p50_s", "complete.out_tokens_per_s"]
+_CHAT, _COMPLETE = ("starcoder2-3b.chat_closed32",
+                    "starcoder2-3b.complete_closed8")
+#: the reader -> the one cell its entry lists (``_by_hand`` counts with
+#: this configuration's FLOPs and this cell's mix)
+SERVING_READERS = {
+    "engine.decode_dispatch_ms": _CHAT, "engine.prefill_time_share": _CHAT,
+    "decode_step.device_ms": _CHAT, "decode_step.mfu": _CHAT,
+    "paged_attn.roofline": _CHAT, "chat.device_idle_share": _CHAT,
+    "chat.tpot_p50_s": _CHAT, "sched.queue_wait_p50_s": _COMPLETE,
+    "engine.prefill_p50_s": _COMPLETE,
+    "prefill.device_ms_per_ktok": _COMPLETE, "prefill_step.mfu": _COMPLETE,
+    "complete.device_idle_share": _COMPLETE,
+    "complete.ttft_p50_s": _COMPLETE,
+    "complete.out_tokens_per_s": _COMPLETE}
 
 
 @pytest.mark.parametrize("metric", SERVING_READERS)
@@ -273,11 +280,75 @@ def test_a_serving_reader_reads_the_number_a_hand_count_gives(metric):
 
 
 def test_every_serving_metric_of_the_benchmark_has_that_test():
+    """Every ``per_layer`` entry that names one of the two serving cells
+    these tables were made for is pinned against a hand count: here
+    (``SERVING_READERS``, on ``_serving_ctx``) or in
+    ``test_benchmark_spans.py`` (``BY_HAND``). Either table also says
+    which cell: the entry lists that one and no other, so no cell that
+    comes later can be appended to an entry whose hand count is this
+    configuration's. Neither table holds a name without such an entry.
+
+    The rule for what comes later: an entry whose cells are all of a
+    configuration added later, or later cells of this one, is outside
+    these tables by the cells it lists, whatever its name begins with. It
+    lists only its own cells, and it brings its reader file and a
+    hand-count test of that reader in a new file of this directory."""
+    from benchmark import harness
+    from test_benchmark_spans import BY_HAND, CHAT, COMPLETE
+    assert (CHAT, COMPLETE) == (_CHAT, _COMPLETE)
+    bench = harness.load_benchmark()
+    entries = {m["name"]: m.get("workloads") for m in bench["per_layer"]
+               if "workloads" not in m
+               or {CHAT, COMPLETE} & set(m["workloads"])}
+    spans = {metric: cell for cell, metric in BY_HAND}
+    assert not set(SERVING_READERS) & set(spans)
+    assert entries == {metric: [cell] for metric, cell in
+                       {**SERVING_READERS, **spans}.items()}
+
+
+def _unpinned_entry(bench, cell):
+    bench["per_layer"].append(dict(bench["per_layer"][-1],
+                                   name="engine.unpinned",
+                                   workloads=[cell]))
+
+
+def _pinned_name_without_its_entry(bench, cell):
+    bench["per_layer"] = [m for m in bench["per_layer"]
+                          if m["name"] != "engine.batch_occupancy"]
+
+
+def _span_reader_on_the_other_cell(bench, cell):
+    entry = next(m for m in bench["per_layer"]
+                 if m["name"] == "prefill.padding_share")
+    entry["workloads"] = [cell]
+
+
+def _later_cell_on_an_accepted_entry(bench, cell):
+    entry = next(m for m in bench["per_layer"]
+                 if m["name"] == "decode_step.mfu")
+    entry["workloads"].append("later.cell")
+
+
+@pytest.mark.parametrize("change,ok", [
+    (_unpinned_entry, False), (_pinned_name_without_its_entry, False),
+    (_span_reader_on_the_other_cell, False),
+    (_later_cell_on_an_accepted_entry, False),
+    # the same entry on a cell that came later is nobody's but its own
+    (lambda bench, cell: _unpinned_entry(bench, "later.cell"), True),
+], ids=["unpinned_entry", "pinned_name_without_its_entry",
+        "span_reader_on_the_other_cell",
+        "later_cell_on_an_accepted_entry", "entry_of_a_later_cell"])
+def test_that_guard_holds_the_tables_and_the_entries_together(
+        monkeypatch, change, ok):
     from benchmark import harness
     bench = harness.load_benchmark()
-    serving = {m["name"] for m in bench["per_layer"]
-               if all(w.startswith("starcoder2") for w in m["workloads"])}
-    assert serving == set(SERVING_READERS)
+    change(bench, "starcoder2-3b.chat_closed32")
+    monkeypatch.setattr(harness, "load_benchmark", lambda: bench)
+    if ok:
+        test_every_serving_metric_of_the_benchmark_has_that_test()
+    else:
+        with pytest.raises(AssertionError):
+            test_every_serving_metric_of_the_benchmark_has_that_test()
 
 
 def test_a_reader_with_nothing_to_read_returns_nothing():
