@@ -18,7 +18,8 @@ from __future__ import annotations
 
 import dataclasses
 from dataclasses import dataclass, field
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from typing import (Any, Dict, List, NamedTuple, Optional, Sequence,
+                    Tuple)
 
 import jax
 import jax.numpy as jnp
@@ -29,6 +30,8 @@ from deeplearning4j_tpu.nn.conf.inputs import InputType
 from deeplearning4j_tpu.nn.layers import convolution as _conv
 from deeplearning4j_tpu.nn.layers import normalization as _norm
 from deeplearning4j_tpu.nn.layers import recurrent as _rnn
+from deeplearning4j_tpu.nn.layers import routed_experts as _re
+from deeplearning4j_tpu.nn.layers import sparse_latent as _sl
 from deeplearning4j_tpu.nn.weights import init_weights
 
 import numpy as np
@@ -42,15 +45,22 @@ import numpy as np
 #: serving/quant.py; kv_page_prime: the engine's prime-through-the-
 #: pool marker — its presence routes a prefill chunk through the
 #: paged path on the folded-gather read, see _stream_attend_paged)
+#: (kv_c / kv_r / kv_i and their kv_page_* pool leaves: the latent, the
+#: rotated key and the index key LatentAttentionLayer caches per token;
+#: moe_stats: RoutedExpertsLayer's router-load counters; attn_stats: the
+#: positions LatentAttentionLayer's streaming forms scored)
 STREAM_STATE_KEYS = frozenset(
     {"h", "c", "kv_k", "kv_v", "kv_pos", "kv_abs", "kv_mask",
      "pos_offset", "kv_page_k", "kv_page_v", "kv_page_table",
-     "kv_page_scale_k", "kv_page_scale_v", "kv_page_prime"})
+     "kv_page_scale_k", "kv_page_scale_v", "kv_page_prime",
+     "kv_c", "kv_r", "kv_i", "kv_page_c", "kv_page_r", "kv_page_i",
+     "moe_stats", "attn_stats"})
 
 #: streaming-state keys whose LEADING axis is the batch dimension (beam
 #: search gathers these when pruning beams; kv_pos/kv_abs/pos_offset are
 #: batch-independent scalars/vectors)
-BATCHED_STREAM_KEYS = frozenset({"h", "c", "kv_k", "kv_v", "kv_mask"})
+BATCHED_STREAM_KEYS = frozenset({"h", "c", "kv_k", "kv_v", "kv_mask",
+                                 "kv_c", "kv_r", "kv_i"})
 
 
 def reorder_stream_state(net, indices) -> None:
@@ -1095,6 +1105,14 @@ class SelfAttentionLayer(FeedForwardLayerConf):
             raise ValueError("SelfAttentionLayer needs RNN input [N,F,T]")
         return InputType.recurrent(self.n_out or it.size, it.timesteps)
 
+    def paged_leaves(self):
+        """The cache leaves a page pool holds for this layer: keys and
+        values, [Hkv, D] a token, tokens on axis 1 ([P, Hkv, page, D])."""
+        hkv = self.n_kv_heads or self.n_heads
+        d = self.n_out // self.n_heads
+        return (PagedLeaf("kv_k", (hkv, d), 1),
+                PagedLeaf("kv_v", (hkv, d), 1))
+
     def init(self, key, it):
         if self.n_in is None:
             self.n_in = it.size
@@ -2138,3 +2156,723 @@ class RBM(FeedForwardLayerConf):
             h_mean = jnp.mean(self.prop_up(params, x), axis=0)
             loss = loss + self.sparsity * jnp.sum((h_mean - 0.01) ** 2)
         return loss
+
+
+# ---------------------------------------------------------------------------
+# the decoder vocabulary of latent-attention / routed-expert models
+# ---------------------------------------------------------------------------
+
+
+class PagedLeaf(NamedTuple):
+    """One leaf of a streaming attention layer's cache as the layer
+    declares it to a page pool. The dense streaming leaf is
+    ``[N, *token_shape with L at token_axis]`` under ``key`` (``kv_...``),
+    the pool leaf ``[P, *token_shape with page_size at token_axis]`` under
+    ``page_key`` (``kv_page_...``); one page table a row serves every
+    leaf of a layer."""
+
+    key: str
+    token_shape: Tuple[int, ...]
+    token_axis: int
+
+    @property
+    def page_key(self) -> str:
+        return "kv_page_" + self.key[len("kv_"):]
+
+    def shape(self, lead: int, length: int) -> Tuple[int, ...]:
+        """``[lead, ...]`` with ``length`` tokens at the token axis."""
+        s = tuple(self.token_shape)
+        return ((lead,) + s[:self.token_axis] + (int(length),)
+                + s[self.token_axis:])
+
+    @property
+    def token_elements(self) -> int:
+        return int(np.prod(self.token_shape, dtype=np.int64))
+
+
+def paged_leaves(layer) -> Tuple[PagedLeaf, ...]:
+    """What a streaming layer keeps per token behind a page table: its own
+    ``paged_leaves()``, or none (a layer with no per-token cache)."""
+    declare = getattr(layer, "paged_leaves", None)
+    return tuple(declare()) if declare is not None else ()
+
+
+def _rms_norm(x, gamma, eps: float):
+    """RMSNorm over the last axis, float32 statistics."""
+    xf = x.astype(jnp.float32)
+    y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
+    return (y * gamma.astype(jnp.float32)).astype(x.dtype)
+
+
+@register_layer
+@dataclass
+class RMSNorm(FeedForwardLayerConf):
+    """Root-mean-square normalisation over the feature axis (axis 1 of
+    [N,F] and [N,F,T]) with a gain and no bias, statistics in float32
+    (Zhang & Sennrich 2019): the norm of the decoders that dropped
+    LayerNorm's mean and bias."""
+
+    eps: float = 1e-6
+
+    def output_type(self, it):
+        if it.kind == "cnn":
+            raise ValueError("RMSNorm supports FF [N,F] and RNN [N,F,T] "
+                             "input (feature axis 1)")
+        return it
+
+    def init(self, key, it):
+        nf = it.size if it.kind == "rnn" else it.flat_size()
+        self.n_in = self.n_out = nf
+        return {"gamma": jnp.ones((nf,), jnp.float32)}, {}
+
+    def apply(self, params, x, state, *, train=False, rng=None, mask=None):
+        y = jnp.moveaxis(_rms_norm(jnp.moveaxis(x, 1, -1), params["gamma"],
+                                   self.eps), -1, 1)
+        return _act.get(self.activation)(y), state
+
+
+@register_layer
+@dataclass
+class GatedFeedForward(FeedForwardLayerConf):
+    """Position-wise gated feed-forward ``(silu(x W_g) * x W_u) W_d`` over
+    [N,F] or [N,F,T], no biases: ``hidden`` wide inside, ``n_out``
+    (default: the input's width) out. Streaming needs no state: every
+    position is its own."""
+
+    hidden: int = 256
+
+    def output_type(self, it):
+        n_out = self.n_out or it.size
+        return (InputType.recurrent(n_out, it.timesteps)
+                if it.kind == "rnn" else InputType.feed_forward(n_out))
+
+    def init(self, key, it):
+        if self.n_in is None:
+            self.n_in = it.size
+        if self.n_out is None:
+            self.n_out = self.n_in
+        kg, ku, kd = jax.random.split(key, 3)
+        f, i, o = self.n_in, self.hidden, self.n_out
+        return {"Wg": init_weights(kg, (f, i), f, i, self.weight_init,
+                                   self.dist),
+                "Wu": init_weights(ku, (f, i), f, i, self.weight_init,
+                                   self.dist),
+                "Wd": init_weights(kd, (i, o), i, o, self.weight_init,
+                                   self.dist)}, {}
+
+    def apply(self, params, x, state, *, train=False, rng=None, mask=None):
+        x = self.maybe_dropout_input(x, train, rng)
+        y = _re.gated_ffn(jnp.moveaxis(x, 1, -1), params["Wg"], params["Wu"],
+                      params["Wd"]).astype(x.dtype)
+        return _act.get(self.activation)(jnp.moveaxis(y, -1, 1)), state
+
+
+@register_layer
+@dataclass
+class SequenceEmbeddingLayer(FeedForwardLayerConf):
+    """Token embedding over a sequence of ids: ``[N, T]`` integers in,
+    ``[N, E, T]`` out (``W`` [V, E], row ``id``; no bias). A net whose
+    input feeds this layer takes ids and no one-hot tensor
+    (``takes_ids``): the decoders of ``util/decoding`` and the serving
+    engine ask the net and feed it accordingly. The declared input type
+    stays ``InputType.recurrent(vocab, T)``: the size is the range of the
+    ids."""
+
+    #: the dtype the rows are handed on in (None: the table's own). A
+    #: lookup has no float input to promote against, so a net that
+    #: computes wider than it stores its table says so here
+    out_dtype: Optional[str] = None
+
+    takes_ids = True
+
+    def output_type(self, it):
+        if it.kind != "rnn":
+            raise ValueError("SequenceEmbeddingLayer needs a sequence "
+                             "input (InputType.recurrent(vocab, T))")
+        return InputType.recurrent(self.n_out, it.timesteps)
+
+    def init(self, key, it):
+        if self.n_in is None:
+            self.n_in = it.size
+        w = init_weights(key, (self.n_in, self.n_out), self.n_in,
+                         self.n_out, self.weight_init, self.dist)
+        return {"W": w}, {}
+
+    def apply(self, params, x, state, *, train=False, rng=None, mask=None):
+        if x.ndim != 2:
+            raise ValueError(
+                f"SequenceEmbeddingLayer takes ids [N, T], got an array "
+                f"of {x.ndim} axes (a one-hot [N, V, T] goes through a "
+                f"kernel-1 convolution instead)")
+        with jax.named_scope("embed.ids"):
+            y = jnp.take(params["W"], x.astype(jnp.int32), axis=0)
+            if self.out_dtype is not None:
+                y = y.astype(self.out_dtype)
+        return _act.get(self.activation)(jnp.moveaxis(y, -1, 1)), state
+
+
+@register_layer
+@dataclass
+class LastStepOutputLayer(RnnOutputLayer):
+    """``RnnOutputLayer`` whose STREAMING form answers for the chunk's
+    last position only: ``[N, V]`` out of ``rnn_time_step``, the
+    distribution that follows the chunk, and no ``[N, V, T]`` block of
+    which a prime reads one column. The training forward and the loss
+    are ``RnnOutputLayer``'s, over every position. (A left-padded
+    chunk's last position is always a real token.)"""
+
+    supports_streaming = True
+    last_step_only = True
+
+    def apply(self, params, x, state, *, train=False, rng=None, mask=None,
+              stream=False, pad_left=None):
+        if not stream:
+            return super().apply(params, x, state, train=train, rng=rng,
+                                 mask=mask)
+        with jax.named_scope("head.last"):
+            y, _ = super().apply(params, x[:, :, -1:], state)
+        return y[:, :, 0], state
+
+
+@register_layer
+@dataclass
+class LatentAttentionLayer(FeedForwardLayerConf):
+    """Multi-head latent attention with learned sparse selection, over
+    [N,F,T] (DeepSeek-V2's MLA, arXiv:2405.04434, with DeepSeek-V3.2's
+    indexer inside it: one layer, because the indexer reads the query
+    latent).
+
+    ``c_q = RMSNorm(x W_qa)``, ``q = c_q W_qb`` -> H x (nope | rope);
+    ``[c_kv | k_r] = x W_kva``, ``c_kv <- RMSNorm(c_kv)``; the rope parts
+    are rotated (interleaved pairs, YaRN frequencies). What a token leaves
+    behind is ``c_kv`` (``kv_lora_rank``), the one rotated key ``k_r``
+    shared by all heads, and the index key ``k^I`` — the three cache
+    leaves, ``kv_c`` / ``kv_r`` / ``kv_i`` [N, L, .], which
+    ``paged_leaves()`` declares to the serving engine's page pool.
+
+    Selection: ``q^I = c_q W_iq`` (Hi x Di), ``k^I = LayerNorm(x W_ik)``,
+    the first ``qk_rope_head_dim`` dims of both rotated (half-split
+    pairs), ``w = x W_iw Hi^-1/2 Di^-1/2``; query t scores every earlier
+    position ``I(t, s) = sum_j w_tj relu(q^I_tj . k^I_s)`` and attends the
+    ``min(index_topk, t + 1)`` of highest I, ties to the lower index.
+
+    Two forms of one function. PER-HEAD (training forward, dense
+    streaming, so the serving engine's prefill): ``[k_nope | v] = c_kv
+    W_kvb`` for every cached position, queries in blocks of
+    ``sparse_latent.QUERY_BLOCK``, each block's scores against all cache
+    slots masked to its rows' selected sets. ABSORBED (paged decode, a page table in
+    the state): ``q' = q_nope W_UK`` against the gathered ``c_kv`` of the
+    row's selected positions only, ``o = (p . c_kv) W_UV``; the index keys
+    of the row's whole context are scored every step, read from the pool
+    through the table.
+
+    Streaming: ``cache_length`` > 0; scalar ``kv_pos`` with optional
+    ``pad_left`` (packed accounting, as ``SelfAttentionLayer``), or the
+    engine's paged view (per-row ``kv_pos``, ``kv_page_table``). A
+    per-row rewind without a page table is not implemented. Either
+    streaming form adds to ``attn_stats`` (int32, in its state) the cache
+    positions whose attention scores the call computed, whatever the
+    selection kept of them: the form is chosen here, so it is counted
+    here (the serving engine's ``health()["sparse_attn"]``).
+    """
+
+    n_heads: int = 4
+    q_lora_rank: int = 32
+    kv_lora_rank: int = 16
+    qk_nope_head_dim: int = 16
+    qk_rope_head_dim: int = 8
+    v_head_dim: int = 16
+    index_n_heads: int = 2
+    index_head_dim: int = 16
+    index_topk: int = 16
+    eps: float = 1e-6
+    rope_theta: float = 10000.0
+    #: YaRN (``rope_factor`` 1.0 = plain rope): see sparse_latent.py
+    rope_factor: float = 1.0
+    rope_original_max: int = 4096
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale_all_dim: float = 0.0
+    cache_length: int = 0
+
+    supports_streaming = True
+    causal = True
+
+    # -- what the layer is -------------------------------------------------
+    def output_type(self, it):
+        if it.kind != "rnn":
+            raise ValueError("LatentAttentionLayer needs RNN input [N,F,T]")
+        return InputType.recurrent(self.n_out or it.size, it.timesteps)
+
+    def paged_leaves(self):
+        return (PagedLeaf("kv_c", (self.kv_lora_rank,), 0),
+                PagedLeaf("kv_r", (self.qk_rope_head_dim,), 0),
+                PagedLeaf("kv_i", (self.index_head_dim,), 0))
+
+    def paged_read_tokens(self) -> Dict[str, int]:
+        """Tokens of each leaf one row's paged decode reads (the serving
+        engine's modeled KV traffic): the whole context's index keys,
+        the selected positions' latents and rotated keys."""
+        top = min(self.index_topk, self.cache_length)
+        return {"kv_c": top, "kv_r": top, "kv_i": self.cache_length}
+
+    def _query_groups(self, t: int, slots: int, aligned: bool):
+        """How ``_attend_per_head`` takes ``t`` queries against ``slots``
+        key slots: ``(block, pad, [(blocks, seen), ...])`` — queries in
+        blocks of ``block`` (``pad`` rows added to fill the last), and
+        per group of consecutive blocks how many it has and how many
+        leading slots their scores span. Unaligned: one group, every
+        slot. Aligned (slot for query): up to four groups, each against
+        the prefix that ends where its last query stands."""
+        b = min(_sl.QUERY_BLOCK, t)
+        pad = -t % b
+        n_blocks = (t + pad) // b
+        groups = next(g for g in (4, 2, 1) if n_blocks % g == 0) \
+            if aligned else 1
+        per = n_blocks // groups
+        return b, pad, [(per, min(slots, (g + 1) * per * b) if aligned
+                         else slots) for g in range(groups)]
+
+    @property
+    def softmax_scale(self) -> float:
+        m = 1.0
+        if self.rope_factor > 1.0:
+            m = 0.1 * self.rope_mscale_all_dim * np.log(self.rope_factor) \
+                + 1.0
+        return float((self.qk_nope_head_dim + self.qk_rope_head_dim)
+                     ** -0.5 * m * m)
+
+    def _inv_freq(self):
+        return _sl.yarn_inv_freq(self.qk_rope_head_dim, self.rope_theta,
+                             self.rope_factor, self.rope_original_max,
+                             self.rope_beta_fast, self.rope_beta_slow)
+
+    def init(self, key, it):
+        if self.n_in is None:
+            self.n_in = it.size
+        if self.n_out is None:
+            self.n_out = self.n_in
+        dr = self.qk_rope_head_dim
+        if dr % 2 or dr > self.index_head_dim:
+            raise ValueError(
+                f"qk_rope_head_dim {dr} must be even and at most "
+                f"index_head_dim {self.index_head_dim} (the index key's "
+                f"first rope dims are rotated)")
+        h, e = self.n_heads, self.n_in
+        shapes = {
+            "Wqa": (e, self.q_lora_rank),
+            "Wqb": (self.q_lora_rank,
+                    h * (self.qk_nope_head_dim + dr)),
+            "Wkva": (e, self.kv_lora_rank + dr),
+            "Wkvb": (self.kv_lora_rank,
+                     h * (self.qk_nope_head_dim + self.v_head_dim)),
+            "Wo": (h * self.v_head_dim, self.n_out),
+            "Wiq": (self.q_lora_rank,
+                    self.index_n_heads * self.index_head_dim),
+            "Wik": (e, self.index_head_dim),
+            "Wiw": (e, self.index_n_heads)}
+        keys = jax.random.split(key, len(shapes))
+        p = {name: init_weights(k, s, s[0], s[1], self.weight_init,
+                                self.dist)
+             for k, (name, s) in zip(keys, shapes.items())}
+        p["q_gamma"] = jnp.ones((self.q_lora_rank,), jnp.float32)
+        p["kv_gamma"] = jnp.ones((self.kv_lora_rank,), jnp.float32)
+        p["ik_gamma"] = jnp.ones((self.index_head_dim,), jnp.float32)
+        p["ik_beta"] = jnp.zeros((self.index_head_dim,), jnp.float32)
+        return p, {}
+
+    # -- the forward -------------------------------------------------------
+    def apply(self, params, x, state, *, train=False, rng=None, mask=None,
+              stream=False, pad_left=None):
+        if pad_left is not None and not stream:
+            raise ValueError("pad_left is only meaningful for streaming")
+        x = self.maybe_dropout_input(x, train, rng)
+        xt = jnp.moveaxis(x, 1, 2)                              # [N,T,F]
+        proj = self._project(params, xt)
+        if not stream:
+            n, t = xt.shape[:2]
+            q_pos = jnp.arange(t, dtype=jnp.int32)[None]
+            keys = self._rotate_keys(proj, q_pos)
+            q = self._rotate_queries(proj, q_pos)
+            key_valid = None if mask is None else \
+                jnp.asarray(mask).reshape(n, t).astype(bool)
+            o, _ = self._attend_per_head(params, q, q_pos, keys, key_valid,
+                                         aligned=True)
+        elif state.get("kv_page_table") is not None:
+            if mask is not None or pad_left is not None:
+                raise ValueError(
+                    "direct paged decode is packed/maskless (the "
+                    "engine's decode dispatch shape)")
+            o, state = self._stream_paged(params, proj, state)
+        else:
+            o, state = self._stream_dense(params, proj, state, mask,
+                                          pad_left)
+        with jax.named_scope("mla.project"):
+            y = (o @ params["Wo"]).astype(x.dtype)
+        return _act.get(self.activation)(jnp.moveaxis(y, 2, 1)), state
+
+    def _project(self, p, xt):
+        """Everything a chunk's tokens give before positions enter: the
+        query parts, the cache leaves unrotated, the indexer's parts."""
+        n, t, _ = xt.shape
+        h, dn, dr = self.n_heads, self.qk_nope_head_dim, \
+            self.qk_rope_head_dim
+        with jax.named_scope("mla.project"):
+            cq = _rms_norm(xt @ p["Wqa"], p["q_gamma"], self.eps)
+            q = (cq @ p["Wqb"]).reshape(n, t, h, dn + dr)
+            kva = xt @ p["Wkva"]
+            ckv = _rms_norm(kva[..., :self.kv_lora_rank], p["kv_gamma"],
+                            self.eps)
+        with jax.named_scope("dsa.index"):
+            qi = (cq @ p["Wiq"]).reshape(n, t, self.index_n_heads,
+                                         self.index_head_dim)
+            ki = xt @ p["Wik"]
+            kf = ki.astype(jnp.float32)
+            mean = jnp.mean(kf, axis=-1, keepdims=True)
+            var = jnp.mean((kf - mean) ** 2, axis=-1, keepdims=True)
+            ki = ((kf - mean) * jax.lax.rsqrt(var + self.eps)
+                  * p["ik_gamma"].astype(jnp.float32)
+                  + p["ik_beta"].astype(jnp.float32)).astype(xt.dtype)
+            w = (xt @ p["Wiw"]) * (self.index_n_heads ** -0.5
+                                   * self.index_head_dim ** -0.5)
+        return {"q": q, "ckv": ckv, "kr": kva[..., self.kv_lora_rank:],
+                "qi": qi, "ki": ki, "w": w.astype(xt.dtype)}
+
+    def _tables(self, positions):
+        return _sl.rope_tables(jnp.maximum(positions, 0), self._inv_freq())
+
+    def _rotate_keys(self, proj, positions):
+        """(c_kv, rotated k_r, rotated k^I) of the chunk, the three
+        cache leaves in ``paged_leaves()`` order. positions [N|1, T]."""
+        dr = self.qk_rope_head_dim
+        cos, sin = self._tables(positions)
+        ki = proj["ki"]
+        ki = jnp.concatenate([_sl.rope_half(ki[..., :dr], cos, sin),
+                              ki[..., dr:]], -1)
+        return proj["ckv"], _sl.rope_interleaved(proj["kr"], cos, sin), ki
+
+    def _rotate_queries(self, proj, positions):
+        """(q_nope, rotated q_rope, rotated q^I, w)."""
+        dn, dr = self.qk_nope_head_dim, self.qk_rope_head_dim
+        cos, sin = self._tables(positions)
+        q, qi = proj["q"], proj["qi"]
+        qi = jnp.concatenate([_sl.rope_half(qi[..., :dr], cos, sin),
+                              qi[..., dr:]], -1)
+        return (q[..., :dn], _sl.rope_interleaved(q[..., dn:], cos, sin), qi,
+                proj["w"])
+
+    def index_selection(self, params, x):
+        """What the indexer makes of a whole sequence x [N,F,T] (no
+        cache): ``(scores [N,T,T] float32, selected [N,T,T] bool)``, the
+        sets the training forward attends. For tests and diagnostics."""
+        proj = self._project(params, jnp.moveaxis(x, 1, 2))
+        t = x.shape[2]
+        pos = jnp.arange(t, dtype=jnp.int32)
+        _, _, qi, w = self._rotate_queries(proj, pos[None])
+        scores = _sl.index_scores(qi, self._rotate_keys(proj, pos[None])[2], w)
+        causal = (pos[None, :] <= pos[:, None])[None]
+        return scores, _sl.top_k_mask(scores, jnp.broadcast_to(
+            causal, scores.shape), self.index_topk)
+
+    def _attend_per_head(self, p, q, q_pos, keys, key_valid=None,
+                         aligned=False):
+        """Per-head attention of a chunk's queries against ``keys`` =
+        (c_kv, k_r, k^I), each [N, L, .]; query t may see the slots
+        ``<= q_pos[t]`` (q_pos [N|1, T]) that ``key_valid`` [N|1, L]
+        admits. Queries go in blocks of ``QUERY_BLOCK``: a block's
+        [N, H, B, L] scores exist at once, the chunk's never. ``aligned``
+        says the keys are the chunk's own, slot for query (q_pos[t] = t):
+        the blocks then go in up to four groups, each against the prefix
+        of the keys that ends where its last query stands, so that a
+        block's scores span on average 5/8 of the chunk and not all of
+        it. Returns ``([N, T, H * v_head_dim], scored)``, ``scored`` the
+        (query row, slot) pairs whose attention scores it computed."""
+        q_nope, q_rope, qi, w = q
+        ckv, kr, ki = keys
+        n, t = q_nope.shape[:2]
+        L = ckv.shape[1]
+        h, dn, dv = self.n_heads, self.qk_nope_head_dim, self.v_head_dim
+        with jax.named_scope("mla.project"):
+            kv = (ckv @ p["Wkvb"]).reshape(n, L, h, dn + dv)
+        k_nope, v = kv[..., :dn], kv[..., dn:]
+        slot = jnp.arange(L, dtype=jnp.int32)
+        q_pos = jnp.broadcast_to(q_pos, (n, t))
+        b, pad, groups = self._query_groups(t, L, aligned)
+
+        def blocks(a):
+            a = jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
+            a = a.reshape((n, (t + pad) // b, b) + a.shape[2:])
+            return jnp.moveaxis(a, 1, 0)
+
+        def attend(args, seen):
+            """One block of queries against the first ``seen`` slots."""
+            qn, qr, qib, wb, pos = args
+            valid = slot[None, None, :seen] <= pos[..., None]   # [N,B,s]
+            if key_valid is not None:
+                valid = valid & key_valid[:, None, :seen]
+            with jax.named_scope("dsa.index"):
+                scores = _sl.index_scores(qib, ki[:, :seen], wb)
+            with jax.named_scope("dsa.select"):
+                sel = _sl.top_k_mask(scores, valid, self.index_topk)
+            with jax.named_scope("mla.attend"):
+                s = jnp.einsum("nqhd,nshd->nhqs", qn, k_nope[:, :seen],
+                               preferred_element_type=jnp.float32) \
+                    + jnp.einsum("nqhd,nsd->nhqs", qr, kr[:, :seen],
+                                 preferred_element_type=jnp.float32)
+                s = jnp.where(sel[:, None], s * self.softmax_scale, _sl.MASKED)
+                a = jax.nn.softmax(s, axis=-1).astype(v.dtype)
+                return jnp.einsum("nhqs,nshd->nqhd", a, v[:, :seen],
+                                  preferred_element_type=jnp.float32
+                                  ).astype(v.dtype)
+
+        parts = tuple(blocks(a) for a in (q_nope, q_rope, qi, w, q_pos))
+        out, at = [], 0
+        for per, seen in groups:
+            out.append(jax.lax.map(
+                lambda args, seen=seen: attend(args, seen),
+                tuple(a[at:at + per] for a in parts)))
+            at += per
+        o = jnp.moveaxis(jnp.concatenate(out), 0, 1)
+        return (o.reshape(n, t + pad, h * dv)[:, :t],
+                n * sum(per * b * seen for per, seen in groups))
+
+    @staticmethod
+    def _counted(state, scored: int):
+        """``state`` with ``scored`` more positions in ``attn_stats``."""
+        return {**state, "attn_stats": jnp.asarray(scored, jnp.int32)
+                + state.get("attn_stats", 0)}
+
+    def _stream_dense(self, p, proj, state, mask, pad_left):
+        """Dense streaming: append the chunk's three leaves to the
+        [N, L, .] caches at a shared scalar position (left pads dropped,
+        ``SelfAttentionLayer._stream_attend``'s packed accounting), then
+        per-head attention: of a stream's FIRST chunk (no cache in the
+        state yet: a fresh prime) against the chunk's own keys, slot for
+        query, the pads masked; of a later chunk against the whole
+        cache."""
+        if self.cache_length <= 0:
+            raise ValueError(
+                "LatentAttentionLayer streaming needs cache_length > 0")
+        if mask is not None:
+            raise ValueError(
+                "LatentAttentionLayer streams maskless chunks (left-pad "
+                "with pad_left, or stream rows of equal length)")
+        n, t = proj["q"].shape[:2]
+        L = self.cache_length
+        leaves = self.paged_leaves()
+        pos = state.get("kv_pos")
+        if pos is None:
+            pos = jnp.zeros((), jnp.int32)
+        if getattr(pos, "ndim", 0) >= 1:
+            raise ValueError(
+                "LatentAttentionLayer streams per-row positions only "
+                "behind a page table (the serving engine's paged decode)")
+        if pad_left is not None:
+            m0 = jnp.arange(t) >= pad_left
+            cum = jnp.cumsum(m0.astype(pos.dtype))
+            q_pos = pos + cum - 1                    # pads: pos - 1
+            slots = jnp.where(m0, q_pos, L)          # pads: dropped
+            n_new = cum[-1]
+        else:
+            q_pos = pos + jnp.arange(t, dtype=pos.dtype)
+            slots, n_new = q_pos, t
+        new = self._rotate_keys(proj, q_pos[None])
+        fresh = all(state.get(leaf.key) is None for leaf in leaves)
+        caches = []
+        for leaf, chunk in zip(leaves, new):
+            c = state.get(leaf.key)
+            if c is None:
+                c = jnp.zeros(leaf.shape(n, L), chunk.dtype)
+            caches.append(c.at[:, slots].set(chunk.astype(c.dtype),
+                                             mode="drop"))
+        q = self._rotate_queries(proj, q_pos[None])
+        if fresh and state.get("kv_pos") is None:
+            real = None if pad_left is None else m0[None]
+            o, scored = self._attend_per_head(
+                p, q, jnp.arange(t, dtype=jnp.int32)[None], new, real,
+                aligned=True)
+        else:
+            o, scored = self._attend_per_head(p, q, q_pos[None],
+                                              tuple(caches))
+        out = {**self._counted(state, scored), "kv_pos": pos + n_new}
+        out.update({leaf.key: c for leaf, c in zip(leaves, caches)})
+        return o, out
+
+    def _stream_paged(self, p, proj, state):
+        """Direct paged decode in the absorbed form: the chunk's leaves
+        append at each row's (page, offset); every row scores the index
+        keys of its whole context through the table, keeps the
+        ``index_topk`` best, gathers those positions' ``c_kv`` and ``k_r``
+        from the pool and attends them and nothing else. Appends past a
+        row's allocation or capacity land on the null page 0."""
+        leaves = self.paged_leaves()
+        pools = [state[leaf.page_key] for leaf in leaves]
+        table = state["kv_page_table"]
+        pos = state.get("kv_pos")
+        if pos is None or getattr(pos, "ndim", 0) < 1:
+            raise ValueError("direct paged decode needs the per-row "
+                             "kv_pos vector (the engine arena's)")
+        n, t = proj["q"].shape[:2]
+        L, ps, n_blk = self.cache_length, pools[0].shape[1], table.shape[1]
+        h, dn, dv = self.n_heads, self.qk_nope_head_dim, self.v_head_dim
+        q_pos = pos[:, None] + jnp.arange(t, dtype=pos.dtype)      # [N,T]
+        new = self._rotate_keys(proj, q_pos)
+        blk = jnp.clip(q_pos // ps, 0, n_blk - 1).astype(jnp.int32)
+        page = jnp.where(q_pos < L,
+                         jnp.take_along_axis(table, blk, axis=1), 0)
+        off = (q_pos % ps).astype(jnp.int32)
+        pools = [pool.at[page, off].set(chunk.astype(pool.dtype))
+                 for pool, chunk in zip(pools, new)]
+        pool_c, pool_r, pool_i = pools
+        q_nope, q_rope, qi, w = self._rotate_queries(proj, q_pos)
+        with jax.named_scope("dsa.index"):
+            ki = pool_i[table].reshape(n, n_blk * ps, -1)[:, :L]
+            scores = _sl.index_scores(qi, ki, w)                     # [N,T,L]
+        with jax.named_scope("dsa.select"):
+            live = jnp.arange(L)[None, None, :] <= q_pos[..., None]
+            top = min(self.index_topk, L)
+            best, idx = jax.lax.top_k(
+                jnp.where(live, scores, -jnp.inf), top)          # [N,T,k]
+            chosen = best > -jnp.inf
+        with jax.named_scope("mla.attend"):
+            rows = jnp.arange(n)[:, None, None]
+            sel_page = table[rows, jnp.minimum(idx // ps, n_blk - 1)]
+            c = pool_c[sel_page, idx % ps]                     # [N,T,k,kl]
+            r = pool_r[sel_page, idx % ps]                     # [N,T,k,dr]
+            w_kvb = p["Wkvb"].reshape(self.kv_lora_rank, h, dn + dv)
+            q_lat = jnp.einsum("nqhd,chd->nqhc", q_nope, w_kvb[..., :dn],
+                               preferred_element_type=jnp.float32
+                               ).astype(c.dtype)
+            s = jnp.einsum("nqhc,nqkc->nhqk", q_lat, c,
+                           preferred_element_type=jnp.float32) \
+                + jnp.einsum("nqhd,nqkd->nhqk", q_rope, r,
+                             preferred_element_type=jnp.float32)
+            s = jnp.where(chosen[:, None], s * self.softmax_scale, _sl.MASKED)
+            a = jax.nn.softmax(s, axis=-1).astype(c.dtype)
+            o_lat = jnp.einsum("nhqk,nqkc->nqhc", a, c,
+                               preferred_element_type=jnp.float32
+                               ).astype(c.dtype)
+            o = jnp.einsum("nqhc,chd->nqhd", o_lat, w_kvb[..., dn:],
+                           preferred_element_type=jnp.float32
+                           ).astype(c.dtype)
+        out = {**self._counted(state, n * t * top), "kv_pos": pos + t}
+        out.update({leaf.page_key: pool
+                    for leaf, pool in zip(leaves, pools)})
+        return o.reshape(n, t, h * dv), out
+
+
+@register_layer
+@dataclass
+class RoutedExpertsLayer(FeedForwardLayerConf):
+    """A layer of routed experts that is told which experts it holds:
+    the router is the model's (``router_experts`` outputs, grouped
+    sigmoid choice with a selection bias, ``top_k`` a token, gates scaled
+    by ``scale``; nn/layers/routed_experts.py), the expert matrices are
+    those of ``held`` = (first, count) alone, and the layer gives
+    ``shared(x) + sum over held i of g_i E_i(x)``, every expert a gated
+    (SiLU) feed-forward ``hidden`` wide. What the experts held elsewhere
+    would add is theirs to add: on one device the layer runs with no
+    exchange, and with ``held`` = (0, router_experts) it is the whole
+    layer.
+
+    Streaming (``rnn_time_step``) computes the held part by the grouped
+    product, tile by tile over tokens laid out by expert, dropping no
+    token; left pads route nowhere. It carries ``moe_stats`` int32 [4] in
+    its state — tokens routed, (token, held expert) pairs, rows the
+    grouped product computed (whole tiles), the fullest expert's load in
+    one call (a maximum, the others sums) — which the serving engine
+    reads in ``health()["experts"]``. The training forward runs every
+    held expert over every token (differentiable)."""
+
+    hidden: int = 64
+    router_experts: int = 8
+    held: Any = (0, 8)
+    top_k: int = 2
+    groups: int = 1
+    top_groups: int = 1
+    scale: float = 1.0
+    shared: int = 1
+
+    supports_streaming = True
+
+    def __post_init__(self):
+        self.held = (int(self.held[0]), int(self.held[1]))
+        first, count = self.held
+        if not (0 <= first and count >= 1
+                and first + count <= self.router_experts):
+            raise ValueError(f"held {self.held} is no range of the "
+                             f"router's {self.router_experts} experts")
+        if self.router_experts % self.groups or \
+                self.top_groups > self.groups:
+            raise ValueError("groups must divide router_experts, and "
+                             "top_groups cannot pass groups")
+
+    def output_type(self, it):
+        if it.kind != "rnn":
+            raise ValueError("RoutedExpertsLayer needs RNN input [N,F,T]")
+        return InputType.recurrent(self.n_out or it.size, it.timesteps)
+
+    def init(self, key, it):
+        if self.n_in is None:
+            self.n_in = it.size
+        if self.n_out is None:
+            self.n_out = self.n_in
+        f, i, g = self.n_in, self.hidden, self.held[1]
+        ks = jax.random.split(key, 7)
+
+        def w(k, shape, a, b):
+            return init_weights(k, shape, a, b, self.weight_init, self.dist)
+
+        p = {"Wr": w(ks[0], (f, self.router_experts), f,
+                     self.router_experts),
+             "br": jnp.zeros((self.router_experts,), jnp.float32),
+             "Wg": w(ks[1], (g, f, i), f, i), "Wu": w(ks[2], (g, f, i), f, i),
+             "Wd": w(ks[3], (g, i, self.n_out), i, self.n_out)}
+        if self.shared:
+            s = i * self.shared
+            p.update(Ws_g=w(ks[4], (f, s), f, s), Ws_u=w(ks[5], (f, s), f, s),
+                     Ws_d=w(ks[6], (s, self.n_out), s, self.n_out))
+        return p, {}
+
+    def apply(self, params, x, state, *, train=False, rng=None, mask=None,
+              stream=False, pad_left=None):
+        x = self.maybe_dropout_input(x, train, rng)
+        n, f, t = x.shape
+        flat = jnp.moveaxis(x, 1, 2).reshape(n * t, f)
+        valid = None
+        if pad_left is not None:
+            valid = jnp.broadcast_to(jnp.arange(t) >= pad_left, (n, t))
+        elif mask is not None:
+            valid = jnp.asarray(mask).reshape(n, t).astype(bool)
+        first, count = self.held
+        with jax.named_scope("moe.route"):
+            gates = _re.router_gates(
+                flat, params["Wr"], params["br"], groups=self.groups,
+                top_groups=self.top_groups, top_k=self.top_k,
+                scale=self.scale)[:, first:first + count]
+            if valid is not None:
+                gates = jnp.where(valid.reshape(-1, 1), gates, 0.0)
+        with jax.named_scope("moe.experts"):
+            if stream:
+                tile = min(_re.GROUP_TILE,
+                           max(16, 1 << (n * t - 1).bit_length()))
+                y, stats = _re.grouped_experts(
+                    flat, gates, params["Wg"], params["Wu"], params["Wd"],
+                    tile=tile, max_per_token=self.top_k)
+                tokens = n * t if valid is None else jnp.sum(valid)
+                prev = state.get("moe_stats")
+                if prev is None:
+                    prev = jnp.zeros((4,), jnp.int32)
+                state = {**state, "moe_stats": jnp.stack([
+                    prev[0] + tokens, prev[1] + stats[0],
+                    prev[2] + stats[1],
+                    jnp.maximum(prev[3], stats[2])]).astype(jnp.int32)}
+            else:
+                y = _re.dense_experts(flat, gates, params["Wg"],
+                                      params["Wu"], params["Wd"])
+        if self.shared:
+            with jax.named_scope("moe.shared"):
+                y = y + _re.gated_ffn(flat, params["Ws_g"], params["Ws_u"],
+                                      params["Ws_d"])
+        y = jnp.moveaxis(y.astype(x.dtype).reshape(n, t, -1), 2, 1)
+        return _act.get(self.activation)(y), state

@@ -1375,7 +1375,11 @@ class ComputationGraph(LazyScore):
         its reference sequence, which that rule also picks: its first —
         collapsed — input is non-temporal)."""
         out_types = self._infer_types()
+        # a sequence of ids [N, T] (SequenceEmbeddingLayer's input) is
+        # temporal too: its declared type says so, its rank does not
         lens = {name: (int(a.shape[-1]) if getattr(a, "ndim", 0) == 3
+                       or (getattr(a, "ndim", 0) == 2
+                           and self.conf.input_types[name].kind == "rnn")
                        else None)
                 for name, a in ins.items()}
         for name in self._topo:

@@ -133,7 +133,14 @@ counts at the same boundaries: ``decode_dispatch.rows``, ``sample``
 from their row, ``block_fetches`` = plain cycles that fetched [S, V]),
 ``prefill`` (tokens fed, padded widths dispatched; tokens the prefix
 cache served instead are ``prefix_cache.reused_tokens``) and ``host_io``
-(bytes of the numpy arrays that cross around ``rnn_time_step``).
+(bytes of the numpy arrays that cross around ``rnn_time_step``: int32
+ids for a net that takes ids, the one-hot block otherwise — the engine
+asks the net, ``util.decoding.takes_ids``). A net with routed-expert
+layers adds ``experts`` (their ``moe_stats``), one with sparse-selection
+attention ``sparse_attn`` (host counts from each dispatch's rows, and
+the layers' ``attn_stats``); what the layers count is joined on the
+device behind every dispatch and fetched when ``health()`` is called,
+never in the cycle. ``serving/health.py`` documents both.
 Between cycles, in no span: the serving loop's ``HANDOFF_WAIT_S`` park
 after a cycle that freed a slot.
 """
@@ -159,8 +166,8 @@ from deeplearning4j_tpu.monitoring.metrics import (
 from deeplearning4j_tpu.monitoring.tracing import next_phase, phases
 from deeplearning4j_tpu.nn.conf.layers import (
     BATCHED_STREAM_KEYS, PositionalEmbeddingLayer, check_rewindable,
-    paged_decode_impl, rewind_stream_state, set_paged_decode_impl,
-    stream_capacity)
+    paged_decode_impl, paged_leaves, rewind_stream_state,
+    set_paged_decode_impl, stream_capacity)
 from deeplearning4j_tpu.resilience.chaos import fire as _fire_chaos
 from deeplearning4j_tpu.resilience.retry import RetryPolicy, retry_call
 from deeplearning4j_tpu.serving.errors import (
@@ -183,8 +190,8 @@ from deeplearning4j_tpu.serving.overload import (
 from deeplearning4j_tpu.serving.paged_kernel import (
     paged_attention_supported)
 from deeplearning4j_tpu.serving.paging import (
-    PagedKVConfig, PagePool, gather_pages, pages_needed, scatter_pages,
-    set_page)
+    PagedKVConfig, PagePool, allocate_pools, gather_pages, pages_needed,
+    scatter_pages, set_page)
 from deeplearning4j_tpu.serving.prefix_cache import (
     ROOT_DIGEST, PrefixCache, chain_digests)
 from deeplearning4j_tpu.serving.request import (
@@ -301,6 +308,51 @@ def _scatter_rows(arena, primed, slot):
     return out
 
 
+def _page_key(key: str) -> str:
+    """The pool leaf's state key of a dense cache leaf: kv_k ->
+    kv_page_k (``nn.conf.layers.PagedLeaf.page_key``)."""
+    return "kv_page_" + key[len("kv_"):]
+
+
+def _scale_key(key: str) -> str:
+    return "kv_page_scale_" + key[len("kv_"):]
+
+
+#: ``_join_stats`` keeps the scored positions as (high, low) uint32 with
+#: the low word under 2^30: a prime of 8,192 scores 2^25 positions a
+#: layer, so five layers would pass one int32 in ten primes
+_LOW_BITS = 30
+
+
+def _join_experts(total, stats):
+    """``experts`` [layers, 4] with a ``moe_stats`` [4] a layer: tokens,
+    pairs and rows add (int32, wrapping: ``_expert_counters`` takes
+    differences), the fullest expert's load is a maximum."""
+    new = jnp.stack(stats)
+    return jnp.concatenate([total[:, :3] + new[:, :3],
+                            jnp.maximum(total[:, 3:], new[:, 3:])], 1)
+
+
+def _join_attended(total, stats):
+    """``attended`` [2] (high, low) with an ``attn_stats`` scalar a layer,
+    carried from the low word into the high."""
+    low = total[1] + sum(a.astype(jnp.uint32) for a in stats)
+    return jnp.stack([total[0] + (low >> _LOW_BITS),
+                      low & ((1 << _LOW_BITS) - 1)])
+
+
+_JOIN = {"experts": _join_experts, "attended": _join_attended}
+
+
+@jax.jit
+def _join_stats(acc, stats):
+    """One dispatch's layer counters joined to the accumulators, each
+    kind under its key in both dicts (a net has either kind of layer,
+    both or neither): one program whatever the net has."""
+    return {kind: _JOIN[kind](total, stats[kind])
+            for kind, total in acc.items()}
+
+
 class GenerationEngine:
     """Continuous-batching generation over a fixed S-slot arena.
 
@@ -365,10 +417,23 @@ class GenerationEngine:
         self._merge_keys = None
         # -- block-paged KV arena (serving/paging.py) ------------------
         self._paging = paging
+        if (paging is None or not paging.direct) and any(
+                leaf.key not in ("kv_k", "kv_v")
+                for l in layers for leaf in paged_leaves(l)):
+            raise ValueError(
+                "this net's attention keeps more than keys and values "
+                "per token and decodes per-row positions only through "
+                "a page table: construct with "
+                "paging=PagedKVConfig(direct=True)")
         self._pool: Optional[PagePool] = None
         self._prefix: Optional[PrefixCache] = None
         self._page_store = None            # device pools, per paged leaf
-        self._paged_keys = None            # [(layer name, kv_k|kv_v)]
+        self._paged_keys = None            # [(layer name, dense leaf key)]
+        #: what the net's streaming layers declare they keep per token
+        #: (``paged_leaves``), by state name in sorted order, and each
+        #: pool's token axis: the pool's leaves are built from this
+        self._paged_decl = []
+        self._paged_axes = None
         self._page_tables: List[List[int]] = [[] for _ in range(slots)]
         #: fleet page-shipping hook (serving/fleet/agent.py sets it):
         #: called as ``page_publisher(prompt, table)`` right after a
@@ -435,6 +500,16 @@ class GenerationEngine:
             self._L = lens.pop()
             self._ps = paging.page_size
             self._n_max = -(-self._L // self._ps)
+            self._paged_decl = self._declared_leaves()
+            if not self._paged_decl:
+                raise ValueError(
+                    "block-paged KV needs layers that declare what they "
+                    "keep per token (paged_leaves())")
+            #: keys and values [Hkv, D] a token: the one layout the
+            #: grouped-query kernel, the int8 sidecar and the crossover
+            #: fingerprints know
+            plain = all(leaf.key in ("kv_k", "kv_v")
+                        for _, leaf in self._paged_decl)
             # -- kv_dtype resolution (before pool sizing: a byte
             # budget and the impl eligibility both depend on it) -----
             l0 = kv_layers[0]
@@ -442,6 +517,15 @@ class GenerationEngine:
             recurrent = any(getattr(l, "carries_recurrent_state", False)
                             for l in layers)
             kv_dtype = getattr(paging, "kv_dtype", "bf16")
+            if not plain:
+                if kv_dtype == "int8" or paging.decode_impl == "pallas":
+                    raise ValueError(
+                        "the int8 sidecar and the paged-attention "
+                        "kernel know [Hkv, D] keys and values only; "
+                        f"this net's layers declare "
+                        f"{sorted({l.key for _, l in self._paged_decl})}"
+                        " (use kv_dtype='bf16', decode_impl='xla')")
+                kv_dtype = "bf16"
             if kv_dtype != "bf16":
                 from deeplearning4j_tpu.tuning.plan import (
                     quant_key_for_engine, resolve_kv_dtype)
@@ -469,7 +553,12 @@ class GenerationEngine:
                     "paged path (use kv_dtype='bf16', or a pure-"
                     "attention model)")
             self._kv_dtype = kv_dtype
-            if paging.total_bytes is not None:
+            if paging.total_bytes is not None and not plain:
+                usable = paging.resolve_pages_bytes(
+                    self._ps * jnp.dtype(native_dtype).itemsize
+                    * sum(leaf.token_elements
+                          for _, leaf in self._paged_decl))
+            elif paging.total_bytes is not None:
                 from deeplearning4j_tpu.serving.quant import (
                     kv_page_bytes)
                 dims = self._paged_layer_dims()
@@ -480,7 +569,12 @@ class GenerationEngine:
                 usable = paging.resolve_pages(slots, self._n_max)
             self._pool = PagePool(usable + 1, self._ps)  # +1: null page
             self._direct = bool(paging.direct)
-            if self._direct:
+            if self._direct and not plain:
+                # no kernel reads these leaves: the layers' own paged
+                # form runs, gathers folded into the dispatch
+                set_paged_decode_impl("xla", False)
+                self._decode_impl = "xla"
+            elif self._direct:
                 from deeplearning4j_tpu.tuning.plan import (
                     decode_key_for_engine, resolve_decode_impl)
                 #: the crossover fingerprint of this engine's decode
@@ -535,6 +629,12 @@ class GenerationEngine:
                 self._init_quant_store()
         # -- in-engine speculation (SpeculationConfig) -----------------
         self._speculation = speculation
+        if speculation is not None and any(
+                getattr(l, "last_step_only", False) for l in layers):
+            raise ValueError(
+                "in-engine speculation verifies every position of a "
+                "widened chunk; this net's head answers for the last "
+                "position only (LastStepOutputLayer)")
         if speculation is not None:
             # rewind up to the full uniform chunk (gamma + 1 — a free
             # row keeps nothing); fails fast for LSTMs / tight windows
@@ -549,6 +649,37 @@ class GenerationEngine:
         #: that took the device's argmax, rows that sampled from their row,
         #: cycles that fetched the [S, V] block for the latter
         self._greedy_rows = self._drawn_rows = self._block_fetches = 0
+        #: layers whose streaming state carries counters, by state name
+        #: and counter: routed experts' ``moe_stats``, sparse-selection
+        #: attention's ``attn_stats``. Every dispatch's counts are taken
+        #: out of the state it returns and joined to ``_stats_acc`` ON THE
+        #: DEVICE (one tiny program queued behind the dispatch; nothing is
+        #: fetched in the cycle). The accumulators are no part of the
+        #: donated state, so ``health()`` reads them from any thread
+        #: without the step lock; ``_expert_seen`` / ``_expert_host`` are
+        #: what it has read of the experts' so far
+        layers = self._named_layers()
+        self._expert_names = [n for n, l in layers
+                              if hasattr(l, "router_experts")]
+        self._sparse_names = [n for n, l in layers
+                              if hasattr(l, "index_topk")]
+        self._stats_acc = {}
+        if self._expert_names:
+            self._stats_acc["experts"] = jnp.zeros(
+                (len(self._expert_names), 4), jnp.int32)
+        if self._sparse_names:
+            self._stats_acc["attended"] = jnp.zeros((2,), jnp.uint32)
+        self._expert_seen = np.zeros((len(self._expert_names), 3),
+                                     np.uint32)
+        self._expert_host = [0, 0, 0, 0]
+        self._expert_mutex = threading.Lock()
+        #: how much of a context each sparse-selection layer keeps, and
+        #: the host's counts of ``health()["sparse_attn"]``
+        self._sparse_topk = [l.index_topk for n, l in layers
+                             if n in self._sparse_names]
+        self._sparse = dict.fromkeys(
+            ("query_positions", "context_positions", "selected_positions"),
+            0)
         self._io = {"decode": _HostIO("decode"),
                     "prefill": _HostIO("prefill", widths=True)}
         self._prefill_chaos = prefill_chaos
@@ -794,6 +925,14 @@ class GenerationEngine:
                    "bucket_tokens": self._io["prefill"].width},
                "host_io": {k: io.as_dict()
                            for k, io in self._io.items()}}
+        if self._expert_names:
+            out["experts"] = self._expert_counters()
+        if self._sparse_names:
+            high, low = (int(v) for v in
+                         np.asarray(self._stats_acc["attended"]))
+            out["sparse_attn"] = dict(
+                self._sparse,
+                attended_positions=(high << _LOW_BITS) + low)
         if self._pool is not None:
             out["kv_pages"] = {"total": self._pool.usable,
                                "used": self._pool.used_count(),
@@ -833,6 +972,59 @@ class GenerationEngine:
         # polled paths (every autoscaler tick reads every replica)
         out["last_events"] = list(self._own_events)
         return out
+
+    def _count_sparse(self, contexts) -> None:
+        """One dispatch's share of the host counts of
+        ``health()["sparse_attn"]``, from its rows alone, summed over the
+        sparse-attention layers: `contexts` = positions each REAL query
+        of the dispatch may see (itself included); ``selected`` is what
+        the selection keeps of them. (What the programs scored,
+        ``attended_positions``, the layers count themselves.)"""
+        contexts = np.asarray(contexts, np.int64)
+        c = self._sparse
+        for top in self._sparse_topk:
+            c["query_positions"] += len(contexts)
+            c["context_positions"] += int(contexts.sum())
+            c["selected_positions"] += int(np.minimum(contexts, top).sum())
+
+    def _take_stats(self, state: dict) -> dict:
+        """Take one dispatch's layer counters out of the stream state it
+        returned and join them to the accumulators on the device. The
+        state handed on carries none: the next dispatch counts from
+        nought, and the accumulators are never donated."""
+        if not self._stats_acc:
+            return state
+        state = dict(state)
+        stats = {}
+        for kind, key, names in (
+                ("experts", "moe_stats", self._expert_names),
+                ("attended", "attn_stats", self._sparse_names)):
+            for n in names:
+                state[n] = d = dict(state[n])
+                stats.setdefault(kind, []).append(d.pop(key))
+        self._stats_acc = _join_stats(self._stats_acc, stats)
+        return state
+
+    def _expert_counters(self) -> dict:
+        """``health()["experts"]``: the device accumulator fetched HERE
+        (from any thread, no step lock: it is complete up to the last
+        dispatch that finished) and summed over the expert layers. The
+        device sums are int32 and wrap; this keeps Python integers and
+        adds the difference since its last reading, so it is exact as
+        long as ``health()`` is read once in 2^32 routed tokens a
+        layer."""
+        now = np.asarray(self._stats_acc["experts"])
+        sums = now[:, :3].astype(np.int32).view(np.uint32)
+        with self._expert_mutex:
+            moved = (sums - self._expert_seen).sum(axis=0, dtype=np.uint64)
+            self._expert_seen = sums.copy()
+            for i in range(3):
+                self._expert_host[i] += int(moved[i])
+            self._expert_host[3] = max(self._expert_host[3],
+                                       int(now[:, 3].max()))
+            tokens, pairs, rows, load = self._expert_host
+        return {"tokens": tokens, "held_pairs": pairs,
+                "rows_computed": rows, "max_expert_load": load}
 
     @property
     def page_pool(self) -> Optional[PagePool]:
@@ -1292,7 +1484,8 @@ class GenerationEngine:
         row = np.zeros((1, self._n_max), np.int32)
         n_hit = hit_len // self._ps
         row[0, :n_hit] = table[:n_hit]
-        dense = gather_pages(self._page_store, row, length=self._L)
+        dense = gather_pages(self._page_store, row, length=self._L,
+                             axes=self._paged_axes)
         self._kv_traffic(self._L * self._tok_bytes)   # one-row gather
         pos = jnp.asarray(hit_len, jnp.int32)
         for (n, k), leaf in zip(self._paged_keys, dense):
@@ -1357,6 +1550,8 @@ class GenerationEngine:
             p0 = prime_prompt(net, prime_ids[hit_len:], self.V,
                               padded=self._prime_padded,
                               io=self._io["prefill"])
+            if self._sparse_names:
+                self._count_sparse(np.arange(hit_len, len(prime_ids)) + 1)
             req.trace.record("prefill_end")
             next_phase("engine.seat")
             primed_pos = self._net_pos(net)
@@ -1371,6 +1566,7 @@ class GenerationEngine:
             self._recent_traces.append(req.trace)
             return
         primed_state = dict(net.state)
+        primed_state = self._take_stats(primed_state)
         if self._kv_dtype == "int8":
             # pools/scales come back out of the prime's state AFTER the
             # snapshot: every early-exit below (failure already returned;
@@ -1483,6 +1679,7 @@ class GenerationEngine:
             self._page_store = None
             self._scale_store = None
             self._paged_keys = None
+            self._paged_axes = None
             self._page_tables = [[] for _ in range(self.slots)]
             self._invalidate_tables()
             self._kv_pos_dirty = False   # the rebuilt state is fresh
@@ -1932,40 +2129,61 @@ class GenerationEngine:
             self._kv_pos_dirty = True
         self._sync_accounting()
 
+    def _named_layers(self):
+        """(state name, layer) of every layer of the net: the index of a
+        MultiLayerNetwork's layer, the vertex name of a graph's."""
+        named = [(str(i), l) for i, l in
+                 enumerate(getattr(self.net, "layers", None) or [])]
+        vertices = getattr(getattr(self.net, "conf", None),
+                           "vertices", None) or {}
+        return named + [(n, v.layer) for n, v in vertices.items()
+                        if getattr(v, "layer", None) is not None]
+
+    def _declared_leaves(self):
+        """(state name, PagedLeaf) of everything the net's streaming
+        layers keep per token, by state name and, within a layer, in the
+        layer's own order: the order of every per-leaf list here
+        (``_paged_keys``, ``_page_store``, ``_paged_axes``)."""
+        out = []
+        for n, l in sorted(self._named_layers(), key=lambda nl: nl[0]):
+            if getattr(l, "supports_streaming", False) \
+                    and getattr(l, "cache_length", 0):
+                out += [(n, leaf) for leaf in paged_leaves(l)]
+        return out
+
     def _init_page_store(self, primed_state) -> None:
-        """First-admission pool build: one device page array per paged
-        leaf (kv_k/kv_v of every attention layer), sized
-        [total_pages, Hkv, page_size, D] in the leaf's dtype."""
-        keys, store = [], []
-        for n in sorted(primed_state):
-            s = primed_state[n]
-            if not isinstance(s, dict):
-                continue
-            for k in ("kv_k", "kv_v"):
-                if k not in s:
-                    continue
-                # first-admission pool construction (runs once per
-                # engine), not the per-token decode steady state
-                # tpulint: disable=device-transfer-in-hot-loop
-                v = jnp.asarray(s[k])      # [1, Hkv, L, D]
-                if v.shape[2] != self._L:
-                    raise RuntimeError(
-                        f"paged leaf {n}.{k} carries length "
-                        f"{v.shape[2]} != cache_length {self._L}")
-                keys.append((n, k))
-                store.append(jnp.zeros(
-                    (self._pool.total_pages, v.shape[1], self._ps,
-                     v.shape[3]), v.dtype))
-        if not keys:
-            raise RuntimeError("paged mode found no kv_k/kv_v leaves "
-                               "in the primed stream state")
-        self._paged_keys = keys
-        self._page_store = store
+        """First-admission pool build: one device page array per leaf
+        the layers declare (``paged_leaves()``: an attention layer's
+        kv_k / kv_v ``[total_pages, Hkv, page_size, D]``, a
+        latent-attention layer's three ``[total_pages, page_size, W]``),
+        in the dtype the primed leaf came in."""
+        dtypes = []
+        for n, leaf in self._paged_decl:
+            v = (primed_state.get(n) or {}).get(leaf.key)
+            if v is None or tuple(v.shape) != leaf.shape(1, self._L):
+                raise RuntimeError(
+                    f"layer {n} declares the paged leaf {leaf.key} as "
+                    f"{leaf.shape(1, self._L)}; its primed state holds "
+                    f"{None if v is None else tuple(v.shape)}")
+            dtypes.append(v.dtype)
+        leaves = [leaf for _, leaf in self._paged_decl]
+        self._paged_keys = [(n, leaf.key) for n, leaf in self._paged_decl]
+        self._paged_axes = tuple(leaf.token_axis + 1 for leaf in leaves)
+        self._page_store = allocate_pools(
+            self._pool.total_pages, self._ps, leaves, dtypes)
         # per-token KV bytes summed over leaves — the unit of the
         # modeled kv-bytes-moved accounting
-        self._tok_bytes = sum(
-            int(p.shape[1]) * int(p.shape[3]) * p.dtype.itemsize
-            for p in store)
+        self._tok_bytes = sum(leaf.token_elements * dt.itemsize
+                              for leaf, dt in zip(leaves, dtypes))
+        # tokens of each leaf ONE row's direct-xla dispatch reads: the
+        # whole mapped view, unless the layer says it reads fewer (a
+        # sparse layer gathers its selection)
+        layers = dict(self._named_layers())
+        self._xla_read_bytes = sum(
+            getattr(layers[n], "paged_read_tokens",
+                    dict)().get(leaf.key, self._L)
+            * leaf.token_elements * dt.itemsize
+            for (n, leaf), dt in zip(self._paged_decl, dtypes))
 
     def _paged_layer_dims(self):
         """(state name, Hkv, head_dim) per paged attention layer,
@@ -1973,14 +2191,8 @@ class GenerationEngine:
         _init_page_store derives from a primed state (sorted() over
         the state keys), so the eager int8 store and the lazy bf16
         store address identical leaves."""
-        named = [(str(i), l) for i, l in
-                 enumerate(getattr(self.net, "layers", None) or [])]
-        vertices = getattr(getattr(self.net, "conf", None),
-                           "vertices", None) or {}
-        named += [(n, v.layer) for n, v in vertices.items()
-                  if getattr(v, "layer", None) is not None]
         out = []
-        for n, l in named:
+        for n, l in self._named_layers():
             if getattr(l, "supports_streaming", False) \
                     and getattr(l, "cache_length", 0):
                 hkv = getattr(l, "n_kv_heads", None) or l.n_heads
@@ -1995,11 +2207,13 @@ class GenerationEngine:
         from deeplearning4j_tpu.serving.quant import pool_leaves
         self._paged_keys = [(n, k) for n, _, _ in self._quant_dims
                             for k in ("kv_k", "kv_v")]
+        self._paged_axes = (2,) * len(self._paged_keys)
         self._page_store, self._scale_store = pool_leaves(
             self._pool.total_pages, self._ps,
             [(h, d) for _, h, d in self._quant_dims])
         self._tok_bytes = sum(2 * h * d                  # int8: 1 B/el
                               for _, h, d in self._quant_dims)
+        self._xla_read_bytes = self._L * self._tok_bytes
         self._scale_row_bytes = sum(2 * h * 4
                                     for _, h, _ in self._quant_dims)
 
@@ -2028,10 +2242,8 @@ class GenerationEngine:
         for i, (n, k) in enumerate(self._paged_keys):
             cur = st.get(n)
             d = dict(cur) if isinstance(cur, dict) else {}
-            d["kv_page_k" if k == "kv_k" else "kv_page_v"] = \
-                self._page_store[i]
-            d["kv_page_scale_k" if k == "kv_k"
-              else "kv_page_scale_v"] = self._scale_store[i]
+            d[_page_key(k)] = self._page_store[i]
+            d[_scale_key(k)] = self._scale_store[i]
             d["kv_page_table"] = row_dev
             d["kv_page_prime"] = marker
             d["kv_pos"] = pos
@@ -2056,10 +2268,8 @@ class GenerationEngine:
         store, scales = [], []
         for n, k in self._paged_keys:
             d = out[n]
-            store.append(d.pop("kv_page_k" if k == "kv_k"
-                               else "kv_page_v"))
-            scales.append(d.pop("kv_page_scale_k" if k == "kv_k"
-                                else "kv_page_scale_v"))
+            store.append(d.pop(_page_key(k)))
+            scales.append(d.pop(_scale_key(k)))
             d.pop("kv_page_table", None)
             d.pop("kv_page_prime", None)
         self._page_store = store
@@ -2073,7 +2283,8 @@ class GenerationEngine:
         row = np.zeros((1, self._n_max), np.int32)
         row[0, :len(table)] = table
         dense = [primed_state[n][k] for n, k in self._paged_keys]
-        self._page_store = scatter_pages(self._page_store, dense, row)
+        self._page_store = scatter_pages(self._page_store, dense, row,
+                                         axes=self._paged_axes)
         self._kv_traffic(self._L * self._tok_bytes)   # one-row commit
 
     def _dispatch_step(self):
@@ -2105,6 +2316,9 @@ class GenerationEngine:
         self._greedy_rows += live - drawn
         self._drawn_rows += drawn
         self._block_fetches += drawn > 0
+        if self._sparse_names:
+            seated = [s for s, r in enumerate(self._slots) if r is not None]
+            self._count_sparse(self._row_pos[seated] + 1)
         for s, req in enumerate(self._slots):
             if req is not None:
                 self._row_pos[s] += 1
@@ -2152,6 +2366,7 @@ class GenerationEngine:
             self._extract_paged_state()
         elif table is not None:
             self._paged_scatter(table)
+        self.net.state = self._take_stats(self.net.state)
         dt = time.perf_counter() - t0
         self._dispatch_s_total += dt
         self._dispatch_hist.observe(dt)
@@ -2220,10 +2435,9 @@ class GenerationEngine:
         for i, ((n, k), pool) in enumerate(zip(self._paged_keys,
                                                self._page_store)):
             d = dict(st[n])
-            d["kv_page_k" if k == "kv_k" else "kv_page_v"] = pool
+            d[_page_key(k)] = pool
             if self._scale_store is not None:
-                d["kv_page_scale_k" if k == "kv_k"
-                  else "kv_page_scale_v"] = self._scale_store[i]
+                d[_scale_key(k)] = self._scale_store[i]
             d["kv_page_table"] = tables[n]
             st[n] = d
         if self._kv_pos_dirty:
@@ -2252,24 +2466,16 @@ class GenerationEngine:
         are consumed, so the returned references are the only live
         copies."""
         st = dict(self.net.state)
-        store = [st[n]["kv_page_k" if k == "kv_k" else "kv_page_v"]
-                 for n, k in self._paged_keys]
+        names = list(dict.fromkeys(n for n, _ in self._paged_keys))
+        for n in names:
+            st[n] = dict(st[n])
+        store = [st[n].pop(_page_key(k)) for n, k in self._paged_keys]
         if self._scale_store is not None:
             # under donation the returned scale leaves are likewise the
             # only live copies (base-token appends rewrite scale rows)
-            self._scale_store = [
-                st[n]["kv_page_scale_k" if k == "kv_k"
-                      else "kv_page_scale_v"]
-                for n, k in self._paged_keys]
-        tables = {}
-        for n in dict.fromkeys(n for n, _ in self._paged_keys):
-            d = dict(st[n])
-            tables[n] = d.pop("kv_page_table")
-            d.pop("kv_page_k", None)
-            d.pop("kv_page_v", None)
-            d.pop("kv_page_scale_k", None)
-            d.pop("kv_page_scale_v", None)
-            st[n] = d
+            self._scale_store = [st[n].pop(_scale_key(k))
+                                 for n, k in self._paged_keys]
+        tables = {n: st[n].pop("kv_page_table") for n in names}
         self._page_store = store
         if self._state_donated and self._donate:
             # donation consumed the installed buffers: the returned
@@ -2291,8 +2497,10 @@ class GenerationEngine:
           [S, L] view and the scatter writes it all back — 2·S·L
           positions regardless of live context.
         - direct-xla: the folded gather still materializes the mapped
-          [S, L] view once inside the dispatch (S·L reads), but the
-          write is the one-token append (S·width).
+          [S, L] view once inside the dispatch (S·L reads; of a leaf
+          whose layer gathers a selection, ``paged_read_tokens()``
+          positions a row), but the write is the one-token append
+          (S·width).
         - direct-pallas: only LIVE pages are read (the table-indexed
           block specs skip dead blocks to the null page) — sum of each
           active row's page-rounded context — plus the append.
@@ -2315,7 +2523,7 @@ class GenerationEngine:
                 for s, r in enumerate(self._slots) if r is not None)
             return (live * self._tok_bytes + append
                     + (live // ps) * self._scale_row_bytes)
-        return (S * L * self._tok_bytes + append
+        return (S * self._xla_read_bytes + append
                 + S * self._n_max * self._scale_row_bytes)
 
     def _paged_gather(self):
@@ -2324,7 +2532,8 @@ class GenerationEngine:
         returns the (cached) device page table it was gathered through
         (the scatter must use the same snapshot)."""
         table = self._table_dev()
-        dense = gather_pages(self._page_store, table, length=self._L)
+        dense = gather_pages(self._page_store, table, length=self._L,
+                             axes=self._paged_axes)
         st = dict(self.net.state)
         for (n, k), leaf in zip(self._paged_keys, dense):
             d = dict(st[n])
@@ -2339,7 +2548,8 @@ class GenerationEngine:
         run before any retirement triggered by the dispatch's outputs —
         freed pages may be re-allocated at the next admission."""
         dense = [self.net.state[n][k] for n, k in self._paged_keys]
-        self._page_store = scatter_pages(self._page_store, dense, table)
+        self._page_store = scatter_pages(self._page_store, dense, table,
+                                         axes=self._paged_axes)
 
     def _retire(self, slot: int, reason: str,
                 exc: Optional[BaseException] = None) -> None:
@@ -2381,6 +2591,7 @@ class GenerationEngine:
         half of the round-trip elimination); the per-dispatch paged
         view rides in via _install_paged_state instead."""
         S = self.slots
+        paged = set(self._paged_keys or ())
         arena = {}
         for name, s in primed_state.items():
             if not isinstance(s, dict):
@@ -2398,7 +2609,7 @@ class GenerationEngine:
             for k, v in s.items():
                 if k not in _SCATTER_KEYS:
                     continue
-                if self._direct and k in ("kv_k", "kv_v"):
+                if self._direct and (name, k) in paged:
                     continue        # the page pool IS the KV storage
                 # admission-time arena construction (slot lifecycle),
                 # not the per-token decode steady state
@@ -2417,12 +2628,12 @@ class GenerationEngine:
         if self._merge_keys is None:
             # paged leaves join through the page scatter, not the dense
             # arena (their dense view is rebuilt from the pool per step)
-            excl = {"kv_k", "kv_v"} if self._pool is not None else set()
+            excl = set(self._paged_keys or ())
             self._merge_keys = [
                 (n, k) for n in sorted(primed_state)
                 if isinstance(primed_state[n], dict)
                 for k in sorted(primed_state[n])
-                if k in _SCATTER_KEYS and k not in excl]
+                if k in _SCATTER_KEYS and (n, k) not in excl]
         arena_leaves = [arena_state[n][k] for n, k in self._merge_keys]
         primed_leaves = [primed_state[n][k] for n, k in self._merge_keys]
         new_leaves = _scatter_rows(arena_leaves, primed_leaves,

@@ -76,6 +76,38 @@ SERVING_HOST_IO_BYTES = "dl4jtpu_serving_host_io_bytes_total"
 SERVING_SAMPLE_ROWS = "dl4jtpu_serving_sample_rows_total"
 SERVING_BLOCK_FETCHES = "dl4jtpu_serving_block_fetches_total"
 
+#: ``engine.health()`` keys that are no registry series (read where the
+#: payload is read; a net without such layers has neither key):
+#:
+#: ``experts`` — a net with ``RoutedExpertsLayer``s: the layers'
+#: ``moe_stats`` summed over layers and over everything served so far.
+#: ``tokens`` routed (a decode dispatch routes all S rows, idle slots
+#: too; a prime's left pads route nowhere), ``held_pairs`` (token, held
+#: expert) pairs among them (tokens x top_k x held / router_experts on
+#: average), ``rows_computed`` by the grouped product (whole tiles: 1 -
+#: held_pairs / rows_computed is its padding), ``max_expert_load`` the
+#: most pairs one expert took in one dispatch. Counted inside the device
+#: programs and joined on the device behind every dispatch, outside the
+#: donated state; ``health()`` fetches the sum (from any thread, with no
+#: step lock: complete up to the last dispatch that finished) and the
+#: cycle never does.
+#:
+#: ``sparse_attn`` — a net with sparse-selection attention
+#: (``LatentAttentionLayer``), summed over those layers. Host counts from
+#: each dispatch's rows: ``query_positions`` real queries (prompt tokens
+#: fed, live decode rows), ``context_positions`` the positions they could
+#: see (their own included), ``selected_positions`` what the selection
+#: keeps of those (min(index_topk, context) a query). Counted by the
+#: layers inside the device programs (``attn_stats``, joined and fetched
+#: like the experts' counters): ``attended_positions``, the cache
+#: positions whose attention scores the programs computed, by the form
+#: each dispatch took: in the per-head prime of a fresh stream the
+#: chunk's own slots in causal groups (5/8 of width x width for a bucket
+#: of four groups; pads too), in a later chunk (after a prefix hit, or
+#: chunked priming) every cache slot of every row of its query blocks,
+#: in paged decode the gathered index_topk of all S rows. 1 - selected /
+#: attended is attention work the selection had already ruled out.
+
 #: fleet layer (serving/fleet/router.py registers these): multi-replica
 #: routing, prefix-affinity placement, ledger migration, autoscaling.
 #: ``fleet`` labels distinguish routers; ``replica`` / ``cause`` /

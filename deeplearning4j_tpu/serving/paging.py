@@ -4,8 +4,10 @@ PR 5's arena sized every slot for the worst-case sequence, so admitted
 concurrency was capped at S and a short request stranded the HBM of the
 positions it never used. Here the authoritative KV storage is a **page
 pool**: per attention leaf, a ``[P, Hkv, page_size, D]`` array of
-fixed-size token pages, plus one per-slot **page table** mapping the
-slot's token blocks to pool pages. Capacity becomes a *token* budget
+fixed-size token pages (or whatever the layer declares it keeps per
+token — ``nn.conf.layers.PagedLeaf``: a latent-attention layer's three
+``[P, page_size, W]`` leaves), plus one per-slot **page table** mapping the
+slot's token blocks to pool pages, shared by all of a layer's leaves. Capacity becomes a *token* budget
 (the µ-cuDNN memory-budget decomposition applied to serving state):
 
 - admission checks ``prompt_len + max_new_tokens`` against **free
@@ -48,8 +50,8 @@ from typing import List, Optional
 import jax
 import jax.numpy as jnp
 
-__all__ = ["PagePool", "PageExhausted", "PagedKVConfig", "gather_pages",
-           "pages_needed", "scatter_pages", "set_page"]
+__all__ = ["PagePool", "PageExhausted", "PagedKVConfig", "allocate_pools",
+           "gather_pages", "pages_needed", "scatter_pages", "set_page"]
 
 
 class PageExhausted(RuntimeError):
@@ -66,7 +68,8 @@ class PagedKVConfig:
     ``total_tokens`` or ``total_bytes`` (whichever is given —
     ``total_tokens`` rounds down to whole pages; ``total_bytes`` is a
     BYTE budget the engine divides by the per-page cost of the net's kv
-    leaves incl. any int8 scale sidecar, so the same budget admits ~2x
+    leaves (each layer's declared ``paged_leaves()``: whatever it keeps per
+    token) incl. any int8 scale sidecar, so the same budget admits ~2x
     the pages under ``kv_dtype="int8"``), defaulting to the old slot
     arena's worst case (slots × ceil(L / page_size)) so switching
     paging on never shrinks capacity. ``prefix_cache`` enables
@@ -248,19 +251,36 @@ class PagePool:
 # the jitted pool <-> dense-view round trip
 # ---------------------------------------------------------------------------
 
-@partial(jax.jit, static_argnames=("length",))
-def gather_pages(pools, table, *, length: int):
+def allocate_pools(total_pages: int, page_size: int, leaves, dtypes):
+    """One zeroed device page array per declared leaf
+    (``nn.conf.layers.PagedLeaf``): ``[P, *token_shape with page_size at
+    the leaf's token axis]`` in the leaf's dtype — ``[P, Hkv, ps, D]`` for
+    an attention layer's keys and values, ``[P, ps, W]`` for a leaf that
+    is one vector a token."""
+    return [jnp.zeros(leaf.shape(total_pages, page_size), dt)
+            for leaf, dt in zip(leaves, dtypes)]
+
+
+def _token_axes(pools, axes):
+    """The pool axis that counts a page's tokens, per leaf: 2 (the
+    ``[P, Hkv, ps, D]`` layout) where the caller names none."""
+    return (2,) * len(pools) if axes is None else tuple(axes)
+
+
+@partial(jax.jit, static_argnames=("length", "axes"))
+def gather_pages(pools, table, *, length: int, axes=None):
     """Materialize the dense per-slot view from the pool: for each leaf
-    ``[P, Hkv, ps, D]``, gather ``table`` ([S, n_max] page ids, 0 =
-    null) into ``[S, Hkv, n_max*ps, D]`` and slice to the layer cache
+    ``[P, ..., ps, ...]`` (tokens on pool axis ``axes[i]``; default 2:
+    ``[P, Hkv, ps, D]``), gather ``table`` ([S, n_max] page ids, 0 =
+    null) into ``[S, ..., n_max*ps, ...]`` and slice to the layer cache
     length. Unmapped blocks read the null page — garbage the kv_pos
     validity masks keep invisible."""
     out = []
-    for pool in pools:
-        _, h, _, d = pool.shape
-        g = pool[table]                      # [S, n, Hkv, ps, D]
-        g = jnp.moveaxis(g, 2, 1)            # [S, Hkv, n, ps, D]
-        out.append(g.reshape(g.shape[0], h, -1, d)[:, :, :length, :])
+    for pool, ax in zip(pools, _token_axes(pools, axes)):
+        g = pool[table]                      # [S, n, *leaf]
+        g = jnp.moveaxis(g, 1, ax)           # n next to (before) ps
+        g = g.reshape(g.shape[:ax] + (-1,) + g.shape[ax + 2:])
+        out.append(jax.lax.slice_in_dim(g, 0, length, axis=ax))
     return out
 
 
@@ -275,8 +295,8 @@ def set_page(pool, idx, leaf):
     return pool.at[idx].set(leaf.astype(pool.dtype))
 
 
-@partial(jax.jit, donate_argnums=(0,))
-def scatter_pages(pools, dense, table):
+@partial(jax.jit, donate_argnums=(0,), static_argnames=("axes",))
+def scatter_pages(pools, dense, table, axes=None):
     """Commit the updated dense views back to their mapped pages
     (donated: the pool buffer is updated in place). Only pages in
     `table` are written; free pages and unmapped cache entries keep
@@ -285,12 +305,13 @@ def scatter_pages(pools, dense, table):
     page and decode never rewrites old positions — so write order is
     immaterial. Blocks past a slot's allocation write the null page."""
     out = []
-    for pool, d in zip(pools, dense):
-        _, h, ps, dd = pool.shape
-        s, n = table.shape
-        pad = n * ps - d.shape[2]
-        dp = jnp.pad(d, ((0, 0), (0, 0), (0, pad), (0, 0)))
-        dp = dp.reshape(s, h, n, ps, dd)
-        dp = jnp.moveaxis(dp, 2, 1)          # [S, n, Hkv, ps, D]
+    s, n = table.shape
+    for pool, d, ax in zip(pools, dense, _token_axes(pools, axes)):
+        ps = pool.shape[ax]
+        pad = [(0, 0)] * d.ndim
+        pad[ax] = (0, n * ps - d.shape[ax])
+        dp = jnp.pad(d, pad)
+        dp = dp.reshape(d.shape[:ax] + (n, ps) + d.shape[ax + 1:])
+        dp = jnp.moveaxis(dp, ax, 1)         # [S, n, *leaf]
         out.append(pool.at[table].set(dp.astype(pool.dtype)))
     return out

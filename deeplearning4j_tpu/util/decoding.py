@@ -40,6 +40,33 @@ def _one_hot(rows: np.ndarray, vocab: int) -> np.ndarray:
     return x
 
 
+def takes_ids(net) -> bool:
+    """Whether `net` is fed token ids ``[B, T]`` (a layer of it says
+    ``takes_ids``: ``SequenceEmbeddingLayer``) and no one-hot
+    ``[B, V, T]``. Asked of the net, once; no caller passes an option."""
+    known = getattr(net, "_takes_ids", None)
+    if known is None:
+        known = net._takes_ids = any(
+            getattr(l, "takes_ids", False) for l in _stream_layers(net))
+    return known
+
+
+def _encode(net, rows, vocab: int) -> np.ndarray:
+    """`rows` [B, T] of token ids as `net` wants them: int32 ids for a
+    net that ``takes_ids`` (4 bytes a token up), else the float32 one-hot
+    ``[B, V, T]``."""
+    if takes_ids(net):
+        return np.asarray(rows, np.int32)
+    return _one_hot(rows, vocab)
+
+
+def _last(p):
+    """The last position's ``[B, V]`` of a head's output: what a head
+    that answers for the last position only (``LastStepOutputLayer``)
+    returns as it is, and the last column of a full ``[B, V, T]``."""
+    return p if p.ndim == 2 else p[:, :, -1]
+
+
 class RoundTrip:
     """What a caller may watch of one host round trip through
     ``rnn_time_step``: its three steps — ``"input"`` built on the host,
@@ -354,7 +381,7 @@ def _prime(net, ids, vocab: int, chunk_max: int = None,
     at, out = 0, None
     for c in _prime_chunks(len(ids), chunk_max):
         io.step("input")
-        x = _one_hot(np.asarray(ids[at:at + c])[None, :], vocab)
+        x = _encode(net, np.asarray(ids[at:at + c])[None, :], vocab)
         out = _forward(net, x, io)
         at += c
     return out
@@ -421,8 +448,9 @@ def _prime_padded(net, ids, vocab: int, chunk_max: int = None,
             return _prime(net, ids, vocab, chunk_max, io)
         P = cap                # pad exactly to capacity: still one shape
     pad = P - L
-    x = _one_hot(np.asarray([0] * pad + list(ids))[None, :], vocab)
-    x[:, :, :pad] = 0.0       # pads carry no token (masked anyway)
+    x = _encode(net, np.asarray([0] * pad + list(ids))[None, :], vocab)
+    if x.ndim == 3:
+        x[:, :, :pad] = 0.0   # pads carry no token (masked anyway)
     return _forward(net, x, io, pad_left=pad)
 
 
@@ -443,7 +471,7 @@ def prime_prompt(net, ids, vocab_size: int, padded: bool = False,
     out = (_prime_padded(net, ids, vocab_size, chunk_max, io) if padded
            else _prime(net, ids, vocab_size, chunk_max, io))
     io.step("fetch")
-    return _probs(out, io)[0, :, -1]
+    return _last(_probs(out, io))[0]
 
 
 def step_tokens(net, tokens, vocab_size: int,
@@ -466,13 +494,13 @@ def step_tokens(net, tokens, vocab_size: int,
     around the dispatch, fetch from the result coming back — left for the
     caller to end. A cycle passes it to ONE such call, on its own
     thread."""
-    return _decode(net, np.asarray(tokens, np.int64)[:, None], vocab_size,
-                   donate_state, io)[:, :, -1]
+    return _last(_decode(net, np.asarray(tokens, np.int64)[:, None],
+                         vocab_size, donate_state, io))
 
 
 def _dispatch(net, rows, vocab_size: int, donate_state: bool, io):
     io.step("input")
-    x = _one_hot(rows, vocab_size)
+    x = _encode(net, rows, vocab_size)
     return _forward(net, x, io, donate_state=donate_state)
 
 
@@ -485,11 +513,12 @@ def _decode(net, rows, vocab_size: int, donate_state: bool, io):
 @jax.jit
 def greedy_ids(out):
     """The greedy rule on the device: per row of the head's ``[B, V, T]``
-    output, the lowest-index maximum at the last position, as int32 —
+    output (or the ``[B, V]`` of a head that gives the last position
+    only), the lowest-index maximum at the last position, as int32 —
     the index ``np.argmax`` gives on the same values once fetched. Its
     own program (``jit_greedy_ids``), queued behind the forward; the
     streaming forward stays the one program named ``fwd``."""
-    return jnp.argmax(out[:, :, -1], axis=-1).astype(jnp.int32)
+    return jnp.argmax(_last(out), axis=-1).astype(jnp.int32)
 
 
 def step_greedy(net, tokens, vocab_size: int,
@@ -511,7 +540,7 @@ def step_greedy(net, tokens, vocab_size: int,
     io.step("fetch")
     ids = np.asarray(ids)
     io.d2h(ids)
-    return ids, (_probs(out, io)[:, :, -1] if block else None)
+    return ids, (_last(_probs(out, io)) if block else None)
 
 
 def verify_tokens(net, chunks, vocab_size: int,

@@ -18,4 +18,5 @@ from deeplearning4j_tpu.zoo.googlenet import GoogLeNet  # noqa: F401
 from deeplearning4j_tpu.zoo.inception_resnet import InceptionResNetV1, FaceNetNN4Small2  # noqa: F401
 from deeplearning4j_tpu.zoo.text_lstm import TextGenerationLSTM
 from deeplearning4j_tpu.zoo.transformer import TextGenerationTransformer  # noqa: F401
+from deeplearning4j_tpu.zoo.sparse_latent_moe import SparseLatentMoETransformer  # noqa: F401
 from deeplearning4j_tpu.zoo.imagenet import ImageNetLabels  # noqa: F401
