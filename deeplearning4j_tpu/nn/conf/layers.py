@@ -1335,8 +1335,9 @@ class SelfAttentionLayer(FeedForwardLayerConf):
           positions hold the exact bytes the dense cache would; masked
           positions are finite garbage ``-1e30`` hides, the dense
           path's own idle-slot argument).
-        - ``"pallas"`` impl: serving/paged_kernel.py — the table is a
-          scalar-prefetched index map, so only live pages are read
+        - ``"pallas"`` impl: serving/paged_kernel.py — the kernel walks
+          the scalar-prefetched table and copies the mapped pages out
+          of the pool itself, so only live pages are read
           (O(active context), the true paged-attention read path);
           width T = 1 + gamma runs the same kernel for the widened
           speculative verify dispatch.

@@ -188,7 +188,7 @@ from deeplearning4j_tpu.serving.overload import (
     BROWNOUT_NO_PREFIX_INSERTS, BROWNOUT_NO_SPECULATION,
     BROWNOUT_REDUCED_GAMMA, OverloadConfig, OverloadController)
 from deeplearning4j_tpu.serving.paged_kernel import (
-    paged_attention_supported)
+    pages_per_step, paged_attention_supported)
 from deeplearning4j_tpu.serving.paging import (
     PagedKVConfig, PagePool, allocate_pools, gather_pages, pages_needed,
     scatter_pages, set_page)
@@ -944,6 +944,9 @@ class GenerationEngine:
                 # actually run, not the construction-time resolution
                 "decode_path": (f"direct-{self._live_impl()}"
                                 if self._direct else "roundtrip"),
+                # table entries one grid step of the kernel's walk
+                # copies and scores (0 off the kernel path)
+                "kernel_pages_per_step": self._kernel_pages_per_step(),
                 "kv_dtype": self._kv_dtype,
                 "bytes_moved_total": self._kv_bytes_total,
                 "dispatches": self._dispatches,
@@ -2388,6 +2391,19 @@ class GenerationEngine:
         resolved at construction."""
         return paged_decode_impl()[0] if self._direct else None
 
+    def _kernel_pages_per_step(self) -> int:
+        """The G the paged-attention kernel resolves for this engine's
+        pool (``paged_kernel.pages_per_step``); 0 while dispatches run
+        off the kernel path."""
+        if self._live_impl() != "pallas":
+            return 0
+        _, hkv, d = self._paged_layer_dims()[0]
+        native = getattr(self.net.conf, "dtype", None) or "float32"
+        return pages_per_step(
+            (self._pool.total_pages, hkv, self._ps, d), self._n_max,
+            1 if self._kv_dtype == "int8"
+            else jnp.dtype(native).itemsize)
+
     def _invalidate_tables(self) -> None:
         """Drop the cached [S, n_max] table snapshots — call after ANY
         page-table mutation (admit / retire / rebuild). Between
@@ -2501,9 +2517,10 @@ class GenerationEngine:
           whose layer gathers a selection, ``paged_read_tokens()``
           positions a row), but the write is the one-token append
           (S·width).
-        - direct-pallas: only LIVE pages are read (the table-indexed
-          block specs skip dead blocks to the null page) — sum of each
-          active row's page-rounded context — plus the append.
+        - direct-pallas: only LIVE pages are read (the kernel copies
+          the pages that hold a row's keys and no others, however many
+          table entries a grid step covers) — sum of each active row's
+          page-rounded context — plus the append.
 
         int8 adds the scale-sidecar reads (one f32 row per page per
         leaf): the xla gather folds the whole ``scales[table]`` view

@@ -11,18 +11,30 @@ context was actually live. Here the page table IS the access path
 (cuDNN's fused-primitive lesson, PAPERS.md: fold the memory movement
 into the consuming op):
 
-- grid ``(slot, kv-head, page-block)`` with the per-slot page table and
-  per-row lengths prefetched as SCALAR refs
-  (``pltpu.PrefetchScalarGridSpec``): the K/V block specs index the pool
-  *through the table* (``table[s, b]``), so each grid step DMAs exactly
-  one mapped page into VMEM — the pool is never materialized densely.
-- online-softmax accumulators (m, l, acc) live in VMEM scratch across
-  the page-block axis: one HBM read per live page, one HBM write per
-  output block (the flash-attention schedule applied to paged decode).
-- blocks at or past a row's length are skipped (``pl.when``) — dead
-  table entries point at the reserved null page 0, so even their
-  prefetch touches only the one always-resident page. Cost is
-  O(active context), not O(token budget).
+- grid ``(slot, page-group)`` with the per-slot page table and per-row
+  lengths prefetched as SCALAR refs (``pltpu.PrefetchScalarGridSpec``).
+  The pools stay in HBM (``pl.ANY``): one grid step serves a group of G
+  consecutive table entries of a row and copies each mapped page
+  ``pool[table[s, b*G + g]]`` into VMEM itself
+  (``pltpu.make_async_copy``; a page's ``[Hkv, page_size, D]`` is
+  contiguous, so one copy brings every kv head) — the pool is never
+  materialized densely. A one-page-a-step grid (the walk before PR 30)
+  paid ~0.4 µs of step overhead for 8 KB; G pages a step pay it once.
+- the copies of the NEXT live group (the row's next, or the next row's
+  first) are started before the current one is scored: two slots of
+  page buffers and DMA semaphores, the slot carried in SMEM, so a
+  row's walk is bound by the copies and not by their latency. The
+  chain needs the grid in order: both axes are sequential.
+- a step scores ``[reps × W, G × page_size]`` per kv head — 512 keys
+  wide, not one page — and folds it into online-softmax accumulators
+  (m, l, acc) that live in VMEM scratch across the group axis: one
+  HBM read per live page, one HBM write per output block.
+- G comes from the static shapes alone (``pages_per_step``): 512 keys a
+  step, fewer under a VMEM budget, never more than the table is wide.
+- groups wholly past a row's length start no copy and do no arithmetic;
+  inside a partly live group only the pages that hold keys are copied,
+  and the buffer rows they leave stale are masked out of the scores and
+  selected out of V. Cost is O(active context), not O(token budget).
 - the query axis is ``reps × W`` rows per kv head (GQA grouping ×
   query width), with W static: W = 1 is the plain decode step and
   W = 1 + γ is the widened speculative verify dispatch ``[S, V, 1+γ]``
@@ -60,20 +72,94 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.extend.core import jaxpr_as_fun
 
 NEG_INF = -1e30   # finite: exp(NEG_INF - NEG_INF) inside a fully-masked
 #                   row must not produce NaN (explicit re-zeroing below)
 
-__all__ = ["paged_attention", "paged_attention_supported",
-           "paged_ref_attention"]
+__all__ = ["pages_per_step", "paged_attention",
+           "paged_attention_supported", "paged_ref_attention"]
 
 
-def _decode_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-                   acc_scr, m_scr, l_scr, *, ps, qw, nb, scale):
-    """One (slot, kv-head, page-block) grid step: score the row's
-    grouped queries against ONE mapped page, fold into the online
-    softmax, emit at the last block."""
-    s, b = pl.program_id(0), pl.program_id(2)
+#: keys one grid step scores per kv head: the lane width of the score
+#: block. 512 keys are four MXU tiles a product, and at page_size 16 the
+#: 32 page copies of a step are in flight together.
+_GROUP_KEYS = 512
+
+#: VMEM the page buffers may take: two slots of K and of V, each
+#: ``[G, Hkv, page_size, D]``. v5e scopes 16 MiB to a kernel by default;
+#: the query, output and accumulator blocks are small beside this.
+_GROUP_VMEM_BYTES = 4 * 1024 * 1024
+
+
+def pages_per_step(pool_shape: Tuple[int, ...], n_max: int,
+                   itemsize: int) -> int:
+    """G: how many consecutive table entries of a row one grid step
+    copies and scores, resolved from the static shapes alone —
+    ``_GROUP_KEYS`` keys a step, fewer where two slots of K and V pages
+    ``(Hkv, page_size, D)`` of ``itemsize`` bytes an element would pass
+    ``_GROUP_VMEM_BYTES``, never more than the table is wide."""
+    _, hkv, ps, d = pool_shape
+    page_bytes = hkv * ps * d * itemsize
+    g = min(_GROUP_KEYS // ps, _GROUP_VMEM_BYTES // (4 * page_bytes))
+    return max(1, min(g, n_max))
+
+
+def _decode_kernel(*refs, ps, qw, nb, G, scale, quant):
+    """One (slot, page-group) grid step: wait for the group's pages
+    (copied by the step before), start the copies of the next live
+    group, score the row's grouped queries of every kv head against the
+    group's ``G * ps`` keys, fold into the online softmax, emit at the
+    row's last step.
+
+    ``quant``: the pools are int8 and two more scalar-prefetch refs
+    (ks/vs: ``[P, Hkv]`` float32 in SMEM) hold the per-(page, head)
+    amax scales, indexed by the very page id the table routed the copy
+    through. Dequantization folds into the fp32 math: a page's K scale
+    multiplies its ``ps`` score columns alongside 1/sqrt(d), its V
+    scale its ``ps`` probability columns before the PV product —
+    per-page-constant scales commute with both dots, so this IS
+    dequant(int8) attention, not an approximation of it."""
+    if quant:
+        tbl_ref, len_ref, ks_ref, vs_ref = refs[:4]
+        refs = refs[4:]
+    else:
+        tbl_ref, len_ref = refs[:2]
+        refs = refs[2:]
+    (q_ref, k_hbm, v_hbm, o_ref,
+     kbuf, vbuf, sems, slot_scr, acc_scr, m_scr, l_scr) = refs
+    s, b = pl.program_id(0), pl.program_id(1)
+    n_rows, n_groups = pl.num_programs(0), pl.num_programs(1)
+    hkv, rw, d = acc_scr.shape
+    gk = G * ps
+    length = len_ref[s]
+
+    def live_pages(row_len, grp):
+        # table entries of group grp that hold keys of a row this long
+        return jnp.clip((row_len + ps - 1) // ps - grp * G, 0,
+                        jnp.minimum(G, nb - grp * G))
+
+    def live_groups(row_len):
+        # a row's group 0 is always walked (it carries the copy chain
+        # across rows), the others only while they hold keys
+        return jnp.clip((row_len + gk - 1) // gk, 1, n_groups)
+
+    def each_page_copy(row, grp, slot, act):
+        """``act`` on the K and the V copy of every live page of a
+        group (started by one step, waited for by the next)."""
+        def body(g, carry):
+            page = tbl_ref[row, grp * G + g]
+            # a page's [Hkv, ps, D] is contiguous in the pool: one copy
+            # brings every kv head
+            act(pltpu.make_async_copy(k_hbm.at[page], kbuf.at[slot, g],
+                                      sems.at[slot, 0]))
+            act(pltpu.make_async_copy(v_hbm.at[page], vbuf.at[slot, g],
+                                      sems.at[slot, 1]))
+            return carry
+        jax.lax.fori_loop(0, live_pages(len_ref[row], grp), body, 0)
+
+    def start_group(row, grp, slot):
+        each_page_copy(row, grp, slot, lambda copy: copy.start())
 
     @pl.when(b == 0)
     def _init():
@@ -81,103 +167,94 @@ def _decode_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    length = len_ref[s]
+    @pl.when(b < live_groups(length))
+    def _walk():
+        @pl.when((s == 0) & (b == 0))
+        def _first():
+            slot_scr[0] = 0
+            start_group(s, b, 0)
 
-    @pl.when(b * ps < length)
-    def _compute():
-        qb = q_ref[0, 0]                              # [reps*W, D]
-        sblk = jax.lax.dot_general(
-            qb, k_ref[0, 0], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale   # [reps*W, ps]
-        rw = qb.shape[0]
-        kpos = b * ps + jax.lax.broadcasted_iota(jnp.int32, (rw, ps), 1)
+        slot = slot_scr[0]
+        more = b + 1 < live_groups(length)
+        nxt_row = jnp.where(more, s, s + 1)
+        nxt_grp = jnp.where(more, b + 1, 0)
+
+        @pl.when(nxt_row < n_rows)
+        def _prefetch():
+            # the next live group's pages fly while this one is scored
+            start_group(nxt_row, nxt_grp, 1 - slot)
+
+        slot_scr[0] = 1 - slot
+        each_page_copy(s, b, slot, lambda copy: copy.wait())
+
+        kpos = b * gk + jax.lax.broadcasted_iota(jnp.int32, (rw, gk), 1)
         # query row r = rep * W + w sits at absolute position
         # length - W + w; causality within the appended chunk means
         # query w sees keys ≤ its own position (kpos < length follows:
         # the last query position IS length - 1)
-        w = jax.lax.broadcasted_iota(jnp.int32, (rw, ps), 0) % qw
+        w = jax.lax.broadcasted_iota(jnp.int32, (rw, gk), 0) % qw
         valid = kpos <= length - qw + w
-        sblk = jnp.where(valid, sblk, NEG_INF)
-        m_prev = m_scr[:][:, :1]
-        l_prev = l_scr[:][:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(sblk, axis=1, keepdims=True))
-        # explicit zeroing: a row whose whole block is masked would see
-        # exp(NEG_INF - NEG_INF) = 1 — keep those probabilities at 0
-        p = jnp.exp(sblk - m_new) * valid.astype(jnp.float32)
-        corr = jnp.exp(m_prev - m_new)
-        l_new = l_prev * corr + jnp.sum(p, axis=1, keepdims=True)
-        pv = jax.lax.dot_general(
-            p.astype(v_ref.dtype), v_ref[0, 0], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)           # [reps*W, D]
-        acc_scr[:] = acc_scr[:] * corr + pv
-        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+        if nb % G:
+            # the last group is wider than the table: a row coasting
+            # past its capacity must not see the columns beyond it
+            valid &= kpos < nb * ps
+        # pages past the row's length were never copied: their buffer
+        # rows are stale (0 × NaN in the PV product is NaN), so V is
+        # selected, not only the scores masked
+        vrow = b * gk + jax.lax.broadcasted_iota(jnp.int32, (gk, d), 0)
+        v_live = vrow < length
+        if quant:
+            col_page = jax.lax.broadcasted_iota(jnp.int32, (1, gk), 1) // ps
+            pages = [tbl_ref[s, jnp.minimum(b * G + g, nb - 1)]
+                     for g in range(G)]
 
-    @pl.when(b == nb - 1)
+            def page_scales(sc_ref, h):
+                # [1, G*ps]: column j carries the scale of page j // ps
+                row = jnp.zeros((1, gk), jnp.float32)
+                for g, page in enumerate(pages):
+                    row = jnp.where(col_page == g, sc_ref[page, h], row)
+                return row
+
+        for h in range(hkv):
+            qb = q_ref[0, h]                              # [reps*W, D]
+            kb = kbuf[slot, :, h].reshape(gk, d)
+            vb = vbuf[slot, :, h].reshape(gk, d)
+            if quant:
+                # int8 operands are EXPLICITLY widened before any
+                # arithmetic (the int8-promotion-in-dispatch lint
+                # contract): the dots run in fp32
+                qb = qb.astype(jnp.float32)
+                kb = kb.astype(jnp.float32)
+                vb = vb.astype(jnp.float32)
+            sblk = jax.lax.dot_general(
+                qb, kb, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale  # [rw, G*ps]
+            if quant:
+                sblk = sblk * page_scales(ks_ref, h)
+            sblk = jnp.where(valid, sblk, NEG_INF)
+            m_prev = m_scr[h][:, :1]
+            l_prev = l_scr[h][:, :1]
+            m_new = jnp.maximum(m_prev,
+                                jnp.max(sblk, axis=1, keepdims=True))
+            # explicit zeroing: a row whose whole group is masked would
+            # see exp(NEG_INF - NEG_INF) = 1 — keep those at 0
+            p = jnp.exp(sblk - m_new) * valid.astype(jnp.float32)
+            corr = jnp.exp(m_prev - m_new)
+            l_new = l_prev * corr + jnp.sum(p, axis=1, keepdims=True)
+            if quant:
+                p = p * page_scales(vs_ref, h)
+            pv = jax.lax.dot_general(
+                p.astype(vb.dtype), jnp.where(v_live, vb, 0),
+                (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)       # [reps*W, D]
+            acc_scr[h] = acc_scr[h] * corr + pv
+            m_scr[h] = jnp.broadcast_to(m_new, m_scr.shape[1:])
+            l_scr[h] = jnp.broadcast_to(l_new, l_scr.shape[1:])
+
+    @pl.when(b == n_groups - 1)
     def _finish():
-        o_ref[0, 0] = (acc_scr[:] / jnp.maximum(l_scr[:][:, :1], 1e-30)
-                       ).astype(o_ref.dtype)
-
-
-def _decode_kernel_quant(tbl_ref, len_ref, ks_ref, vs_ref, q_ref, k_ref,
-                         v_ref, o_ref, acc_scr, m_scr, l_scr, *, ps, qw,
-                         nb, scale):
-    """The int8-pool variant of _decode_kernel: K/V blocks arrive in
-    VMEM as int8 (the DMA moves half the bytes — the real win, not
-    just the model's), with the per-(page, head) amax scales riding
-    the scalar prefetch (ks/vs: [P, Hkv] float32 in SMEM, indexed by
-    the very page id the table prefetch routed this block through).
-    Dequantization folds into the existing fp32 math for free: the
-    K scale multiplies the score block alongside 1/sqrt(d), and the
-    V scale multiplies the block's pv contribution before it enters
-    the accumulator — per-page-constant scales commute with both
-    dots, so this IS dequant(int8) attention, not an approximation
-    of it."""
-    s, h, b = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-
-    @pl.when(b == 0)
-    def _init():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
-
-    length = len_ref[s]
-
-    @pl.when(b * ps < length)
-    def _compute():
-        page = tbl_ref[s, b]
-        sk = ks_ref[page, h]
-        sv = vs_ref[page, h]
-        qb = q_ref[0, 0].astype(jnp.float32)          # [reps*W, D]
-        # int8 operands are EXPLICITLY widened before any arithmetic
-        # (the int8-promotion-in-dispatch lint contract): the dot runs
-        # in fp32, the page's scale rides the existing score scaling
-        sblk = jax.lax.dot_general(
-            qb, k_ref[0, 0].astype(jnp.float32),
-            (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * (scale * sk)
-        rw = qb.shape[0]
-        kpos = b * ps + jax.lax.broadcasted_iota(jnp.int32, (rw, ps), 1)
-        w = jax.lax.broadcasted_iota(jnp.int32, (rw, ps), 0) % qw
-        valid = kpos <= length - qw + w
-        sblk = jnp.where(valid, sblk, NEG_INF)
-        m_prev = m_scr[:][:, :1]
-        l_prev = l_scr[:][:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(sblk, axis=1, keepdims=True))
-        p = jnp.exp(sblk - m_new) * valid.astype(jnp.float32)
-        corr = jnp.exp(m_prev - m_new)
-        l_new = l_prev * corr + jnp.sum(p, axis=1, keepdims=True)
-        pv = jax.lax.dot_general(
-            p, v_ref[0, 0].astype(jnp.float32), (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32) * sv      # [reps*W, D]
-        acc_scr[:] = acc_scr[:] * corr + pv
-        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
-
-    @pl.when(b == nb - 1)
-    def _finish():
-        o_ref[0, 0] = (acc_scr[:] / jnp.maximum(l_scr[:][:, :1], 1e-30)
-                       ).astype(o_ref.dtype)
+        o_ref[0] = (acc_scr[:] / jnp.maximum(l_scr[:][:, :, :1], 1e-30)
+                    ).astype(o_ref.dtype)
 
 
 def paged_attention(q, k_pool, v_pool, table, lengths, *, query_width: int,
@@ -199,9 +276,9 @@ def paged_attention(q, k_pool, v_pool, table, lengths, *, query_width: int,
       the appended chunk (engine: ``kv_pos + W``).
     - ``k_scales`` / ``v_scales``: ``[P, Hkv]`` float32 — the int8
       pool's per-(page, head) amax-scale sidecars (serving/quant.py).
-      Passing them selects the quantized kernel: pools must be int8,
-      blocks DMA at half the bytes, and dequantization happens in
-      VMEM with the scales riding the scalar-prefetch refs.
+      Passing them selects the quantized form of the same walk: pools
+      must be int8, pages copy at half the bytes, and dequantization
+      happens in VMEM with the scales riding the scalar-prefetch refs.
 
     Returns ``[S, Hkv, reps*W, D]`` in ``q.dtype`` (fp32 accumulation).
     Free/garbage rows produce finite garbage the engine discards — the
@@ -221,55 +298,72 @@ def paged_attention(q, k_pool, v_pool, table, lengths, *, query_width: int,
         raise ValueError(
             f"scale sidecars describe an int8 pool, got "
             f"{k_pool.dtype}")
-    scale = float(1.0 / np.sqrt(d))
+    pref = (jnp.asarray(table, jnp.int32), jnp.asarray(lengths, jnp.int32))
     if quant:
-        kernel = functools.partial(_decode_kernel_quant, ps=ps, qw=qw,
-                                   nb=nb, scale=scale)
-        n_pref = 4
-        pref = (jnp.asarray(table, jnp.int32),
-                jnp.asarray(lengths, jnp.int32),
-                jnp.asarray(k_scales, jnp.float32),
-                jnp.asarray(v_scales, jnp.float32))
-    else:
-        kernel = functools.partial(_decode_kernel, ps=ps, qw=qw, nb=nb,
-                                   scale=scale)
-        n_pref = 2
-        pref = (jnp.asarray(table, jnp.int32),
-                jnp.asarray(lengths, jnp.int32))
+        pref += (jnp.asarray(k_scales, jnp.float32),
+                 jnp.asarray(v_scales, jnp.float32))
+    call = _traced_call(q.shape, q.dtype.name, k_pool.shape,
+                        k_pool.dtype.name, nb, qw, quant, bool(interpret))
+    out, = jaxpr_as_fun(call)(*pref, q, k_pool, v_pool)
+    return out
 
-    def _q_map(s, h, b, tbl, *_):
-        return (s, h, 0, 0)
 
-    def _pool_map(s, h, b, tbl, *_):
-        # the page table IS the index map: block b of row s loads
-        # pool page table[s, b] — the paged read path, fused
-        return (tbl[s, b], h, 0, 0)
+@functools.lru_cache(maxsize=32)
+def _traced_call(q_shape, q_dtype, pool_shape, pool_dtype, nb, qw, quant,
+                 interpret):
+    """The ``pallas_call`` of one decode shape as a closed jaxpr, traced
+    ONCE a process: every attention layer of a decode program binds the
+    same equation, so the kernel is traced, and lowered to Mosaic
+    (jax caches a lowering by the equation's parameters), once a
+    program and not once a layer — with 30 layers the difference is
+    ~10 s of a serving process's set-up on the chip's host."""
+    S, hkv, rw, d = q_shape
+    pages, _, ps, _ = pool_shape
+    G = pages_per_step(pool_shape, nb, jnp.dtype(pool_dtype).itemsize)
+    kernel = functools.partial(
+        _decode_kernel, ps=ps, qw=qw, nb=nb, G=G,
+        scale=float(1.0 / np.sqrt(d)), quant=quant)
+
+    def _row_map(s, b, *_):
+        return (s, 0, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=n_pref,
-        grid=(S, hkv, nb),
+        num_scalar_prefetch=4 if quant else 2,
+        grid=(S, pl.cdiv(nb, G)),
         in_specs=[
-            pl.BlockSpec((1, 1, rw, d), _q_map),
-            pl.BlockSpec((1, 1, ps, d), _pool_map),
-            pl.BlockSpec((1, 1, ps, d), _pool_map),
+            pl.BlockSpec((1, hkv, rw, d), _row_map),
+            # the pools stay in HBM: the kernel copies the pages the
+            # table names itself — the paged read path, fused
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((1, 1, rw, d), _q_map),
-        scratch_shapes=[pltpu.VMEM((rw, d), jnp.float32),
-                        pltpu.VMEM((rw, 128), jnp.float32),
-                        pltpu.VMEM((rw, 128), jnp.float32)],
+        out_specs=pl.BlockSpec((1, hkv, rw, d), _row_map),
+        scratch_shapes=[pltpu.VMEM((2, G, hkv, ps, d), pool_dtype),
+                        pltpu.VMEM((2, G, hkv, ps, d), pool_dtype),
+                        pltpu.SemaphoreType.DMA((2, 2)),
+                        pltpu.SMEM((1,), jnp.int32),
+                        pltpu.VMEM((hkv, rw, d), jnp.float32),
+                        pltpu.VMEM((hkv, rw, 128), jnp.float32),
+                        pltpu.VMEM((hkv, rw, 128), jnp.float32)],
     )
-    return pl.pallas_call(
+    call = pl.pallas_call(
         kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S, hkv, rw, d), q.dtype),
-        interpret=interpret,
-    )(*pref, q, k_pool, v_pool)
+        out_shape=jax.ShapeDtypeStruct(q_shape, q_dtype),
+        interpret=interpret)
+    sds = jax.ShapeDtypeStruct
+    pref = (sds((S, nb), jnp.int32), sds((S,), jnp.int32))
+    if quant:
+        pref += (sds((pages, hkv), jnp.float32),) * 2
+    return jax.make_jaxpr(call)(*pref, sds(q_shape, q_dtype),
+                                sds(pool_shape, pool_dtype),
+                                sds(pool_shape, pool_dtype))
 
 
-#: SMEM the int8 kernel may spend on its two [P, Hkv] float32 scale
+#: SMEM the int8 form may spend on its two [P, Hkv] float32 scale
 #: sidecars. They ride the scalar prefetch, and a 2-D SMEM array pads its
 #: minor dim to 128 lanes, so each costs P * 512 bytes however few kv
 #: heads there are. v5e has 1 MiB of SMEM, shared with the page table:
-#: measured there (jax 0.9.0), a 257-page pool compiles and a 1025-page
+#: measured there (jax 0.9.0), a 761-page pool ran and a 1025-page
 #: pool is refused ("Used 1.01M of 1.00M smem").
 _SMEM_SCALE_BUDGET = 768 * 1024
 
@@ -279,29 +373,35 @@ def paged_attention_supported(pool_shape: Tuple[int, ...],
                               kv_dtype: str = "bf16") -> bool:
     """Shape gate for the REAL-CHIP kernel path (mirrors
     flash_attention_supported): what Mosaic compiles on a v5e under jax
-    0.9.0, established by compiling each side of every bound there
-    (PERF.md "PR 21"). ``pool_shape`` is the pool leaf's
-    ``(P, Hkv, page_size, D)``.
+    0.9.0, established by compiling and running each side of every
+    bound there against the dense-gather reference (PERF.md "PR 30").
+    ``pool_shape`` is the pool leaf's ``(P, Hkv, page_size, D)``.
 
-    - native-dtype pools (``kv_dtype="bf16"`` — float32 or bfloat16
-      storage): head dim lane-tileable, page rows a multiple of 8.
-      8-row bfloat16 page blocks compile although bf16 packs 16 rows
-      per tile.
-    - int8 pools: the (32, 128) int8 tile, and the scale sidecars must
-      fit SMEM (``_SMEM_SCALE_BUDGET``) — which bounds the POOL SIZE,
-      not only the block shape. A larger int8 pool decodes on the XLA
-      path.
+    One rule for every pool dtype, because the kernel copies whole
+    pages out of HBM itself and a copy's slice must sit on the pool's
+    tiling:
+
+    - head dim a multiple of the 128 lanes (128, 256 and 512 ran;
+      64 is refused: "Slice shape along dimension 3 must be aligned to
+      tiling (128)" — such a model decodes on the XLA path);
+    - page rows a multiple of 8 (8, 16, 24 ran in bfloat16, 8 in
+      float32, 8 to 64 in int8; 12 is refused in bfloat16. 4 compiles
+      too: the bound is sufficient, not tight);
+    - int8 pools besides: the scale sidecars must fit SMEM
+      (``_SMEM_SCALE_BUDGET``) — which bounds the POOL SIZE, not the
+      page. A larger int8 pool decodes on the XLA path.
 
     Interpret mode (CPU tests) has no such limits — this gate only
     decides the ``decode_impl="auto"`` resolution on a TPU backend."""
     if len(pool_shape) != 4:
         return False
     pages, hkv, ps, d = pool_shape
+    if d % 128 or ps % 8 or query_rows < 1:
+        return False
     if kv_dtype == "int8":
         lanes = -(-hkv // 128) * 128
-        return (d in (128, 256) and ps % 32 == 0 and query_rows >= 1
-                and 2 * pages * lanes * 4 <= _SMEM_SCALE_BUDGET)
-    return d in (64, 128, 256) and ps % 8 == 0 and query_rows >= 1
+        return 2 * pages * lanes * 4 <= _SMEM_SCALE_BUDGET
+    return True
 
 
 def paged_ref_attention(q, k_pool, v_pool, table, lengths, *,

@@ -21,7 +21,8 @@ from deeplearning4j_tpu.serving import (
 from deeplearning4j_tpu.serving.health import (
     SERVING_DISPATCH_LATENCY, SERVING_KV_BYTES_MOVED)
 from deeplearning4j_tpu.serving.paged_kernel import (
-    paged_attention, paged_attention_supported, paged_ref_attention)
+    pages_per_step, paged_attention, paged_attention_supported,
+    paged_ref_attention)
 from deeplearning4j_tpu.util.decoding import prompt_lookup_proposer
 from deeplearning4j_tpu.zoo import TextGenerationTransformer
 
@@ -147,10 +148,157 @@ class TestPagedKernel:
 
     def test_supported_gate(self):
         assert paged_attention_supported((100, 2, 16, 128), 1)
-        assert paged_attention_supported((100, 2, 8, 64), 4)
+        assert paged_attention_supported((100, 2, 8, 128), 4)
+        assert paged_attention_supported((100, 2, 24, 256), 1)
+        # a page copy's slice must sit on the pool's (8, 128) tiling
+        assert not paged_attention_supported((100, 2, 8, 64), 4)
         assert not paged_attention_supported((100, 2, 16, 48), 1)
         assert not paged_attention_supported((100, 2, 6, 128), 1)
+        assert not paged_attention_supported((100, 2, 12, 128), 1)
         assert not paged_attention_supported((100, 2, 16), 1)
+
+
+# ---------------------------------------------------------------------
+# the grouped walk: one grid step copies and scores G table entries of
+# a row, for every kv head (interpret mode, against the reference)
+# ---------------------------------------------------------------------
+def _grouped_case(S, nb, ps, lengths, *, hkv=2, reps=2, qw=1, d=8,
+                  dtype=jnp.float32, seed=0):
+    """A pool whose dead table entries route to the null page, as the
+    engine's tables do; the null page and every page no row maps are
+    NaN: no byte of them may reach arithmetic."""
+    rng = np.random.default_rng(seed)
+    P = S * nb + 1
+    q = jnp.asarray(rng.normal(size=(S, hkv, reps * qw, d)), dtype)
+    kp = rng.normal(size=(P, hkv, ps, d))
+    vp = rng.normal(size=(P, hkv, ps, d))
+    table = rng.permutation(np.arange(1, P)).reshape(S, nb)
+    lengths = np.asarray(lengths)
+    live = {0}
+    for s, ln in enumerate(lengths):
+        n_live = -(-int(ln) // ps)
+        table[s, n_live:] = 0
+        live.update(int(p) for p in table[s, :n_live])
+    live.discard(0)
+    dead = [p for p in range(P) if p not in live]
+    return (q, jnp.asarray(kp, dtype), jnp.asarray(vp, dtype),
+            jnp.asarray(table, jnp.int32),
+            jnp.asarray(lengths, jnp.int32), dead)
+
+
+def _assert_walk_matches(q, kp, vp, table, lengths, dead, qw, tol):
+    ref = paged_ref_attention(q, kp, vp, table, lengths, query_width=qw)
+    poison = jnp.asarray(dead, jnp.int32)
+    out = paged_attention(q, kp.at[poison].set(jnp.nan),
+                          vp.at[poison].set(jnp.nan), table, lengths,
+                          query_width=qw, interpret=True)
+    assert np.isfinite(np.asarray(out, np.float32)).all()
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(ref, np.float32),
+                               atol=tol, rtol=tol)
+
+
+class TestGroupedWalk:
+    @pytest.mark.parametrize("nb,ps,want", [
+        (256, 16, 32),     # the benchmark's table: 512 keys a step
+        (5, 4, 5),         # a table narrower than a group
+        (200, 4, 128),     # small pages: still 512 keys
+        (64, 32, 16),
+        (64, 1024, 1),     # a page wider than a group: one a step
+    ])
+    def test_pages_per_step_follows_the_shape(self, nb, ps, want):
+        assert pages_per_step((9, 2, ps, 128), nb, 2) == want
+
+    def test_pages_per_step_keeps_inside_vmem(self):
+        """Two slots of K and of V pages stay inside the budget: a wide
+        multi-head pool gets fewer pages a step, never none."""
+        g = pages_per_step((9, 32, 16, 256), 256, 4)
+        assert 1 <= g < 32
+        assert 4 * g * 32 * 16 * 256 * 4 <= 4 * 1024 * 1024
+        assert pages_per_step((9, 64, 64, 512), 256, 4) == 1
+
+    @pytest.mark.parametrize("nb", [3, 5, 130, 200],
+                             ids=lambda n: f"nb{n}")
+    def test_table_width_not_a_multiple_of_the_group(self, nb):
+        """n_max smaller than G (one short group), and n_max that
+        leaves a ragged last group (G = 128 at ps = 4): rows that end
+        in the first, a middle and the ragged group, and a full one."""
+        ps = 4
+        assert pages_per_step((1, 2, ps, 8), nb, 4) == min(nb, 128)
+        assert nb < 128 or nb % 128
+        full = nb * ps
+        case = _grouped_case(4, nb, ps, [1, full // 2 + 1, full - 1,
+                                         full])
+        _assert_walk_matches(*case, qw=1, tol=2e-5)
+
+    @pytest.mark.parametrize("qw", [1, 3])
+    @pytest.mark.parametrize("edge", [-1, 0, 1], ids=["under", "at",
+                                                      "over"])
+    def test_row_lengths_at_a_group_edge(self, edge, qw):
+        """Rows k·G·ps − 1, k·G·ps and k·G·ps + 1 keys long (k = 1, 2)
+        beside a row of one chunk: the last live group is full, one key
+        into the next, or one key short."""
+        ps = 4
+        G = pages_per_step((1, 2, ps, 8), 10 ** 6, 4)
+        nb = 2 * G + 2
+        assert pages_per_step((1, 2, ps, 8), nb, 4) == G
+        lengths = [G * ps + edge, 2 * G * ps + edge, qw]
+        case = _grouped_case(3, nb, ps, lengths, qw=qw)
+        _assert_walk_matches(*case, qw=qw, tol=2e-5)
+
+    def test_dead_pages_inside_a_partly_live_group_are_never_read(self):
+        """A group of 5 table entries of which 1 to 4 hold keys: the
+        others route to the NaN-poisoned null page and must reach
+        neither the scores nor the PV product (0 × NaN is NaN)."""
+        case = _grouped_case(4, 5, 4, [1, 6, 11, 16])
+        assert pages_per_step(case[1].shape, 5, 4) == 5
+        _assert_walk_matches(*case, qw=1, tol=2e-5)
+
+    def test_stale_buffer_rows_never_reach_the_pv_product(self):
+        """Keys past a row's length inside its last live page are
+        copied with the page; NaN there must stay out of the output
+        like the pages that were never copied (V is selected on the
+        key's position, the scores masked)."""
+        q, kp, vp, table, lengths, dead = _grouped_case(
+            3, 5, 4, [1, 6, 11])
+        ref = paged_ref_attention(q, kp, vp, table, lengths,
+                                  query_width=1)
+        kp, vp = np.array(kp), np.array(vp)
+        for s, ln in enumerate(np.asarray(lengths)):
+            page = int(table[s, (int(ln) - 1) // 4])
+            kp[page, :, int(ln) % 4:] = np.nan
+            vp[page, :, int(ln) % 4:] = np.nan
+        out = paged_attention(q, jnp.asarray(kp), jnp.asarray(vp),
+                              table, lengths, query_width=1,
+                              interpret=True)
+        np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
+
+    def test_two_rows_share_pages_inside_one_group(self):
+        """Prefix sharing: two rows map the same two physical pages in
+        their first group and read identical bytes through their own
+        tables; their tails differ."""
+        q, kp, vp, table, lengths, dead = _grouped_case(
+            2, 5, 4, [13, 18])
+        tbl = np.array(table)
+        dead = sorted(set(dead) | {int(p) for p in tbl[1, :2]})
+        tbl[1, :2] = tbl[0, :2]
+        _assert_walk_matches(q, kp, vp, jnp.asarray(tbl), lengths, dead,
+                             qw=1, tol=2e-5)
+
+    @pytest.mark.parametrize("qw", [1, 3])
+    def test_bf16_pool_at_the_benchmarks_shape(self, qw):
+        """Hkv 2, 12 query heads a kv head, 16-row pages, D 128, a
+        table 40 entries wide: G resolves to 32 as in the chat cell (a
+        full group and a ragged one), bfloat16 in and out."""
+        nb, ps = 40, 16
+        case = _grouped_case(3, nb, ps, [qw, 505, nb * ps], reps=12,
+                             qw=qw, d=128, dtype=jnp.bfloat16)
+        assert pages_per_step(case[1].shape, 256, 2) == 32
+        assert pages_per_step(case[1].shape, nb, 2) == 32
+        out = paged_attention(*case[:5], query_width=qw, interpret=True)
+        assert out.dtype == jnp.bfloat16
+        assert out.shape == case[0].shape
+        _assert_walk_matches(*case, qw=qw, tol=2e-2)
 
 
 # ---------------------------------------------------------------------
@@ -171,6 +319,18 @@ class TestDirectParity:
             assert got[i] == want, p
         assert eng.health()["kv_traffic"]["decode_path"] == \
             "direct-" + impl["decode_impl"]
+
+    @pytest.mark.parametrize("impl", DIRECT_IMPLS)
+    def test_health_reports_kernel_pages_per_step(self, rope_net, impl):
+        """The G the live engine's kernel resolved (8 table entries a
+        row here, all in one step); 0 off the kernel path."""
+        eng = GenerationEngine(
+            rope_net, V, slots=2,
+            paging=PagedKVConfig(page_size=4, direct=True, **impl))
+        assert pages_per_step((eng.page_pool.total_pages, 2, 4, 8),
+                              32 // 4, 4) == 8
+        assert eng.health()["kv_traffic"]["kernel_pages_per_step"] == \
+            (8 if impl["decode_impl"] == "pallas" else 0)
 
     @pytest.mark.parametrize("impl", DIRECT_IMPLS)
     def test_sampled_mixed_configs_match_one_shot(self, rope_model,
@@ -546,3 +706,4 @@ class TestConfig:
             rope_net, V, slots=2,
             paging=PagedKVConfig(page_size=4, direct=False))
         assert eng.health()["kv_traffic"]["decode_path"] == "roundtrip"
+        assert eng.health()["kv_traffic"]["kernel_pages_per_step"] == 0

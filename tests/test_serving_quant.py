@@ -207,14 +207,24 @@ class TestQuantReaders:
                             interpret=True, k_scales=ks, v_scales=vs)
 
     def test_supported_gate_tightens_for_int8(self):
+        """One shape rule for every pool dtype since the grouped walk
+        (the kernel copies whole pages itself); what tightens for int8
+        is the POOL SIZE: its scale sidecars ride SMEM."""
         assert paged_attention_supported((0, 0, 32, 128), 1,
                                          kv_dtype="int8")
-        assert not paged_attention_supported((0, 0, 8, 128), 1,
+        assert paged_attention_supported((0, 0, 8, 128), 1,
+                                         kv_dtype="int8")
+        assert not paged_attention_supported((0, 0, 12, 128), 1,
                                              kv_dtype="int8")
         assert not paged_attention_supported((0, 0, 32, 64), 1,
                                              kv_dtype="int8")
-        # the bf16 gate is unchanged
-        assert paged_attention_supported((0, 0, 8, 64), 1)
+        assert paged_attention_supported((768, 2, 32, 128), 1,
+                                         kv_dtype="int8")
+        assert not paged_attention_supported((1025, 2, 32, 128), 1,
+                                             kv_dtype="int8")
+        # a native-dtype pool of that size has no sidecars to fit
+        assert paged_attention_supported((1025, 2, 32, 128), 1)
+        assert not paged_attention_supported((0, 0, 8, 64), 1)
 
 
 # ---------------------------------------------------------------------
