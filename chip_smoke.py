@@ -449,7 +449,6 @@ def check_tokens_against_dense(net, outs, prompts, sz: Sizes) -> dict:
 
 
 def phase_serve(run: Run, net) -> None:
-    from deeplearning4j_tpu.nn.conf.layers import paged_decode_impl
     from deeplearning4j_tpu.serving import GenerationEngine, PagedKVConfig
 
     sz = run.sz
@@ -488,9 +487,10 @@ def phase_serve(run: Run, net) -> None:
 
     decode_path = health["kv_traffic"]["decode_path"]
     check(decode_path == "direct-pallas", f"decode_path {decode_path!r}")
-    check(paged_decode_impl() == ("pallas", not on_tpu),
-          f"live paged decode impl {paged_decode_impl()} — on a TPU the "
-          f"kernel must be Mosaic-compiled, not interpreted")
+    reads = set(net._paged_reads())
+    check(reads == {("pallas", not on_tpu)},
+          f"the net's attention layers read the pool by {reads} — on a "
+          f"TPU the kernel must be Mosaic-compiled, not interpreted")
     for out, p in zip(outs, prompts):
         check(len(out) == len(p) + sz.gen_steps and out[:len(p)] == p,
               f"request of {len(p)} tokens returned {len(out)} ids")
@@ -662,7 +662,7 @@ def kernel_probes():
     yield "fused.bn_act_conv1x1 fwd+bwd [128,56,56,64]->256 bf16", bn_conv
 
     def lstm():
-        t, n, h = 256, 256, 256      # bench_all's lstm_train shape
+        t, n, h = 256, 256, 256
         return pallas_lstm_recurrence(
             arr(t, n, 4 * h, scale=0.1), arr(h, 4 * h, scale=0.05),
             arr(n, h, scale=0.1), arr(n, h, scale=0.1))

@@ -223,7 +223,8 @@ class ProjectInfo:
     def mutable_globals(self, module_name: str) -> Set[str]:
         """Module-scope names that some function in the module rebinds
         via a ``global`` statement — the set_*-seam shape
-        (`set_paged_decode_impl` & friends). A global only ever bound at
+        (`set_stream_cache_sharding` rebinding
+        `_STREAM_CACHE_SHARDING`). A global only ever bound at
         import time is configuration, not mutable process state."""
         mod = self.modules.get(module_name)
         if mod is None:

@@ -5,16 +5,15 @@ The repo's exactness contract for process-wide knobs (ISSUE 13,
 generalizing PR 11's env-read special case): anything mutable at process
 scope that a step-builder or dispatch-construction body reads — an
 ``os.environ`` value, a module global flipped through a documented
-``set_*`` seam (``_STREAM_CACHE_SHARDING``, ``_PAGED_DECODE_IMPL``), or
-an accessor function over one (``paged_decode_impl()``) — MUST either
-enter the jit cache key (flipping it then retraces, the correct
-behavior) or be resolved to an explicit argument at the API boundary.
-Otherwise the value bakes into the compiled step at trace time and a
-later flip silently keeps the stale trace — or, when a caller keys its
-own cache on it, retraces on every flip. The PR 10 health-accounting bug
-was the construction-time variant: an engine snapshotted
-``paged_decode_impl()`` into ``self`` at __init__ while dispatches
-followed the LIVE process-wide setting.
+``set_*`` seam (``_STREAM_CACHE_SHARDING``), or an accessor function
+over one — MUST either enter the jit cache key (flipping it then
+retraces, the correct behavior) or be resolved to an explicit argument
+at the API boundary. Otherwise the value bakes into the compiled step at
+trace time and a later flip silently keeps the stale trace — or, when a
+caller keys its own cache on it, retraces on every flip. The
+construction-time variant: an object snapshots the global into ``self``
+at __init__ while what it dispatches follows the LIVE process-wide
+setting, and its own accounting of what ran is then wrong.
 
 Shapes:
 
@@ -318,8 +317,6 @@ class JitKeyDriftRule(Rule):
                         f"construction-time snapshot of process-wide "
                         f"{what} stored on self: dispatch-time behavior "
                         f"follows the LIVE setting, which a later "
-                        f"set_* call can flip (the PR 10 "
-                        f"paged_decode_impl() health-accounting bug) — "
-                        f"read the accessor at use time or key the jit "
-                        f"cache on it")
+                        f"set_* call can flip — read the accessor "
+                        f"at use time or key the jit cache on it")
                     break

@@ -8,12 +8,12 @@ One shared model for everything the stack observes:
                            <-  fit loops / MetricsListener (listener.py)
                            <-  ParallelWrapper TrainingStats phases
     registry  ->  GET /metrics on UIServer (Prometheus text exposition)
-              ->  JSONL sink / bench.py record snapshots (exporters.py)
+              ->  JSONL sink / record snapshots (exporters.py)
 
 `ensure_started()` is the one switch: idempotent, called by the fit loops
-and bench drivers, it installs the jit-recompile watcher and declares the
-default span series so a scrape taken before the first iteration already
-shows the full schema.
+and the benchmark's runners, it installs the jit-recompile watcher and
+declares the default span series so a scrape taken before the first
+iteration already shows the full schema.
 """
 
 from __future__ import annotations

@@ -3,8 +3,8 @@
 `render_prometheus()` produces text-exposition-format 0.0.4 (the format
 every Prometheus/VictoriaMetrics/Grafana-agent scraper speaks); the
 UIServer serves it at GET /metrics. `JsonlSink` appends one JSON object
-per call — the same shape bench.py embeds in its one-line records, so a
-long run can stream periodic snapshots next to its result line.
+per call, so a long run can stream periodic snapshots next to its result
+line.
 """
 
 from __future__ import annotations
@@ -16,7 +16,7 @@ import time
 from typing import Any, Dict, Optional
 
 from deeplearning4j_tpu.monitoring.metrics import (
-    Histogram, MetricsRegistry, compact_key, global_registry)
+    Histogram, MetricsRegistry, global_registry)
 
 CONTENT_TYPE = "text/plain; version=0.0.4; charset=utf-8"
 
@@ -141,46 +141,12 @@ def refresh_runtime_bounded(timeout: float = 5.0,
 
 
 def metrics_snapshot(refresh_timeout: float = 5.0) -> Dict[str, Any]:
-    """Compact global-registry snapshot for embedding in bench records.
+    """Compact global-registry snapshot for embedding in a run's record.
     Refreshes runtime gauges first (bounded, guarded: no backend init)
     and never raises — the snapshot must survive the tpu-unavailable
     paths."""
     try:
         refresh_runtime_bounded(refresh_timeout)
         return global_registry().snapshot_compact()
-    except Exception:  # noqa: BLE001 — a bench record beats a traceback
+    except Exception:  # noqa: BLE001 — a record beats a traceback
         return {}
-
-
-def snapshot_delta_compact(prev: Optional[Dict[str, Any]],
-                           cur: Dict[str, Any]) -> Dict[str, Any]:
-    """Compact rendering of ``cur`` minus ``prev`` (both full
-    ``MetricsRegistry.snapshot()`` dicts): counters and histograms become
-    the increment since ``prev`` (zero-increment series are dropped as
-    noise), gauges keep their point-in-time value. bench_all stamps one
-    of these per record so the Nth bench's "metrics" field carries only
-    that bench's own spans and compile counts, not the cumulative totals
-    of every bench the process ran before it."""
-    prev_samples: Dict[str, Dict[str, Any]] = {}
-    for name, m in (prev or {}).items():
-        for s in m["samples"]:
-            prev_samples[compact_key(name, s["labels"])] = s
-
-    out: Dict[str, Any] = {}
-    for name, m in cur.items():
-        for s in m["samples"]:
-            key = compact_key(name, s["labels"])
-            p = prev_samples.get(key)
-            if m["type"] == "histogram":
-                n = s["count"] - (p["count"] if p else 0)
-                if n > 0:
-                    total = s["sum"] - (p["sum"] if p else 0.0)
-                    out[key] = {"count": n, "sum": round(total, 6),
-                                "mean": round(total / n, 6)}
-            elif m["type"] == "counter":
-                d = s["value"] - (p["value"] if p else 0.0)
-                if d:
-                    out[key] = d
-            else:
-                out[key] = s["value"]
-    return out

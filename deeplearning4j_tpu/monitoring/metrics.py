@@ -7,9 +7,9 @@ thread-safe registry of labeled metrics that every layer of the stack
 (fit loops, parallel wrapper, UI server, bench drivers) publishes into,
 and that exporters.py renders as Prometheus text exposition or JSONL.
 
-Deliberately jax-free: bench.py must be able to snapshot the registry on
-its failure paths (tpu-unavailable) where the accelerator runtime never
-came up. Device-level gauges live in runtime.py.
+Deliberately jax-free: an entry point must be able to snapshot the
+registry on its failure paths (tpu-unavailable) where the accelerator
+runtime never came up. Device-level gauges live in runtime.py.
 """
 
 from __future__ import annotations

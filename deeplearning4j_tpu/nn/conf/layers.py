@@ -231,39 +231,12 @@ def set_stream_cache_sharding(mesh, axis: str = "data") -> None:
     _STREAM_CACHE_SHARDING = None if mesh is None else (mesh, axis)
 
 
-#: the direct paged-decode implementation the streaming attention layer
-#: dispatches when a page table rides the state: ("xla", False) folds the
-#: pool[table] gather into the attention op (any backend); ("pallas", i)
-#: runs the serving/paged_kernel.py paged-attention kernel (i = interpret
-#: mode, for CPU exactness tests). Module-level like
-#: _STREAM_CACHE_SHARDING — part of every streaming jit key, so flipping
-#: it retraces instead of silently reusing the other impl's trace.
-_PAGED_DECODE_IMPL: Tuple[str, bool] = ("xla", False)
-
-
-def set_paged_decode_impl(impl: str, interpret: bool = False) -> None:
-    """Select the direct paged-decode attention implementation
-    (process-wide, like set_stream_cache_sharding): ``"xla"`` — the
-    any-backend fallback where the attention reads K/V through the page
-    table with the gather folded into the dispatch; ``"pallas"`` — the
-    TPU paged-attention kernel (``interpret=True`` emulates it on CPU
-    for exactness tests). The serving engine sets this from
-    ``PagedKVConfig.decode_impl`` at construction."""
-    if impl not in ("xla", "pallas"):
-        raise ValueError(f"paged decode impl must be 'xla' or 'pallas', "
-                         f"got {impl!r}")
-    global _PAGED_DECODE_IMPL
-    _PAGED_DECODE_IMPL = (impl, bool(interpret))
-
-
-def paged_decode_impl() -> Tuple[str, bool]:
-    """The LIVE (impl, interpret) pair direct paged dispatches run
-    under right now. Process-wide: a later engine's construction can
-    flip it, retracing every direct engine's next dispatch onto the
-    new impl — consumers that model per-impl behavior (the engine's
-    KV-traffic accounting, health()) must read this, not a
-    construction-time snapshot."""
-    return _PAGED_DECODE_IMPL
+def paged_reads(layers) -> Tuple[Tuple[str, bool], ...]:
+    """The ``paged_read`` of every layer among `layers` that has one: a
+    net's streaming jit keys hold it, so a net whose engine chose the
+    kernel and a net whose engine chose the folded gather each trace,
+    and keep, their own program."""
+    return tuple(l.paged_read for l in layers if hasattr(l, "paged_read"))
 
 
 def _shard_cache(x, n_lead: int):
@@ -1099,6 +1072,15 @@ class SelfAttentionLayer(FeedForwardLayerConf):
     window: Optional[int] = None
 
     supports_streaming = True
+    #: (impl, interpret): how a decode step reads the page pool when a
+    #: page table rides the state. ("xla", False) folds the pool[table]
+    #: gather into the attention op (any backend); ("pallas", i) runs the
+    #: serving/paged_kernel.py paged-attention kernel (i = interpret
+    #: mode, for CPU exactness tests). No configuration field: the
+    #: serving engine records here, on the layers of the net it serves,
+    #: what ``paged_kernel.choose_paged_read`` answered, and the net's
+    #: streaming jit keys hold it (``paged_reads``).
+    paged_read = ("xla", False)
 
     def output_type(self, it):
         if it.kind != "rnn":
@@ -1325,8 +1307,8 @@ class SelfAttentionLayer(FeedForwardLayerConf):
         page), installed by the serving engine around its decode
         dispatches. The chunk's new tokens append with ONE
         [N, T, Hkv, D] scatter at each row's ``(page, offset)`` — an
-        O(one-token) write, vs the legacy full-arena scatter_pages —
-        then the queries attend against the pool through the table:
+        O(one-token) write — then the queries attend against the pool
+        through the table, by this layer's ``paged_read``:
 
         - ``"xla"`` impl (any backend): the ``pool[table]`` gather is
           folded into this dispatch and feeds the SAME
@@ -1439,7 +1421,7 @@ class SelfAttentionLayer(FeedForwardLayerConf):
         else:
             kp = kp.at[page, :, off, :].set(kt.astype(kp.dtype))
             vp = vp.at[page, :, off, :].set(vt.astype(vp.dtype))
-        impl, interpret = _PAGED_DECODE_IMPL
+        impl, interpret = self.paged_read
         if impl == "pallas" and not prime:
             from deeplearning4j_tpu.serving.paged_kernel import (
                 paged_attention)

@@ -24,7 +24,7 @@ from deeplearning4j_tpu.nn.conf.graph_conf import LayerVertex
 from deeplearning4j_tpu.nn.conf.inputs import InputType
 from deeplearning4j_tpu.nn.conf.layers import (
     STREAM_STATE_KEYS, BaseOutputLayerConf, CenterLossOutputLayer,
-    stream_capacity)
+    paged_reads, stream_capacity)
 from deeplearning4j_tpu.nn.conf.network import ComputationGraphConfiguration
 from deeplearning4j_tpu.nn.score import LazyScore
 from deeplearning4j_tpu.nn.updater import normalize_gradients
@@ -63,6 +63,13 @@ class ComputationGraph(LazyScore):
         self._jit_cache: Dict[Any, Any] = {}
         self._initialized = False
         self._topo = conf.topological_order()
+        #: the layers that read a page pool (``paged_read``): what
+        #: ``_paged_reads`` keys every streaming dispatch on, found once
+        #: (a walk over ~250 vertices a dispatch cost 0.25 ms on the
+        #: chip's host)
+        self._paged_layers = [
+            v.layer for v in conf.vertices.values()
+            if hasattr(getattr(v, "layer", None), "paged_read")]
         self._vertex_input_types: Dict[str, List[InputType]] = {}
         self.fuse_bn_act_conv = False
         self._fusion_cache = None
@@ -1301,17 +1308,18 @@ class ComputationGraph(LazyScore):
         with packed accounting — pads never enter caches nor consume
         streaming positions, so any prompt length primes in one dispatch
         at a bucketed shape (see MultiLayerNetwork.rnn_time_step)."""
-        # stream-cache sharding / paged-decode impl configs key the
-        # cache: flipping the process-wide setting retraces for every
-        # net on next use. donate_state (TPU/GPU only — a no-op on CPU)
-        # aliases the carried state buffers into the dispatch: the
-        # serving engine's direct-paged decode sets it so the page
-        # pools update in place (see MultiLayerNetwork.rnn_time_step).
+        # the process-wide stream-cache sharding keys the cache
+        # (flipping it retraces for every net on next use), and so does
+        # the page-pool read this net's own attention layers hold.
+        # donate_state (TPU/GPU only — a no-op on CPU) aliases the
+        # carried state buffers into the dispatch: the serving engine's
+        # paged decode sets it so the page pools update in place (see
+        # MultiLayerNetwork.rnn_time_step).
         from deeplearning4j_tpu.nn.conf import layers as _L
         padded = pad_left is not None
         donate = donate_state and jax.default_backend() != "cpu"
         key = ("rnn_step", padded, donate, self.conf.dtype,
-               _L._STREAM_CACHE_SHARDING, _L._PAGED_DECODE_IMPL)
+               _L._STREAM_CACHE_SHARDING, self._paged_reads())
         if key not in self._jit_cache:
             if padded:
                 def fwd(params, state, ins, rng, pad):
@@ -1426,6 +1434,11 @@ class ComputationGraph(LazyScore):
             updates[name] = new_pos
         return {**pos, **updates}
 
+
+    def _paged_reads(self):
+        """This graph's part of its streaming jit keys: how each of its
+        attention layers reads a page pool (``layers.paged_reads``)."""
+        return paged_reads(self._paged_layers)
 
     def set_stream_cache_sharding(self, mesh, axis: str = "data"):
         """Shard streaming attention KV caches over the sequence axis of

@@ -290,8 +290,8 @@ class MultiLayerNetwork(LazyScore):
         returns a raw device ok-flag, and under "skip" a bad step
         applies a where-zeroed update: params/opt-state/BN-stats keep
         their pre-step values, all on device, no host sync. Returns a
-        4-tuple under "off" (the pre-resilience contract bench.py and
-        the distributed workers rely on), a 5-tuple otherwise."""
+        4-tuple under "off" (the pre-resilience contract the
+        distributed workers rely on), a 5-tuple otherwise."""
         if getattr(self, "_quantized", False):
             raise RuntimeError(
                 "this network was quantized for inference "
@@ -397,7 +397,9 @@ class MultiLayerNetwork(LazyScore):
         # the process-wide stream-cache sharding config is part of the
         # key: flipping it retraces the step for EVERY net on next use
         # (a stale compiled step would silently keep the old layout);
-        # same for the paged-decode impl (xla fallback vs pallas kernel)
+        # so is the page-pool read this net's own attention layers hold
+        # (xla fallback vs pallas kernel: what the engine serving THIS
+        # net chose)
         from deeplearning4j_tpu.nn.compute import f32_head as head
         from deeplearning4j_tpu.nn.conf import layers as _L
         # donation only means anything where XLA aliases buffers; on CPU
@@ -407,7 +409,7 @@ class MultiLayerNetwork(LazyScore):
         key = ("out", train, carry_rnn, stream, padded, donate,
                self.conf.dtype,
                _L._STREAM_CACHE_SHARDING if stream else None,
-               _L._PAGED_DECODE_IMPL if stream else None)
+               _L.paged_reads(self.layers) if stream else None)
         if key not in self._jit_cache:
             if padded:
                 # left-padded packed chunk: pad count is a TRACED scalar,

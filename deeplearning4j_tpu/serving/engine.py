@@ -56,15 +56,15 @@ Serving engine v2 extras, each orthogonal and composable:
   budget — admission checks the request's worst-case pages against the
   free pool (head-of-line blocking when short; requests that can NEVER
   fit are rejected at submit), retirement frees pages immediately, and
-  decode runs DIRECTLY on the page pool by default (``direct=True``):
-  the attention step reads K/V through the per-slot page table (XLA
-  fallback, or the ``serving/paged_kernel.py`` Pallas paged-attention
-  kernel) and the new token appends with an O(one-token) in-dispatch
-  write — no per-step gather/scatter round trip (``direct=False``
-  keeps the legacy round trip as the bench A/B baseline). Outputs stay
-  bit-identical to the slot arena (and to one-shot ``sample_stream``)
-  on every path. With ``prefix_cache=True`` (default) shared
-  full-block prompt prefixes prime once (``serving/prefix_cache.py``):
+  decode runs DIRECTLY on the page pool: the attention step reads K/V
+  through the per-slot page table (the folded XLA gather, or the
+  ``serving/paged_kernel.py`` Pallas paged-attention kernel:
+  ``paged_kernel.choose_paged_read`` answers which, once, at
+  construction) and the new token appends with an O(one-token)
+  in-dispatch write. Outputs stay bit-identical to the slot arena (and
+  to one-shot ``sample_stream``) on either read. With
+  ``prefix_cache=True`` (default) shared full-block prompt prefixes
+  prime once (``serving/prefix_cache.py``):
   later requests map the cached pages and prefill only their suffix.
   ``dl4jtpu_serving_kv_bytes_moved_total`` prices the KV path in use;
   see ARCHITECTURE.md "Paged decode fast path".
@@ -166,8 +166,7 @@ from deeplearning4j_tpu.monitoring.metrics import (
 from deeplearning4j_tpu.monitoring.tracing import next_phase, phases
 from deeplearning4j_tpu.nn.conf.layers import (
     BATCHED_STREAM_KEYS, PositionalEmbeddingLayer, check_rewindable,
-    paged_decode_impl, paged_leaves, rewind_stream_state,
-    set_paged_decode_impl, stream_capacity)
+    paged_leaves, rewind_stream_state, stream_capacity)
 from deeplearning4j_tpu.resilience.chaos import fire as _fire_chaos
 from deeplearning4j_tpu.resilience.retry import RetryPolicy, retry_call
 from deeplearning4j_tpu.serving.errors import (
@@ -188,7 +187,7 @@ from deeplearning4j_tpu.serving.overload import (
     BROWNOUT_NO_PREFIX_INSERTS, BROWNOUT_NO_SPECULATION,
     BROWNOUT_REDUCED_GAMMA, OverloadConfig, OverloadController)
 from deeplearning4j_tpu.serving.paged_kernel import (
-    pages_per_step, paged_attention_supported)
+    PLAIN_LEAVES, choose_paged_read, pages_per_step)
 from deeplearning4j_tpu.serving.paging import (
     PagedKVConfig, PagePool, allocate_pools, gather_pages, pages_needed,
     scatter_pages, set_page)
@@ -417,14 +416,13 @@ class GenerationEngine:
         self._merge_keys = None
         # -- block-paged KV arena (serving/paging.py) ------------------
         self._paging = paging
-        if (paging is None or not paging.direct) and any(
-                leaf.key not in ("kv_k", "kv_v")
+        if paging is None and any(
+                leaf.key not in PLAIN_LEAVES
                 for l in layers for leaf in paged_leaves(l)):
             raise ValueError(
                 "this net's attention keeps more than keys and values "
                 "per token and decodes per-row positions only through "
-                "a page table: construct with "
-                "paging=PagedKVConfig(direct=True)")
+                "a page table: construct with paging=PagedKVConfig()")
         self._pool: Optional[PagePool] = None
         self._prefix: Optional[PrefixCache] = None
         self._page_store = None            # device pools, per paged leaf
@@ -441,18 +439,17 @@ class GenerationEngine:
         #: closure over :meth:`export_prefix_chain`. Failures are
         #: swallowed: publishing is best-effort, admission is not.
         self.page_publisher: Optional[Callable] = None
-        #: direct paged decode (no gather/scatter round trip) + its
-        #: resolved attention impl ("xla" | "pallas"); see
+        #: which code reads the page pool in a decode step ("xla" |
+        #: "pallas"; None without paging): what
+        #: ``paged_kernel.choose_paged_read`` answered at construction,
+        #: recorded on this net's attention layers too. See
         #: ARCHITECTURE.md "Paged decode fast path"
-        self._direct = False
         self._decode_impl: Optional[str] = None
-        self._decode_key: Optional[str] = None
         #: the pool's authoritative storage precision ("bf16" = the
         #: net's native leaf dtype, "int8" = serving/quant.py) and the
         #: int8 plumbing: per-leaf [P, Hkv] scale sidecars + the
         #: (name, Hkv, head_dim) layer map the eager store builds from
         self._kv_dtype = "bf16"
-        self._quant_key: Optional[str] = None
         self._scale_store = None
         self._quant_dims = None
         self._scale_row_bytes = 0          # per-dispatch scale read unit
@@ -461,20 +458,19 @@ class GenerationEngine:
         #: step (the host used to rebuild and re-upload it every step
         #: even when nothing changed)
         self._tables_cache: Optional[np.ndarray] = None
-        self._table_dev_cache = None
         self._tables_layer_cache = None    # per-layer copies (donation)
         #: modeled KV bytes moved by the pool<->dispatch paths (see
         #: serving/health.SERVING_KV_BYTES_MOVED)
         self._kv_bytes_total = 0
         self._tok_bytes = 0                # per-position bytes, all leaves
-        #: whether direct dispatches actually donate state buffers
+        #: whether paged dispatches actually donate state buffers
         #: (rnn_time_step resolves donation off on CPU — there the
         #: pre-dispatch table/pool references stay valid)
         self._state_donated = jax.default_backend() != "cpu"
         #: host mirror of the dispatch-latency histogram (health())
         self._dispatch_s_total = 0.0
         #: a retirement freed a slot whose DEVICE kv_pos keeps coasting
-        #: (+1 per dispatch): the next direct install zeroes free rows'
+        #: (+1 per dispatch): the next install zeroes free rows'
         #: positions so an idle slot that once held a long context
         #: doesn't defeat the kernel's dead-block skip forever
         self._kv_pos_dirty = False
@@ -506,53 +502,20 @@ class GenerationEngine:
                     "block-paged KV needs layers that declare what they "
                     "keep per token (paged_leaves())")
             #: keys and values [Hkv, D] a token: the one layout the
-            #: grouped-query kernel, the int8 sidecar and the crossover
-            #: fingerprints know
-            plain = all(leaf.key in ("kv_k", "kv_v")
-                        for _, leaf in self._paged_decl)
-            # -- kv_dtype resolution (before pool sizing: a byte
-            # budget and the impl eligibility both depend on it) -----
-            l0 = kv_layers[0]
+            #: grouped-query kernel and the int8 sidecar know
+            leaf_keys = [leaf.key for _, leaf in self._paged_decl]
+            plain = PLAIN_LEAVES.issuperset(leaf_keys)
             native_dtype = getattr(net.conf, "dtype", None) or "float32"
-            recurrent = any(getattr(l, "carries_recurrent_state", False)
-                            for l in layers)
-            kv_dtype = getattr(paging, "kv_dtype", "bf16")
-            if not plain:
-                if kv_dtype == "int8" or paging.decode_impl == "pallas":
-                    raise ValueError(
-                        "the int8 sidecar and the paged-attention "
-                        "kernel know [Hkv, D] keys and values only; "
-                        f"this net's layers declare "
-                        f"{sorted({l.key for _, l in self._paged_decl})}"
-                        " (use kv_dtype='bf16', decode_impl='xla')")
-                kv_dtype = "bf16"
-            if kv_dtype != "bf16":
-                from deeplearning4j_tpu.tuning.plan import (
-                    quant_key_for_engine, resolve_kv_dtype)
-                #: the paged_decode_quant crossover fingerprint — what
-                #: kv_dtype="auto" consults and a calibrating bench
-                #: records (tuning/crossover.py)
-                self._quant_key = quant_key_for_engine(
-                    self._ps, l0.n_out // l0.n_heads,
-                    getattr(l0, "n_kv_heads", None) or l0.n_heads,
-                    self._L, native_dtype)
-            if kv_dtype == "auto":
-                # eligibility is the static gate (direct paged decode,
-                # no recurrent h/c); the CHOICE needs a calibrated,
-                # platform-matching paged_decode_quant entry that says
-                # int8 won — uncalibrated runs stay bf16 (quantization
-                # is an accuracy trade, opted into by measurement)
-                kv_dtype = resolve_kv_dtype(
-                    bool(paging.direct) and not recurrent,
-                    self._quant_key)
-            if kv_dtype == "int8" and recurrent:
+            if paging.kv_dtype == "int8" and any(
+                    getattr(l, "carries_recurrent_state", False)
+                    for l in layers):
                 raise ValueError(
                     "kv_dtype='int8' quantizes position-indexed KV "
                     "pages only; recurrent h/c state is a function of "
                     "the whole prefix and cannot re-prime through the "
                     "paged path (use kv_dtype='bf16', or a pure-"
                     "attention model)")
-            self._kv_dtype = kv_dtype
+            self._kv_dtype = paging.kv_dtype
             if paging.total_bytes is not None and not plain:
                 usable = paging.resolve_pages_bytes(
                     self._ps * jnp.dtype(native_dtype).itemsize
@@ -563,52 +526,27 @@ class GenerationEngine:
                     kv_page_bytes)
                 dims = self._paged_layer_dims()
                 usable = paging.resolve_pages_bytes(kv_page_bytes(
-                    [(h, d) for _, h, d in dims], self._ps, kv_dtype,
-                    native_dtype))
+                    [(h, d) for _, h, d in dims], self._ps,
+                    self._kv_dtype, native_dtype))
             else:
                 usable = paging.resolve_pages(slots, self._n_max)
             self._pool = PagePool(usable + 1, self._ps)  # +1: null page
-            self._direct = bool(paging.direct)
-            if self._direct and not plain:
-                # no kernel reads these leaves: the layers' own paged
-                # form runs, gathers folded into the dispatch
-                set_paged_decode_impl("xla", False)
-                self._decode_impl = "xla"
-            elif self._direct:
-                from deeplearning4j_tpu.tuning.plan import (
-                    decode_key_for_engine, resolve_decode_impl)
-                #: the crossover fingerprint of this engine's decode
-                #: shape — what "auto" consults and what a calibrating
-                #: bench records (tuning/crossover.py)
-                self._decode_key = decode_key_for_engine(
-                    self._ps, l0.n_out // l0.n_heads,
-                    getattr(l0, "n_kv_heads", None) or l0.n_heads,
-                    self._L,
-                    getattr(net.conf, "dtype", None) or "float32")
-                impl = paging.decode_impl
-                if impl == "auto":
-                    # ELIGIBILITY is the static gate (unchanged): the
-                    # kernel path needs TPU-tileable shapes and a TPU
-                    # backend; the XLA fallback serves everything else.
-                    # The CHOICE among eligible impls comes from the
-                    # measured kernel-crossover store when a calibrated
-                    # entry for this (page_size, head_dim, L) exists —
-                    # PERF.md: "record the crossover so auto can learn
-                    # it". No entry → the kernel (the PR 10 default).
-                    ok = all(paged_attention_supported(
-                        (self._pool.total_pages,
-                         getattr(l, "n_kv_heads", None) or l.n_heads,
-                         self._ps, l.n_out // l.n_heads), 1,
-                        kv_dtype=self._kv_dtype)
-                        for l in kv_layers)
-                    eligible = jax.default_backend() == "tpu" and ok
-                    impl = resolve_decode_impl(eligible,
-                                               self._decode_key)
-                # process-wide like stream-cache sharding: part of the
-                # streaming jit key, so engines with different impls
-                # retrace rather than silently sharing a trace
-                set_paged_decode_impl(impl, paging.kernel_interpret)
-                self._decode_impl = impl
+            # the read belongs to the net this engine serves: its
+            # attention layers hold it and its streaming jit keys key on
+            # it, so another engine's net keeps its own
+            read = choose_paged_read(
+                leaf_keys,
+                [(self._pool.total_pages,
+                  getattr(l, "n_kv_heads", None) or l.n_heads,
+                  self._ps, l.n_out // l.n_heads)
+                 for l in kv_layers] if plain else (),
+                kv_dtype=self._kv_dtype, decode_impl=paging.decode_impl,
+                kernel_interpret=paging.kernel_interpret,
+                backend=jax.default_backend())
+            self._decode_impl = read[0]
+            for l in kv_layers:
+                if hasattr(l, "paged_read"):
+                    l.paged_read = read
             if paging.prefix_cache:
                 if any(getattr(l, "carries_recurrent_state", False)
                        for l in layers):
@@ -686,13 +624,13 @@ class GenerationEngine:
         self._decode_chaos = decode_chaos
         self._seat_chaos = seat_chaos
         self._decode_retry = decode_retry
-        #: donate state into direct dispatches ONLY without a retry
+        #: donate state into paged dispatches ONLY without a retry
         #: policy: a retried attempt would re-run against donated,
-        #: already-consumed buffers. With decode_retry set, direct mode
-        #: pays a pool copy per step (on TPU/GPU) for retryability —
-        #: the retry-exactness contract (the fault fires before any
-        #: state mutates) then holds exactly as on the legacy path.
-        self._donate = self._direct and decode_retry is None
+        #: already-consumed buffers. With decode_retry set, a paged
+        #: engine pays a pool copy per step (on TPU/GPU) for
+        #: retryability — the retry-exactness contract (the fault fires
+        #: before any state mutates) then holds as on the slot arena.
+        self._donate = self._pool is not None and decode_retry is None
         # -- survivability (serving/supervisor.py, serving/overload.py)
         self._supervisor = supervisor
         if isinstance(overload, OverloadConfig):
@@ -797,9 +735,9 @@ class GenerationEngine:
         if self._pool is not None:
             self._kv_bytes = r.counter(
                 SERVING_KV_BYTES_MOVED, "Modeled bytes the KV path "
-                "moves between the page pool and the dispatch (legacy: "
-                "full gather+scatter round trip; direct: in-dispatch "
-                "read + one-token append)", ("model",)).labels(**lab)
+                "moves between the page pool and the dispatch "
+                "(in-dispatch read + one-token append)",
+                ("model",)).labels(**lab)
         r.gauge(SERVING_ACTIVE_SLOTS, "Arena slots holding an active "
                 "request", ("model",)).set_function(
             scrape_probe(self, lambda s: s.active_slots()),
@@ -939,11 +877,7 @@ class GenerationEngine:
                                "free": self._pool.free_count(),
                                "page_size": self._pool.page_size}
             out["kv_traffic"] = {
-                # the LIVE impl: another engine's construction can flip
-                # the process-wide setting — report what dispatches
-                # actually run, not the construction-time resolution
-                "decode_path": (f"direct-{self._live_impl()}"
-                                if self._direct else "roundtrip"),
+                "decode_path": f"direct-{self._decode_impl}",
                 # table entries one grid step of the kernel's walk
                 # copies and scores (0 off the kernel path)
                 "kernel_pages_per_step": self._kernel_pages_per_step(),
@@ -1545,7 +1479,7 @@ class GenerationEngine:
                 # once: the prompt's pool bytes must come from the same
                 # quantized append the decode steps run) — a prefix hit
                 # just starts kv_pos past the shared pages, no dense
-                # gather/scatter round trip
+                # gather and re-scatter
                 self._install_prime_paged_state(table, hit_len)
             elif hit_len:
                 self._install_prefix(table, hit_len)
@@ -2231,7 +2165,7 @@ class GenerationEngine:
         forces the folded-gather read and unlocks packed (pad_left)
         accounting in ``_stream_attend_paged``. On a prefix hit the
         suffix prime attends the shared pages in place — no dense
-        gather, no page re-scatter (``_install_prefix``'s round trip
+        gather, no page re-scatter (``_install_prefix``'s dense view
         has no int8 equivalent)."""
         net = self.net
         row = np.zeros((1, self._n_max), np.int32)
@@ -2335,27 +2269,18 @@ class GenerationEngine:
         callable (the fault fires before any state mutates, so a
         retried dispatch is numerically identical to a fault-free one).
 
-        Paged modes differ in what moves around `fn`:
-
-        - DIRECT (the fast path): the pool + cached page tables are
-          installed into ``net.state`` as references — the dispatch
-          itself reads K/V through the table and appends the new
-          tokens' K/V in place (O(one-token) write); afterwards the
-          updated pool references are extracted back. Nothing is
-          materialized densely, nothing is scattered back.
-        - legacy round trip (``PagedKVConfig(direct=False)``, the bench
-          A/B baseline): gather the dense view from the pool, run the
-          dispatch over it, commit the updated view back BEFORE any
-          retirement the outputs trigger can free pages.
+        A paged engine reads install → dispatch → extract: the pool +
+        cached page tables are installed into ``net.state`` as
+        references — the dispatch itself reads K/V through the table and
+        appends the new tokens' K/V in place (O(one-token) write);
+        afterwards the updated pool references are extracted back.
+        Nothing is materialized densely, nothing is scattered back.
 
         Every cycle lands in the dispatch-latency histogram and the
         modeled KV traffic in the kv-bytes-moved counter."""
-        direct = self._pool is not None and self._direct
-        table = None
-        if direct:
+        paged = self._pool is not None
+        if paged:
             self._install_paged_state()
-        elif self._pool is not None:
-            table = self._paged_gather()
 
         def once():
             _fire_chaos(self._decode_chaos, self._dispatches)
@@ -2365,37 +2290,26 @@ class GenerationEngine:
         out = (retry_call(once, policy=self._decode_retry,
                           op="serving_decode")
                if self._decode_retry is not None else once())
-        if direct:
+        if paged:
             self._extract_paged_state()
-        elif table is not None:
-            self._paged_scatter(table)
         self.net.state = self._take_stats(self.net.state)
         dt = time.perf_counter() - t0
         self._dispatch_s_total += dt
         self._dispatch_hist.observe(dt)
-        if self._pool is not None:
+        if paged:
             self._kv_traffic(self._kv_dispatch_bytes(width))
         self._dispatches += 1
         self._dispatch_rows += self.active_slots()
         return out
 
     # ------------------------------------------------------------------
-    # the paged pool <-> dispatch plumbing (direct view / legacy round
-    # trip) + cached page tables
+    # the paged pool <-> dispatch plumbing + cached page tables
     # ------------------------------------------------------------------
-    def _live_impl(self) -> Optional[str]:
-        """The impl direct dispatches run under RIGHT NOW — the
-        process-wide setting, which a later engine's construction can
-        flip (retracing this engine's next dispatch onto the new
-        path). ``self._decode_impl`` records only what THIS engine
-        resolved at construction."""
-        return paged_decode_impl()[0] if self._direct else None
-
     def _kernel_pages_per_step(self) -> int:
         """The G the paged-attention kernel resolves for this engine's
         pool (``paged_kernel.pages_per_step``); 0 while dispatches run
         off the kernel path."""
-        if self._live_impl() != "pallas":
+        if self._decode_impl != "pallas":
             return 0
         _, hkv, d = self._paged_layer_dims()[0]
         native = getattr(self.net.conf, "dtype", None) or "float32"
@@ -2410,7 +2324,6 @@ class GenerationEngine:
         mutations every dispatch reuses the same host array and device
         upload(s): steady-state decode re-uploads nothing."""
         self._tables_cache = None
-        self._table_dev_cache = None
         self._tables_layer_cache = None
 
     def _tables_np(self) -> np.ndarray:
@@ -2421,16 +2334,9 @@ class GenerationEngine:
             self._tables_cache = t
         return self._tables_cache
 
-    def _table_dev(self):
-        """One shared device copy of the table (the legacy round trip's
-        gather/scatter argument)."""
-        if self._table_dev_cache is None:
-            self._table_dev_cache = jnp.asarray(self._tables_np())
-        return self._table_dev_cache
-
     def _tables_dev_per_layer(self):
         """Device table copies, one DISTINCT buffer per paged layer:
-        the direct path donates the whole state pytree on TPU, and
+        the dispatch donates the whole state pytree on TPU, and
         donation must never see the same buffer at two leaves."""
         if self._tables_layer_cache is None:
             tnp = self._tables_np()
@@ -2477,7 +2383,7 @@ class GenerationEngine:
 
     def _extract_paged_state(self) -> None:
         """Pull the (appended-to) pools back out of ``net.state`` after
-        a direct dispatch, and refresh the per-layer table cache from
+        a dispatch, and refresh the per-layer table cache from
         the returned leaves — under donation the pre-dispatch buffers
         are consumed, so the returned references are the only live
         copies."""
@@ -2509,9 +2415,6 @@ class GenerationEngine:
         """Bytes the KV path moves around ONE dispatch, modeled from
         the path in use (summed over attention leaves; reads + writes):
 
-        - legacy round trip: the gather materializes the full dense
-          [S, L] view and the scatter writes it all back — 2·S·L
-          positions regardless of live context.
         - direct-xla: the folded gather still materializes the mapped
           [S, L] view once inside the dispatch (S·L reads; of a leaf
           whose layer gathers a selection, ``paged_read_tokens()``
@@ -2531,10 +2434,8 @@ class GenerationEngine:
         if self._tok_bytes == 0:
             return 0
         S, L, ps = self.slots, self._L, self._ps
-        if not self._direct:
-            return 2 * S * L * self._tok_bytes
         append = S * width * self._tok_bytes
-        if self._live_impl() == "pallas":
+        if self._decode_impl == "pallas":
             live = sum(
                 min(-(-int(self._row_pos[s] + width) // ps) * ps, L)
                 for s, r in enumerate(self._slots) if r is not None)
@@ -2542,31 +2443,6 @@ class GenerationEngine:
                     + (live // ps) * self._scale_row_bytes)
         return (S * self._xla_read_bytes + append
                 + S * self._n_max * self._scale_row_bytes)
-
-    def _paged_gather(self):
-        """Legacy round trip: materialize the dense per-slot KV view
-        from the pool into ``net.state`` for the coming dispatch;
-        returns the (cached) device page table it was gathered through
-        (the scatter must use the same snapshot)."""
-        table = self._table_dev()
-        dense = gather_pages(self._page_store, table, length=self._L,
-                             axes=self._paged_axes)
-        st = dict(self.net.state)
-        for (n, k), leaf in zip(self._paged_keys, dense):
-            d = dict(st[n])
-            d[k] = leaf
-            st[n] = d
-        self.net.state = st
-        return table
-
-    def _paged_scatter(self, table) -> None:
-        """Legacy round trip: commit the dispatch's updated dense KV
-        back to the mapped pages (donated in-place pool update). Must
-        run before any retirement triggered by the dispatch's outputs —
-        freed pages may be re-allocated at the next admission."""
-        dense = [self.net.state[n][k] for n, k in self._paged_keys]
-        self._page_store = scatter_pages(self._page_store, dense, table,
-                                         axes=self._paged_axes)
 
     def _retire(self, slot: int, reason: str,
                 exc: Optional[BaseException] = None) -> None:
@@ -2602,11 +2478,11 @@ class GenerationEngine:
         0. Free rows are inert: nothing reads them until a scatter
         overwrites them.
 
-        DIRECT paged mode drops the dense kv_k/kv_v leaves entirely:
-        the pool is the only KV storage (no [S, Hkv, L, D] arena copy
-        exists to allocate, gather into, or scatter from — the memory
-        half of the round-trip elimination); the per-dispatch paged
-        view rides in via _install_paged_state instead."""
+        A paged engine drops the dense kv_k/kv_v leaves entirely: the
+        pool is the only KV storage (no [S, Hkv, L, D] arena copy
+        exists to allocate, gather into, or scatter from); the
+        per-dispatch paged view rides in via _install_paged_state
+        instead."""
         S = self.slots
         paged = set(self._paged_keys or ())
         arena = {}
@@ -2626,7 +2502,7 @@ class GenerationEngine:
             for k, v in s.items():
                 if k not in _SCATTER_KEYS:
                     continue
-                if self._direct and (name, k) in paged:
+                if (name, k) in paged:
                     continue        # the page pool IS the KV storage
                 # admission-time arena construction (slot lifecycle),
                 # not the per-token decode steady state
@@ -2644,7 +2520,7 @@ class GenerationEngine:
     def _merge(self, arena_state, primed_state, slot: int):
         if self._merge_keys is None:
             # paged leaves join through the page scatter, not the dense
-            # arena (their dense view is rebuilt from the pool per step)
+            # arena (the pool is their only storage)
             excl = set(self._paged_keys or ())
             self._merge_keys = [
                 (n, k) for n in sorted(primed_state)

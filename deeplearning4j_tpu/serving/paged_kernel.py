@@ -1,13 +1,13 @@
-"""Pallas TPU paged-attention decode kernel (+ the XLA reference).
+"""Pallas TPU paged-attention decode kernel, its gate, the chooser
+between it and the XLA read, and the XLA reference.
 
 The direct-paged-decode counterpart of ``nn/layers/pallas_attention.py``:
 where that module fuses the *training/prefill* attention schedule, this
 one fuses the *serving decode* read path over the block-paged KV pool
-(``serving/paging.py``). The engine's steady-state step used to wrap the
-canonical decode in a full-arena ``gather_pages → dispatch →
-scatter_pages`` round trip — every generated token moved 2× the entire
-token-budget pool per attention leaf through HBM regardless of how much
-context was actually live. Here the page table IS the access path
+(``serving/paging.py``). A dense ``gather_pages → dispatch →
+scatter_pages`` round trip would move 2× the entire token-budget pool
+per attention leaf through HBM for every generated token, regardless of
+how much context is live. Here the page table IS the access path
 (cuDNN's fused-primitive lesson, PAPERS.md: fold the memory movement
 into the consuming op):
 
@@ -49,17 +49,18 @@ The XLA fallback for the same seam lives in
 ``SelfAttentionLayer._stream_attend_paged`` (nn/conf/layers.py): it
 folds the ``pool[table]`` gather into the attention dispatch and shares
 ``_grouped_attend`` with the dense arena bit-for-bit.
-``paged_ref_attention`` here is the standalone dense-gather reference
-the kernel tests compare against.
+``choose_paged_read`` is the one place that picks between the two (the
+engine's constructor calls it once and records the answer on its net's
+attention layers); ``paged_attention_supported`` is the kernel's shape
+gate it consults. ``paged_ref_attention`` here is the standalone
+dense-gather reference the kernel tests compare against.
 
 Appends are NOT this kernel's job: the new token's K/V lands in the
 pool via a one-token ``[S, Hkv, W, D]`` scatter at ``(page, offset)``
-computed from each row's position (the layer does it before attending),
-replacing the donated full-arena ``scatter_pages`` with an
-O(one-token) write. Prefix-shared read-only blocks stay safe by block
+computed from each row's position (the layer does it before attending):
+an O(one-token) write. Prefix-shared read-only blocks stay safe by block
 alignment: a slot only ever appends at positions ≥ its own fresh
-blocks (copy-on-extend falls out of the allocation math, the same
-argument as the legacy scatter's).
+blocks (copy-on-extend falls out of the allocation math).
 """
 
 from __future__ import annotations
@@ -77,8 +78,14 @@ from jax.extend.core import jaxpr_as_fun
 NEG_INF = -1e30   # finite: exp(NEG_INF - NEG_INF) inside a fully-masked
 #                   row must not produce NaN (explicit re-zeroing below)
 
-__all__ = ["pages_per_step", "paged_attention",
-           "paged_attention_supported", "paged_ref_attention"]
+__all__ = ["PLAIN_LEAVES", "choose_paged_read", "pages_per_step",
+           "paged_attention", "paged_attention_supported",
+           "paged_ref_attention"]
+
+#: the cache leaves the kernel (and the int8 sidecar) can read: keys and
+#: values, [Hkv, D] a token, as ``SelfAttentionLayer.paged_leaves()``
+#: declares them
+PLAIN_LEAVES = frozenset({"kv_k", "kv_v"})
 
 
 #: keys one grid step scores per kv head: the lane width of the score
@@ -392,7 +399,8 @@ def paged_attention_supported(pool_shape: Tuple[int, ...],
       page. A larger int8 pool decodes on the XLA path.
 
     Interpret mode (CPU tests) has no such limits — this gate only
-    decides the ``decode_impl="auto"`` resolution on a TPU backend."""
+    decides ``decode_impl="auto"`` on a TPU backend
+    (``choose_paged_read``)."""
     if len(pool_shape) != 4:
         return False
     pages, hkv, ps, d = pool_shape
@@ -402,6 +410,40 @@ def paged_attention_supported(pool_shape: Tuple[int, ...],
         lanes = -(-hkv // 128) * 128
         return 2 * pages * lanes * 4 <= _SMEM_SCALE_BUDGET
     return True
+
+
+def choose_paged_read(leaf_keys, pool_shapes, *, kv_dtype: str,
+                      decode_impl: str, kernel_interpret: bool,
+                      backend: str) -> Tuple[str, bool]:
+    """The one place that answers which code reads the page pool in a
+    decode step: ``(impl, interpret)``, what the engine records as its
+    net's layers' ``paged_read``. From what can be observed —
+    ``leaf_keys``, the cache leaves the net's layers declare;
+    ``pool_shapes``, each attention layer's ``(P, Hkv, page_size, D)``;
+    the pool's ``kv_dtype``; the ``backend`` — and what
+    ``PagedKVConfig`` asked:
+
+    - leaves other than plain keys and values: no kernel reads them and
+      no int8 sidecar scales them, the layers' own paged form runs with
+      its gathers folded into the dispatch (``"xla"``). ``"pallas"`` or
+      ``"int8"`` asked of such a net is an error;
+    - ``"auto"``: the kernel iff the backend is a TPU and every pool
+      passes ``paged_attention_supported``, else ``"xla"``;
+    - ``"xla"`` / ``"pallas"``: taken as asked (``kernel_interpret``
+      runs the kernel off the chip: the tests' reference)."""
+    if not PLAIN_LEAVES.issuperset(leaf_keys):
+        if kv_dtype == "int8" or decode_impl == "pallas":
+            raise ValueError(
+                "the int8 sidecar and the paged-attention kernel know "
+                "[Hkv, D] keys and values only; this net's layers "
+                f"declare {sorted(set(leaf_keys))} (use kv_dtype='bf16',"
+                " decode_impl='xla')")
+        return "xla", False
+    if decode_impl == "auto":
+        decode_impl = "pallas" if backend == "tpu" and all(
+            paged_attention_supported(shape, 1, kv_dtype=kv_dtype)
+            for shape in pool_shapes) else "xla"
+    return decode_impl, bool(kernel_interpret) and decode_impl == "pallas"
 
 
 def paged_ref_attention(q, k_pool, v_pool, table, lengths, *,

@@ -18,22 +18,18 @@ slot's token blocks to pool pages, shared by all of a layer's leaves. Capacity b
 - pages are refcounted, so the prefix cache can map one physical page
   into many slots' tables read-only (``serving/prefix_cache.py``).
 
-The per-step dispatch is DIRECT by default (PR 10, ``direct=True``):
-the attention step reads K/V straight through the page table (XLA
-fallback folds the ``pool[table]`` gather into the dispatch; the
-``serving/paged_kernel.py`` Pallas kernel reads only live pages via
-scalar-prefetched tables) and the new token's K/V appends with an
-O(one-token) in-dispatch write — one fixed-shape dispatch per step,
-nothing materialized densely, zero retraces after warmup (see
-ARCHITECTURE.md "Paged decode fast path"). ``direct=False`` keeps the
-legacy round trip this module's ``gather_pages``/``scatter_pages``
-implement — a jitted gather materializes the active slots' dense
-``[S, Hkv, L, D]`` view, the ONE decode (or widened verify) dispatch
-runs over it unchanged, and a jitted donated scatter commits the
-updated view back — the bench A/B baseline, bit-identical math either
-way since valid positions carry the exact bytes the slot arena would
-hold. (``gather_pages`` also still serves the prefix cache's one-row
-prefill installs.)
+The per-step dispatch works DIRECTLY on the pool: the attention step
+reads K/V straight through the page table (the XLA read folds the
+``pool[table]`` gather into the dispatch; the ``serving/paged_kernel.py``
+Pallas kernel reads only live pages via scalar-prefetched tables) and
+the new token's K/V appends with an O(one-token) in-dispatch write — one
+fixed-shape dispatch per step, nothing materialized densely, zero
+retraces after warmup (see ARCHITECTURE.md "Paged decode fast path").
+This module's ``gather_pages`` / ``scatter_pages`` serve admission only:
+a prime's dense ``[1, Hkv, L, D]`` row is scattered into the request's
+pages, and a prefix hit's shared pages are gathered into the one-row
+view the suffix prime attends — valid positions carry the exact bytes
+the slot arena would hold.
 
 Page 0 is the reserved **null page**: table entries beyond a slot's
 allocation point at it, so gathers read garbage that position-validity
@@ -49,6 +45,8 @@ from typing import List, Optional
 
 import jax
 import jax.numpy as jnp
+
+from deeplearning4j_tpu.serving.quant import KV_DTYPES
 
 __all__ = ["PagePool", "PageExhausted", "PagedKVConfig", "allocate_pools",
            "gather_pages", "pages_needed", "scatter_pages", "set_page"]
@@ -80,33 +78,24 @@ class PagedKVConfig:
     of the unquantized path, not a cast; ``"int8"`` stores symmetric
     per-(page, kv-head) int8 with a ``[P, Hkv]`` amax-scale sidecar
     per leaf (``serving/quant.py`` — quantize-once on write,
-    dequantize-on-read in both decode impls; requires ``direct=True``:
-    the legacy dense round trip has no quantized read path);
-    ``"auto"`` consults the measured ``paged_decode_quant`` crossover
-    entry for this engine's shape (tuning/plan.resolve_kv_dtype) —
-    uncalibrated runs stay bf16.
+    dequantize-on-read in both decode impls).
 
-    ``direct`` (default) makes decode operate DIRECTLY on the page
-    pool: the attention step reads K/V through the page table and the
-    new token appends with an O(one-token) in-dispatch write — no
-    per-step gather/scatter round trip (ARCHITECTURE.md "Paged decode
-    fast path"). ``direct=False`` keeps the legacy round trip (the
-    bench A/B baseline). ``decode_impl`` selects the direct read path:
-    ``"xla"`` (any backend — the gather folds into the dispatch),
-    ``"pallas"`` (the serving/paged_kernel.py TPU paged-attention
-    kernel; ``kernel_interpret=True`` emulates it on CPU for exactness
-    tests), or ``"auto"`` (eligibility: pallas needs TPU + shapes that
-    pass the kernel gate, xla otherwise; among eligible impls the
-    measured kernel-crossover store makes the choice when a calibrated
-    entry exists for this shape — tuning/crossover.py — with the
-    kernel as the uncalibrated default)."""
+    Decode operates DIRECTLY on the page pool: the attention step reads
+    K/V through the page table and the new token appends with an
+    O(one-token) in-dispatch write (ARCHITECTURE.md "Paged decode fast
+    path"). ``decode_impl`` asks for the read: ``"xla"`` (any backend —
+    the gather folds into the dispatch), ``"pallas"`` (the
+    serving/paged_kernel.py TPU paged-attention kernel;
+    ``kernel_interpret=True`` emulates it on CPU for exactness tests),
+    or ``"auto"`` (the kernel on a TPU where the shapes pass its gate,
+    xla otherwise). ``paged_kernel.choose_paged_read`` is the one place
+    that answers, from this and from what the net's layers declare."""
 
     page_size: int = 8
     total_pages: Optional[int] = None
     total_tokens: Optional[int] = None
     total_bytes: Optional[int] = None
     prefix_cache: bool = True
-    direct: bool = True
     decode_impl: str = "auto"
     kernel_interpret: bool = False
     kv_dtype: str = "bf16"
@@ -119,15 +108,10 @@ class PagedKVConfig:
             raise ValueError(
                 f"decode_impl must be 'auto', 'xla' or 'pallas', got "
                 f"{self.decode_impl!r}")
-        if self.kv_dtype not in ("bf16", "int8", "auto"):
+        if self.kv_dtype not in KV_DTYPES:
             raise ValueError(
-                f"kv_dtype must be 'bf16', 'int8' or 'auto', got "
+                f"kv_dtype must be one of {KV_DTYPES}, got "
                 f"{self.kv_dtype!r}")
-        if self.kv_dtype != "bf16" and not self.direct:
-            raise ValueError(
-                "kv_dtype='int8'/'auto' needs direct=True: the legacy "
-                "gather/scatter round trip materializes the dense view "
-                "in the net dtype and has no quantized read path")
         given = [k for k in ("total_pages", "total_tokens",
                              "total_bytes")
                  if getattr(self, k) is not None]
@@ -248,7 +232,7 @@ class PagePool:
 
 
 # ---------------------------------------------------------------------------
-# the jitted pool <-> dense-view round trip
+# the jitted pool <-> dense-view moves of admission
 # ---------------------------------------------------------------------------
 
 def allocate_pools(total_pages: int, page_size: int, leaves, dtypes):

@@ -49,9 +49,8 @@ __all__ = ["KV_DTYPES", "dequantize", "kv_page_bytes", "pool_leaves",
 
 #: the PagedKVConfig.kv_dtype vocabulary: "bf16" = the unquantized
 #: pool in the net's native leaf dtype (the name of the default, not a
-#: cast); "int8" = this module; "auto" = the measured
-#: paged_decode_quant crossover entry decides (tuning/plan.py)
-KV_DTYPES = ("bf16", "int8", "auto")
+#: cast); "int8" = this module
+KV_DTYPES = ("bf16", "int8")
 
 
 def pow2ceil(x):

@@ -4,8 +4,7 @@ crossover").
 The round-3 lesson is the charter: a hand-fused Pallas kernel can LOSE
 to XLA's own fusion because the ``pallas_call`` boundary costs more than
 the saved traffic at some shapes (PERF.md round 3: the bn→act→conv plan
-measured 20-25% slower; round 10: the paged-decode kernel's win depends
-on context length). Static gates cannot know which side wins — only a
+measured 20-25% slower). Static gates cannot know which side wins — only a
 measurement on the target hardware can. This package makes that
 measurement a persistent, consultable artifact:
 
@@ -22,16 +21,15 @@ measurement a persistent, consultable artifact:
   of a bench-only env flag.
 - ``calibrate`` is the explicit measurement harness that fills the
   store from a run on the chip (per-shape paired timings of the fused
-  training kernels and the paged-decode read path).
+  training kernels).
 """
 
 from deeplearning4j_tpu.tuning.crossover import (  # noqa: F401
-    CROSSOVER_NAME, IMPL_REVS, KernelCrossoverStore, decode_fingerprint,
-    default_store, fingerprint, reset_default_store, stem_fingerprint,
+    CROSSOVER_NAME, IMPL_REVS, KernelCrossoverStore, default_store,
+    fingerprint, reset_default_store, stem_fingerprint,
     bottleneck_fingerprint, winner)
 from deeplearning4j_tpu.tuning.plan import (  # noqa: F401
-    EXECUTION_PLANS, apply_execution_plan, modeled_train_step_traffic,
-    resolve_decode_impl)
+    EXECUTION_PLANS, apply_execution_plan, modeled_train_step_traffic)
 from deeplearning4j_tpu.tuning.calibrate import (  # noqa: F401
     calibrate_training_kernels)
 
@@ -39,7 +37,6 @@ __all__ = [
     "CROSSOVER_NAME", "EXECUTION_PLANS", "IMPL_REVS",
     "KernelCrossoverStore", "apply_execution_plan",
     "bottleneck_fingerprint", "calibrate_training_kernels",
-    "decode_fingerprint", "default_store", "fingerprint",
-    "modeled_train_step_traffic", "reset_default_store",
-    "resolve_decode_impl", "stem_fingerprint", "winner",
+    "default_store", "fingerprint", "modeled_train_step_traffic",
+    "reset_default_store", "stem_fingerprint", "winner",
 ]

@@ -6,8 +6,8 @@ run on the chip.
 representative tensors at each shape, and times the fused kernel chain
 against its exact-semantics XLA fallback — fwd+bwd through jit, synced
 — recording each paired measurement into the store. One call on a TPU
-writes the entries every later ``execution_plan="auto"`` (and
-``decode_impl="auto"``) resolution reads.
+writes the entries every later ``execution_plan="auto"`` resolution
+reads.
 
 On a non-TPU backend the kernels run in interpret mode — the timings
 are meaningless as TPU predictions, which is exactly why store entries

@@ -49,8 +49,6 @@ CROSSOVER_VERSION = 1
 IMPL_REVS: Dict[str, int] = {
     "train_bottleneck": 1,   # nn/layers/bottleneck.py fused chain
     "train_stem": 1,         # nn/layers/stem.py space-to-depth stem
-    "paged_decode": 1,       # serving/paged_kernel.py vs XLA fallback
-    "paged_decode_quant": 1,  # int8 KV pool (serving/quant.py) vs bf16
 }
 
 AUTOTUNE_DECISIONS = "dl4jtpu_autotune_decisions_total"
@@ -111,25 +109,6 @@ def stem_fingerprint(h: int, w: int, c_in: int, c_out: int,
                        cin=int(c_in), cout=int(c_out))
 
 
-def decode_fingerprint(page_size: int, head_dim: int, n_kv_heads: int,
-                       cache_length: int, dtype: Any) -> str:
-    return fingerprint("paged_decode", dtype, ps=int(page_size),
-                       d=int(head_dim), hkv=int(n_kv_heads),
-                       L=int(cache_length))
-
-
-def quant_fingerprint(page_size: int, head_dim: int, n_kv_heads: int,
-                      cache_length: int, dtype: Any) -> str:
-    """int8-vs-bf16 KV-pool crossover key (``kv_dtype="auto"``):
-    kernel_ms records the int8 leg's timing, fallback_ms the bf16
-    leg's, so ``winner() == "kernel"`` means the quantized pool won on
-    this shape/hardware. dtype is the NET's native dtype (the bf16
-    side's storage — the int8 side is implied by the domain)."""
-    return fingerprint("paged_decode_quant", dtype, ps=int(page_size),
-                       d=int(head_dim), hkv=int(n_kv_heads),
-                       L=int(cache_length))
-
-
 def winner(entry: dict) -> str:
     """The ONE place the kernel-vs-fallback verdict rule lives:
     'kernel' iff the measured kernel time beats the fallback. choose(),
@@ -151,7 +130,7 @@ def _current_device_kind() -> str:
 class KernelCrossoverStore:
     """Load → consult → ratchet (the TPULINT_BASELINE lifecycle) for
     measured kernel-vs-fallback timings. Thread-safe: ``choose`` is on
-    serving/fit resolution paths."""
+    fit's resolution path."""
 
     def __init__(self, path: Optional[str] = None,
                  entries: Optional[Dict[str, dict]] = None):

@@ -33,8 +33,8 @@ import logging
 from typing import Optional
 
 from deeplearning4j_tpu.tuning.crossover import (
-    KernelCrossoverStore, bottleneck_fingerprint, decode_fingerprint,
-    default_store, quant_fingerprint, stem_fingerprint)
+    KernelCrossoverStore, bottleneck_fingerprint, default_store,
+    stem_fingerprint)
 
 log = logging.getLogger(__name__)
 
@@ -119,55 +119,6 @@ def apply_execution_plan(net, plan: Optional[str], *,
     net.set_fusion("bottleneck", stem=stem_on, only=only)
     return {"plan": plan, "level": "bottleneck", "blocks": len(chosen),
             "stem": stem_on, "keys": keys}
-
-
-def resolve_decode_impl(eligible: bool, key: str, *,
-                        store: Optional[KernelCrossoverStore] = None
-                        ) -> str:
-    """The serving twin: ``decode_impl="auto"`` resolution for the
-    paged-attention kernel. ``eligible`` is the STATIC gate the engine
-    already computes (``paged_attention_supported`` shapes + a TPU
-    backend) — eligibility says the kernel *can* run; the store says
-    whether it *should*. Uncalibrated behavior is unchanged: eligible →
-    the kernel (the PR 10 default), ineligible → the XLA fallback,
-    regardless of what any store says."""
-    if not eligible:
-        return "xla"
-    store = default_store() if store is None else store
-    return ("xla" if (store.choose(key, default="kernel")
-                      == "fallback") else "pallas")
-
-
-def decode_key_for_engine(page_size: int, head_dim: int,
-                          n_kv_heads: int, cache_length: int,
-                          dtype) -> str:
-    return decode_fingerprint(page_size, head_dim, n_kv_heads,
-                              cache_length, dtype)
-
-
-def resolve_kv_dtype(eligible: bool, key: str, *,
-                     store: Optional[KernelCrossoverStore] = None
-                     ) -> str:
-    """``kv_dtype="auto"`` resolution for the int8 KV page pool.
-    ``eligible`` is the engine's static gate (direct paged decode, no
-    recurrent h/c state) — eligibility says int8 *can* serve this net;
-    only a measurement says it *should*. Uncalibrated (or platform-
-    mismatched — the store's lookup already refuses a CPU-calibrated
-    entry on TPU) runs stay on bf16: quantization is an accuracy
-    trade, so unlike the decode-impl default it must be OPTED INTO by
-    a calibrated win ("kernel" = the int8 leg measured faster)."""
-    if not eligible:
-        return "bf16"
-    store = default_store() if store is None else store
-    return ("int8" if store.choose(key, default="fallback") == "kernel"
-            else "bf16")
-
-
-def quant_key_for_engine(page_size: int, head_dim: int,
-                         n_kv_heads: int, cache_length: int,
-                         dtype) -> str:
-    return quant_fingerprint(page_size, head_dim, n_kv_heads,
-                             cache_length, dtype)
 
 
 # ---------------------------------------------------------------------------

@@ -1,7 +1,7 @@
 """JAX persistent compilation cache placement.
 
 One helper, called from every process entry point that compiles
-(``chip_smoke.py``, ``bench.py``, ``bench_all.py``, the fleet worker, the
+(``chip_smoke.py``, ``benchmark/run.py``, the fleet worker, the
 ``parallel.main`` CLI) — never at package import, so a library user's own
 cache configuration is left alone.
 

@@ -852,7 +852,7 @@ def speculative_sample(net, draft, seed_ids, steps: int,
             proposals, q_dists = [], []
             if pending is not None:
                 out_d = draft.rnn_time_step(
-                    _one_hot(np.asarray([[pending]]), V))
+                    _encode(draft, np.asarray([[pending]]), V))
                 q_next = filter_probs(_probs(out_d)[0, :, -1],
                                       temperature, top_k, top_p)
             q = q_next
@@ -861,7 +861,7 @@ def speculative_sample(net, draft, seed_ids, steps: int,
                 proposals.append(d)
                 q_dists.append(q)
                 out_d = draft.rnn_time_step(
-                    _one_hot(np.asarray([[d]]), V))
+                    _encode(draft, np.asarray([[d]]), V))
                 q = filter_probs(_probs(out_d)[0, :, -1], temperature,
                                  top_k, top_p)
         # --- target scores pending + all proposals in ONE forward -----
@@ -877,7 +877,7 @@ def speculative_sample(net, draft, seed_ids, steps: int,
             p_next = None
             continue
         out_t = net.rnn_time_step(
-            _one_hot(np.asarray(chunk)[None, :], V))
+            _encode(net, np.asarray(chunk)[None, :], V))
         tp = _probs(out_t)[0]                      # [V, len(chunk)]
         off = len(chunk) - g                       # 1 when pending rode
         if pending is not None:
@@ -943,12 +943,15 @@ def _batch_prime(net, prompts, vocab_size: int):
             T = cap
     B, V = len(prompts), vocab_size
     Bb = _width_bucket(B)                        # bucketed batch rows
-    x = np.zeros((Bb, V, T), np.float32)
+    rows = np.zeros((Bb, T), np.int64)
     mask = np.zeros((Bb, T), np.float32)
     for b, p in enumerate(prompts):
         pad = T - len(p)
-        x[b, list(p), pad + np.arange(len(p))] = 1.0
+        rows[b, pad:] = p
         mask[b, pad:] = 1.0
+    x = _encode(net, rows, V)
+    if x.ndim == 3:
+        x *= mask[:, None, :]      # a one-hot pad column is all zero
     net.rnn_clear_previous_state()
     if hasattr(net, "layers"):                   # MultiLayerNetwork
         out = net.rnn_time_step(x, mask=mask)
@@ -1094,7 +1097,8 @@ def speculative_sample_batch(net, draft, prompts, steps: int,
                 for b in range(B):
                     if not done[b] and pending[b] is not None:
                         toks[b] = pending[b]
-                out_d = draft.rnn_time_step(_one_hot(toks[:, None], V))
+                out_d = draft.rnn_time_step(
+                    _encode(draft, toks[:, None], V))
                 draft_writes += 1
                 for b in range(B):
                     if not done[b]:
@@ -1112,7 +1116,8 @@ def speculative_sample_batch(net, draft, prompts, steps: int,
                     proposals[b].append(d)
                     q_dists[b].append(qs[b])
                     toks[b] = d
-                out_d = draft.rnn_time_step(_one_hot(toks[:, None], V))
+                out_d = draft.rnn_time_step(
+                    _encode(draft, toks[:, None], V))
                 draft_writes += 1
                 for b in range(B):
                     if not done[b]:
@@ -1136,7 +1141,7 @@ def speculative_sample_batch(net, draft, prompts, steps: int,
                 rewind_stream_state(
                     draft, np.full(Bb, draft_writes, np.int32))
             break
-        out_t = net.rnn_time_step(_one_hot(chunk, V))
+        out_t = net.rnn_time_step(_encode(net, chunk, V))
         tp_all = _probs(out_t)               # [Bb, V, chunk_len]
         rew = np.zeros(B, np.int32)          # target rollback per row
         draft_keep = np.zeros(B, np.int32)   # draft slots to keep per row
@@ -1294,7 +1299,7 @@ def beam_search_batch(net, prompts, steps: int, vocab_size: int,
                 tok[b * Wb:b * Wb + W] = all_tokens[b]
             if not np.array_equal(pp, np.arange(Bb * Wb)):
                 reorder_stream_state(net, pp)
-            out = net.rnn_time_step(_one_hot(tok[:, None], V))
+            out = net.rnn_time_step(_encode(net, tok[:, None], V))
     results = []
     for b in range(n):
         live = [(beams[b][w], float(scores[b][w])) for w in range(W)
@@ -1316,9 +1321,10 @@ def beam_search(net, seed_ids, steps: int, vocab_size: int,
     """Highest-log-prob continuation of `seed_ids` by beam search.
 
     `net` needs rnn_time_step / rnn_clear_previous_state (MultiLayerNetwork
-    or ComputationGraph, single one-hot [N,V,T] input). `max_length`
-    bounds seed+generation (None = unbounded; required finite for models
-    with positional tables or non-rolling caches). `prime_chunk_max`
+    or ComputationGraph, one input: the one-hot [N,V,T], or ids [N,T]
+    for a net that ``takes_ids``). `max_length` bounds seed+generation
+    (None = unbounded; required finite for models with positional
+    tables or non-rolling caches). `prime_chunk_max`
     overrides the process default (set_prime_chunk_max) per call;
     `prime_padded=True` primes the whole prompt in ONE left-padded
     dispatch (see _prime_padded).
@@ -1383,7 +1389,7 @@ def beam_search(net, seed_ids, steps: int, vocab_size: int,
                 reorder_stream_state(net, pp)   # inherit caches
             tok = np.zeros(Wb, np.int64)
             tok[:W] = tokens
-            out = net.rnn_time_step(_one_hot(tok[:, None], V))
+            out = net.rnn_time_step(_encode(net, tok[:, None], V))
     live = [(beams[w], float(scores[w])) for w in range(W)
             if alive[w] and np.isfinite(scores[w])]
     pool = finished if finished else live
@@ -1548,7 +1554,8 @@ def speculative_beam_search(net, draft, seed_ids, steps: int,
                 # and rewinds/reorders with it below
                 tok = np.zeros(Wb, np.int64)
                 tok[:W] = pending
-                out_d = draft.rnn_time_step(_one_hot(tok[:, None], V))
+                out_d = draft.rnn_time_step(
+                    _encode(draft, tok[:, None], V))
                 props = []
                 for _ in range(g):
                     nxt = _probs(out_d)[:W, :, -1].argmax(axis=1)
@@ -1556,7 +1563,7 @@ def speculative_beam_search(net, draft, seed_ids, steps: int,
                     tok = np.zeros(Wb, np.int64)
                     tok[:W] = nxt
                     out_d = draft.rnn_time_step(
-                        _one_hot(tok[:, None], V))
+                        _encode(draft, tok[:, None], V))
                 proposals = np.stack(props, axis=1)       # [W, g]
         if proposals is None:
             g = 0
@@ -1565,13 +1572,13 @@ def speculative_beam_search(net, draft, seed_ids, steps: int,
                 # pending front to stay position-synchronized
                 tok = np.zeros(Wb, np.int64)
                 tok[:W] = pending
-                draft.rnn_time_step(_one_hot(tok[:, None], V))
+                draft.rnn_time_step(_encode(draft, tok[:, None], V))
 
         chunk = np.zeros((Wb, 1 + g), np.int64)
         chunk[:W, 0] = pending
         if g:
             chunk[:W, 1:] = proposals
-        out = net.rnn_time_step(_one_hot(chunk, V))
+        out = net.rnn_time_step(_encode(net, chunk, V))
         tp = _probs(out)                                   # [Wb, V, 1+g]
 
         accepted = 0

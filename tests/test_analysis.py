@@ -428,8 +428,8 @@ class TestJitKeyDriftRule:
         assert "mutable global" in fs[0].message
 
     def test_negative_mutable_global_in_cache_key(self, tmp_path):
-        """The sanctioned pattern (the repo's _STREAM_CACHE_SHARDING /
-        _PAGED_DECODE_IMPL idiom): the read lands in the jit cache key,
+        """The sanctioned pattern (the repo's _STREAM_CACHE_SHARDING
+        idiom): the read lands in the jit cache key,
         so flipping the seam retraces instead of staling."""
         fs = _scan_snippet(tmp_path, """
             import jax

@@ -1,11 +1,10 @@
 """Kernel-crossover autotuning (tuning/): the measured per-shape store,
-execution-plan resolution on the fit loops, and the decode-side "auto"
-seam.
+and execution-plan resolution on the fit loops.
 
 Contracts pinned here (ISSUE 11 acceptance):
 - store lifecycle: calibrate → persist → a FRESH store (fresh process
-  stand-in) resolves "auto" (training plans AND decode_impl) from the
-  stored timings; no entry → current defaults; platform-mismatched
+  stand-in) resolves "auto" (training plans) from the stored
+  timings; no entry → current defaults; platform-mismatched
   entry → ignored with a warning;
 - ratchet/prune: repeated records merge (running mean), entries from a
   stale kernel revision are dropped on load;
@@ -41,7 +40,7 @@ from deeplearning4j_tpu.tuning import (
     IMPL_REVS, KernelCrossoverStore, apply_execution_plan,
     bottleneck_fingerprint, calibrate_training_kernels, default_store,
     fingerprint, modeled_train_step_traffic, reset_default_store,
-    resolve_decode_impl, stem_fingerprint)
+    stem_fingerprint)
 from deeplearning4j_tpu.tuning import crossover as crossover_mod
 from deeplearning4j_tpu.tuning.crossover import (
     AUTOTUNE_CALIBRATIONS, AUTOTUNE_DECISIONS)
@@ -181,11 +180,11 @@ class TestStore:
         assert s2.choose(key, default="fallback") == "fallback"
 
     def test_platform_mismatch_refused_with_warning(self, caplog):
-        key = fingerprint("paged_decode", "bfloat16", ps=16)
+        key = fingerprint("train_stem", "bfloat16", h=16)
         s = KernelCrossoverStore(entries={key: {
             "kernel_ms": 1.0, "fallback_ms": 2.0, "platform": "tpu",
             "device_kind": "TPU v5e",
-            "impl_rev": IMPL_REVS["paged_decode"], "samples": 1}})
+            "impl_rev": IMPL_REVS["train_stem"], "samples": 1}})
         with caplog.at_level(logging.WARNING):
             assert s.lookup(key) is None
             assert s.choose(key, default="fallback") == "fallback"
@@ -256,62 +255,6 @@ class TestCalibrateHarness:
             assert s2.lookup(_block_key(grp, "float32")) is not None
         for grp in sc.values():
             assert s2.lookup(_stem_key(grp, "float32")) is not None
-
-
-# ---------------------------------------------------------------------
-# decode-side "auto": eligibility is the gate, the store is the choice
-# ---------------------------------------------------------------------
-class TestDecodeAuto:
-    KEY = fingerprint("paged_decode", "float32", ps=8, d=8, hkv=2,
-                      L=32)
-
-    def _store(self, kernel_ms, fallback_ms):
-        s = KernelCrossoverStore(path="/nonexistent/none")
-        s.record(self.KEY, kernel_ms, fallback_ms)
-        return s
-
-    def test_ineligible_is_always_xla(self):
-        s = self._store(1.0, 99.0)          # kernel "wins" — irrelevant
-        assert resolve_decode_impl(False, self.KEY, store=s) == "xla"
-
-    def test_eligible_uncalibrated_keeps_kernel_default(self):
-        s = KernelCrossoverStore(path="/nonexistent/none")
-        assert resolve_decode_impl(True, self.KEY, store=s) == "pallas"
-
-    def test_eligible_calibrated_follows_the_store(self):
-        assert resolve_decode_impl(
-            True, self.KEY, store=self._store(1.0, 2.0)) == "pallas"
-        assert resolve_decode_impl(
-            True, self.KEY, store=self._store(5.0, 2.0)) == "xla"
-
-    def test_engine_auto_on_cpu_resolves_xla_regardless_of_store(self):
-        """Uncalibrated-behavior-unchanged pin: on a CPU backend the
-        eligibility gate fails, so "auto" is the XLA fallback even when
-        a (CPU-calibrated!) entry claims the kernel wins."""
-        from deeplearning4j_tpu.serving import (
-            GenerationEngine, PagedKVConfig)
-        from deeplearning4j_tpu.zoo import TextGenerationTransformer
-        net = TextGenerationTransformer(
-            vocab_size=12, embed_dim=16, n_heads=2, n_layers=1,
-            max_length=32, positional="rope").init()
-        eng = GenerationEngine(
-            net, 12, slots=2, queue_limit=4,
-            paging=PagedKVConfig(page_size=8))
-        try:
-            assert eng._decode_impl == "xla"
-            assert eng._decode_key.startswith("paged_decode|")
-            # now calibrate that exact key kernel-winning on THIS
-            # platform — eligibility still refuses the kernel on CPU
-            s = KernelCrossoverStore(path="/nonexistent/none")
-            s.record(eng._decode_key, 0.1, 9.0)
-            reset_default_store(s)
-            eng2 = GenerationEngine(
-                net, 12, slots=2, queue_limit=4,
-                paging=PagedKVConfig(page_size=8))
-            assert eng2._decode_impl == "xla"
-            eng2.shutdown()
-        finally:
-            eng.shutdown()
 
 
 # ---------------------------------------------------------------------

@@ -475,7 +475,7 @@ class TestInceptionV3Scale:
 class TestImportedGraphNhwc:
     def test_imported_graph_switches_layout(self):
         """Keras-imported graphs accept the internal NHWC mode with
-        identical outputs (bench_all.py relies on this)."""
+        identical outputs."""
         cfg, weights, _ = _iv3_config_and_weights(classes=7)
         with tempfile.TemporaryDirectory() as d:
             path = os.path.join(d, "iv3.h5")

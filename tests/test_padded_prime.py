@@ -168,7 +168,7 @@ class TestPaddedPrimeServing:
         fn = net._jit_cache.get(("rnn_step", True, False,
                                  net.conf.dtype,
                                  L._STREAM_CACHE_SHARDING,
-                                 L._PAGED_DECODE_IMPL))
+                                 net._paged_reads()))
         assert fn is not None, "rnn_step jit key drifted from the tests"
         return fn._cache_size()
 

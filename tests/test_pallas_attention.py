@@ -3,7 +3,7 @@ the ValidateCudnnLSTM.java role for the attention hot op).
 
 Runs the kernel in interpreter mode on CPU: same kernel code path the TPU
 compiles, exactness asserted against reference_attention and jax.grad
-through it. Real-chip perf lives in bench_all.py / PERF.md.
+through it. Real-chip perf lives in PERF.md.
 """
 
 import jax

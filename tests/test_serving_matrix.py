@@ -600,3 +600,96 @@ class TestSpeculativeBeam:
         with pytest.raises(TypeError, match="streaming net"):
             decoding.speculative_beam_search(
                 net, 42, [1, 2], steps=4, vocab_size=12)
+
+
+# ---------------------------------------------------------------------
+# one input seam: every decoder feeds a net the way the net asks
+# ---------------------------------------------------------------------
+def _decoder_pair(seed, embed=16, vocab=12, cache=64):
+    """One rope decoder twice over the same weights: fed token ids
+    (``SequenceEmbeddingLayer``: ids in, every position out) and fed
+    the one-hot block (a kernel-1 convolution holding the same table).
+    A lookup and a one-hot product give the same rows bit for bit."""
+    from deeplearning4j_tpu.nn.conf import NeuralNetConfiguration
+    from deeplearning4j_tpu.nn.conf.inputs import InputType
+    from deeplearning4j_tpu.nn.conf.layers import (
+        Convolution1DLayer, RnnOutputLayer, SelfAttentionLayer,
+        SequenceEmbeddingLayer)
+    from deeplearning4j_tpu.nn.graph import ComputationGraph
+
+    def build(embedding):
+        conf = (NeuralNetConfiguration.Builder().seed(seed)
+                .weight_init("xavier")
+                .graph_builder().add_inputs("in")
+                .set_input_types(InputType.recurrent(vocab, cache))
+                .add_layer("embed", embedding, "in")
+                .add_layer("attn", SelfAttentionLayer(
+                    n_out=embed, n_heads=2, causal=True,
+                    cache_length=cache, rope=True,
+                    activation="identity"), "embed")
+                .add_layer("out", RnnOutputLayer(
+                    n_out=vocab, loss="mcxent", activation="softmax"),
+                    "attn")
+                .set_outputs("out").build())
+        return ComputationGraph(conf).init()
+
+    ids = build(SequenceEmbeddingLayer(n_out=embed))
+    hot = build(Convolution1DLayer(
+        n_out=embed, kernel=1, convolution_mode="same",
+        activation="identity", has_bias=False))
+    hot.params = {**ids.params, "embed": {
+        "W": ids.params["embed"]["W"].T[:, :, None]}}   # [E, V, 1]
+    assert decoding.takes_ids(ids) and not decoding.takes_ids(hot)
+    return ids, hot
+
+
+def _greedy(net, prompt, steps, V=12):
+    return decoding.sample_stream(net, prompt, steps, V, top_k=1,
+                                  rng=np.random.default_rng(0))
+
+
+class TestDecodersFeedIds:
+    """The five decoders that built their one-hot themselves, on a net
+    that takes ids: each holds the equality it holds on one-hot nets
+    (greedy speculation == plain greedy; beam width 1 == greedy), here
+    against the one-hot twin of the same weights."""
+
+    @pytest.fixture(scope="class")
+    def nets(self):
+        return _decoder_pair(seed=7), _decoder_pair(seed=8, embed=8)
+
+    @pytest.mark.parametrize("decoder", [
+        "speculative_sample", "speculative_sample_batch", "beam_search",
+        "beam_search_batch", "speculative_beam_search"])
+    def test_decoder_on_an_ids_net_equals_its_one_hot_twin(self, nets,
+                                                           decoder):
+        (ids, hot), (draft_ids, draft_hot) = nets
+        V, steps = 12, 6
+        want = [_greedy(hot, p, steps) for p in PROMPTS]
+        if decoder == "speculative_sample":
+            got = [decoding.speculative_sample(
+                ids, draft_ids, p, steps, V, gamma=3, top_k=1,
+                rng=np.random.default_rng(0)) for p in PROMPTS]
+        elif decoder == "speculative_sample_batch":
+            got = decoding.speculative_sample_batch(
+                ids, draft_ids, PROMPTS, steps, V, gamma=3, top_k=1)
+        elif decoder == "beam_search":
+            got = [decoding.beam_search(ids, p, steps, V,
+                                        beam_width=1)[0]
+                   for p in PROMPTS]
+        elif decoder == "beam_search_batch":
+            got = [seq for seq, _ in decoding.beam_search_batch(
+                ids, PROMPTS, steps, V, beam_width=1)]
+        else:
+            got = [decoding.speculative_beam_search(
+                ids, draft_ids, p, steps, V, beam_width=1,
+                gamma=3)[0] for p in PROMPTS]
+            # and at a real width, the twin's beam search exactly
+            wide = decoding.speculative_beam_search(
+                ids, draft_ids, PROMPTS[0], steps, V, beam_width=3,
+                gamma=2)
+            ref = decoding.beam_search(hot, PROMPTS[0], steps, V,
+                                       beam_width=3)
+            assert wide[0] == ref[0]
+            assert wide[1] == pytest.approx(ref[1], abs=1e-5)
+        assert got == want

@@ -1,12 +1,12 @@
 """Direct paged decode (serving/paged_kernel.py + the engine fast
-path): the paged-attention kernel vs the dense-gather reference, engine
-bit-exactness vs one-shot / slot arena / legacy round trip on BOTH
-direct impls (XLA fallback and interpret-mode Pallas kernel) — greedy
-and sampled, prefix cache with shared blocks, in-engine speculation —
-plus the cached-table invariants, the KV-traffic telemetry (the
-round-trip elimination as a number), supervisor recovery re-entering
-the direct path, and the zero-retraces-after-warmup guard with the
-kernel path enabled."""
+path): the paged-attention kernel vs the dense-gather reference, the
+chooser's truth table, engine bit-exactness vs one-shot / slot arena on
+BOTH reads (XLA fallback and interpret-mode Pallas kernel) — greedy and
+sampled, prefix cache with shared blocks, in-engine speculation — plus
+the cached-table invariants, the KV-traffic telemetry, supervisor
+recovery re-entering the direct path, two engines of different reads in
+one process, and the zero-retraces-after-warmup guard with the kernel
+path enabled."""
 
 import jax.numpy as jnp
 import numpy as np
@@ -21,8 +21,8 @@ from deeplearning4j_tpu.serving import (
 from deeplearning4j_tpu.serving.health import (
     SERVING_DISPATCH_LATENCY, SERVING_KV_BYTES_MOVED)
 from deeplearning4j_tpu.serving.paged_kernel import (
-    pages_per_step, paged_attention, paged_attention_supported,
-    paged_ref_attention)
+    choose_paged_read, pages_per_step, paged_attention,
+    paged_attention_supported, paged_ref_attention)
 from deeplearning4j_tpu.util.decoding import prompt_lookup_proposer
 from deeplearning4j_tpu.zoo import TextGenerationTransformer
 
@@ -302,6 +302,71 @@ class TestGroupedWalk:
 
 
 # ---------------------------------------------------------------------
+# the chooser: which code reads the pool, from what can be observed
+# ---------------------------------------------------------------------
+PLAIN = ("kv_k", "kv_v")
+LATENT = ("kv_c", "kv_r", "kv_i")
+TILED = (64, 2, 16, 128)        # passes the kernel's gate
+NARROW = (64, 2, 4, 8)          # head dim off the 128 lanes
+BIG_INT8 = (1025, 2, 16, 128)   # int8 scale sidecars outgrow SMEM
+
+
+class TestChoosePagedRead:
+    @pytest.mark.parametrize(
+        "backend, shapes, leaves, kv_dtype, asked, interpret, want", [
+            # auto: the kernel iff a TPU and every pool passes the gate
+            ("tpu", [TILED], PLAIN, "bf16", "auto", False,
+             ("pallas", False)),
+            ("tpu", [NARROW], PLAIN, "bf16", "auto", False,
+             ("xla", False)),
+            ("tpu", [TILED, NARROW], PLAIN, "bf16", "auto", False,
+             ("xla", False)),
+            ("cpu", [TILED], PLAIN, "bf16", "auto", False,
+             ("xla", False)),
+            ("tpu", [TILED], PLAIN, "int8", "auto", False,
+             ("pallas", False)),
+            ("tpu", [BIG_INT8], PLAIN, "int8", "auto", False,
+             ("xla", False)),
+            ("tpu", [BIG_INT8], PLAIN, "bf16", "auto", False,
+             ("pallas", False)),
+            # an explicit value is taken, whatever the gate says
+            ("cpu", [NARROW], PLAIN, "bf16", "pallas", True,
+             ("pallas", True)),
+            ("tpu", [NARROW], PLAIN, "bf16", "pallas", False,
+             ("pallas", False)),
+            ("tpu", [TILED], PLAIN, "bf16", "xla", False,
+             ("xla", False)),
+            # interpret is the kernel's: it says nothing of the gather
+            ("cpu", [TILED], PLAIN, "bf16", "xla", True,
+             ("xla", False)),
+            # leaves no kernel reads: the layers' own paged form
+            ("tpu", (), LATENT, "bf16", "auto", False, ("xla", False)),
+            ("tpu", (), LATENT + PLAIN, "bf16", "xla", True,
+             ("xla", False)),
+        ])
+    def test_truth_table(self, backend, shapes, leaves, kv_dtype, asked,
+                         interpret, want):
+        assert choose_paged_read(
+            leaves, shapes, kv_dtype=kv_dtype, decode_impl=asked,
+            kernel_interpret=interpret, backend=backend) == want
+
+    @pytest.mark.parametrize("kv_dtype, asked", [("bf16", "pallas"),
+                                                 ("int8", "auto")])
+    def test_kernel_or_int8_asked_of_other_leaves_is_an_error(
+            self, kv_dtype, asked):
+        with pytest.raises(ValueError, match="keys and values only"):
+            choose_paged_read(LATENT, (), kv_dtype=kv_dtype,
+                              decode_impl=asked, kernel_interpret=False,
+                              backend="tpu")
+
+    def test_auto_on_a_cpu_engine_is_xla(self, rope_net):
+        eng = GenerationEngine(rope_net, V, slots=2,
+                               paging=PagedKVConfig(page_size=8))
+        assert eng.health()["kv_traffic"]["decode_path"] == "direct-xla"
+        assert set(rope_net._paged_reads()) == {("xla", False)}
+
+
+# ---------------------------------------------------------------------
 # engine bit-exactness with the direct path on (both impls)
 # ---------------------------------------------------------------------
 class TestDirectParity:
@@ -311,7 +376,7 @@ class TestDirectParity:
         eng, got = run_trace(
             rope_net, PROMPTS, steps=7, slots=2,
             submit_kw=dict(top_k=1),
-            paging=PagedKVConfig(page_size=4, direct=True, **impl))
+            paging=PagedKVConfig(page_size=4, **impl))
         for i, p in enumerate(PROMPTS):
             want = rope_model.sample_stream(
                 rope_net, p, steps=7, top_k=1,
@@ -326,7 +391,7 @@ class TestDirectParity:
         row here, all in one step); 0 off the kernel path."""
         eng = GenerationEngine(
             rope_net, V, slots=2,
-            paging=PagedKVConfig(page_size=4, direct=True, **impl))
+            paging=PagedKVConfig(page_size=4, **impl))
         assert pages_per_step((eng.page_pool.total_pages, 2, 4, 8),
                               32 // 4, 4) == 8
         assert eng.health()["kv_traffic"]["kernel_pages_per_step"] == \
@@ -341,7 +406,7 @@ class TestDirectParity:
                 dict(temperature=0.9)]
         eng = GenerationEngine(
             rope_net, V, slots=4,
-            paging=PagedKVConfig(page_size=4, direct=True, **impl))
+            paging=PagedKVConfig(page_size=4, **impl))
         hs = [eng.submit([1 + i, 2, 3], steps=6,
                          rng=np.random.default_rng(10 + i), **c)
               for i, c in enumerate(cfgs)]
@@ -351,22 +416,6 @@ class TestDirectParity:
                 rope_net, [1 + i, 2, 3], steps=6,
                 rng=np.random.default_rng(10 + i), **c)
             assert got[i] == want, c
-
-    @pytest.mark.parametrize("impl", DIRECT_IMPLS)
-    def test_direct_equals_legacy_roundtrip_bitwise(self, rope_net,
-                                                    impl):
-        """The A/B pair the bench leg also runs: same sampled staggered
-        trace through the legacy gather/scatter round trip and the
-        direct path — identical ids."""
-        kw = dict(steps=6, stagger=True, slots=2)
-        _, legacy = run_trace(
-            rope_net, PROMPTS,
-            paging=PagedKVConfig(page_size=4, direct=False), **kw)
-        _, direct = run_trace(
-            rope_net, PROMPTS,
-            paging=PagedKVConfig(page_size=4, direct=True, **impl),
-            **kw)
-        assert direct == legacy
 
     @pytest.mark.parametrize("impl", DIRECT_IMPLS)
     def test_prefix_cache_shared_blocks(self, rope_model, rope_net,
@@ -381,7 +430,7 @@ class TestDirectParity:
         eng, got = run_trace(
             rope_net, prompts, steps=6, slots=2,
             submit_kw=dict(top_k=1),
-            paging=PagedKVConfig(page_size=4, direct=True, **impl))
+            paging=PagedKVConfig(page_size=4, **impl))
         assert eng.prefix_cache.hits > 0
         for i, p in enumerate(prompts):
             want = rope_model.sample_stream(
@@ -400,7 +449,7 @@ class TestDirectParity:
         eng, got = run_trace(
             rope_net, prompts, steps=8, slots=3,
             submit_kw=dict(top_k=1),
-            paging=PagedKVConfig(page_size=4, direct=True, **impl),
+            paging=PagedKVConfig(page_size=4, **impl),
             speculation=SpeculationConfig(
                 draft=prompt_lookup_proposer(2), gamma=2))
         for i, p in enumerate(prompts):
@@ -449,23 +498,9 @@ class TestTableCache:
         drain(eng, [h])                  # retirement invalidates
         assert eng._tables_cache is None
 
-    def test_legacy_roundtrip_reuses_device_table(self, rope_net):
-        eng = GenerationEngine(
-            rope_net, V, slots=2,
-            paging=PagedKVConfig(page_size=4, direct=False))
-        h = eng.submit([1, 2, 3], steps=6, top_k=1,
-                       rng=np.random.default_rng(0))
-        eng.step()
-        dev = eng._table_dev_cache
-        assert dev is not None
-        eng.step()
-        assert eng._table_dev_cache is dev
-        drain(eng, [h])
-        assert eng._table_dev_cache is None
-
 
 # ---------------------------------------------------------------------
-# KV-traffic telemetry: the round-trip elimination as a number
+# KV-traffic telemetry: what a decode step moves, as a number
 # ---------------------------------------------------------------------
 class TestKVTraffic:
     def _steady_step_bytes(self, net, paging, slots=2):
@@ -482,25 +517,23 @@ class TestKVTraffic:
         return per_step, eng
 
     def test_direct_drops_per_step_bytes(self, rope_net):
-        """The acceptance criterion: the full-arena round trip is gone
-        from the steady-state step — per-step KV bytes drop from
-        O(2·S·L) to O(active read + one-token write)."""
-        legacy, el = self._steady_step_bytes(
-            rope_net, PagedKVConfig(page_size=4, direct=False))
+        """No full-arena round trip in the steady-state step: per-step
+        KV bytes stand at O(active read + one-token write), under the
+        2·S·L·tok_bytes a gather → dispatch → scatter would move."""
         xla, ex = self._steady_step_bytes(
             rope_net, PagedKVConfig(page_size=4, decode_impl="xla"))
         kern, ek = self._steady_step_bytes(
             rope_net, PagedKVConfig(page_size=4, decode_impl="pallas",
                                     kernel_interpret=True))
         # tok_bytes: per-position KV bytes summed over leaves
-        tok = el._tok_bytes
-        S, L = el.slots, el._L
-        assert legacy == 2 * S * L * tok
+        tok = ex._tok_bytes
+        S, L = ex.slots, ex._L
+        roundtrip = 2 * S * L * tok
         assert xla == S * L * tok + S * 1 * tok
         # one active row at position 4 (3 prompt + 1 drawn): one live
         # page-rounded read + the all-rows one-token append
         assert kern == 8 * tok + S * 1 * tok
-        assert kern < xla < legacy
+        assert kern < xla < roundtrip
 
     def test_counter_and_histogram_registered(self, rope_net):
         reg = MetricsRegistry()
@@ -541,8 +574,7 @@ class TestDirectRecovery:
                                                         impl):
         shared = [3, 1, 2, 0] * 2
         prompts = [shared + [5], shared + [7, 8], [9, 9]]
-        cfg = dict(paging=PagedKVConfig(page_size=4, direct=True,
-                                        **impl))
+        cfg = dict(paging=PagedKVConfig(page_size=4, **impl))
         base = GenerationEngine(rope_net, V, slots=2, **cfg)
         hs = [base.submit(p, steps=5, top_k=1,
                           rng=np.random.default_rng(i))
@@ -583,7 +615,7 @@ class TestNoRetraceDirectAfterWarmup:
         net = model.init()
         eng = GenerationEngine(
             net, V, slots=4,
-            paging=PagedKVConfig(page_size=8, direct=True, **impl),
+            paging=PagedKVConfig(page_size=8, **impl),
             speculation=SpeculationConfig(
                 draft=prompt_lookup_proposer(2), gamma=3))
         eng.warmup(max_prompt_len=16)
@@ -668,29 +700,48 @@ class TestReviewRegressions:
         eng3.run_until_idle()
         assert h.result(timeout=0) == base.result(timeout=0)
 
-    def test_health_reports_live_impl_after_global_flip(self, rope_net):
-        """The paged-decode impl is process-wide: a later engine's
-        construction flips it for everyone, and an earlier engine's
-        health()/KV accounting must report the LIVE path its next
-        dispatch actually runs, not its construction-time snapshot."""
-        a = GenerationEngine(rope_net, V, slots=2,
-                             paging=PagedKVConfig(page_size=4,
-                                                  decode_impl="xla"))
-        assert a.health()["kv_traffic"]["decode_path"] == "direct-xla"
-        b = GenerationEngine(
-            rope_net, V, slots=2,
-            paging=PagedKVConfig(page_size=4, decode_impl="pallas",
-                                 kernel_interpret=True))
-        # the global flipped: A's next dispatch runs the kernel path,
-        # and its telemetry follows
-        assert a.health()["kv_traffic"]["decode_path"] == \
-            "direct-pallas"
-        assert b.health()["kv_traffic"]["decode_path"] == \
-            "direct-pallas"
-        # restore the default for later tests in this process
-        GenerationEngine(rope_net, V, slots=2,
-                         paging=PagedKVConfig(page_size=4,
-                                              decode_impl="xla"))
+    @pytest.mark.parametrize("first", ["pallas", "xla"])
+    def test_two_engines_keep_their_own_read(self, first):
+        """The read belongs to the net an engine serves, not to the
+        process: a kernel-path engine and an XLA-path engine on two
+        nets, constructed in either order, each report their own
+        ``decode_path``, and the one that was warm compiles nothing on
+        the dispatches it runs after the other was built and served
+        (a process-wide switch retraced it onto the other's read)."""
+        monitoring.ensure_started()
+        impls = {"pallas": dict(decode_impl="pallas",
+                                kernel_interpret=True),
+                 "xla": dict(decode_impl="xla")}
+        second = "xla" if first == "pallas" else "pallas"
+
+        def build(impl):
+            net = TextGenerationTransformer(
+                vocab_size=V, embed_dim=16, n_heads=2, n_layers=1,
+                max_length=32, positional="rope").init()
+            eng = GenerationEngine(
+                net, V, slots=2,
+                paging=PagedKVConfig(page_size=4, **impls[impl]))
+            eng.warmup(max_prompt_len=8)
+            return eng
+
+        def serve(eng, seed):
+            h = eng.submit([1, 2, 3], steps=5, top_k=1,
+                           rng=np.random.default_rng(seed))
+            return drain(eng, [h])[0]
+
+        a = build(first)
+        want = serve(a, 0)
+        b = build(second)
+        assert serve(b, 0) == want        # both reads, same tokens
+        warm = _compile_total()
+        assert serve(a, 1) == serve(b, 1)
+        assert _compile_total() == warm, (
+            "an engine's read changed under it: its net retraced")
+        for eng, impl in ((a, first), (b, second)):
+            assert eng.health()["kv_traffic"]["decode_path"] == \
+                "direct-" + impl
+            assert set(eng.net._paged_reads()) == \
+                {(impl, impl == "pallas")}
 
 
 # ---------------------------------------------------------------------
@@ -700,10 +751,3 @@ class TestConfig:
     def test_bad_decode_impl_rejected(self):
         with pytest.raises(ValueError, match="decode_impl"):
             PagedKVConfig(decode_impl="cuda")
-
-    def test_health_reports_roundtrip_when_direct_off(self, rope_net):
-        eng = GenerationEngine(
-            rope_net, V, slots=2,
-            paging=PagedKVConfig(page_size=4, direct=False))
-        assert eng.health()["kv_traffic"]["decode_path"] == "roundtrip"
-        assert eng.health()["kv_traffic"]["kernel_pages_per_step"] == 0

@@ -1,12 +1,11 @@
 """int8 KV page pool (serving/quant.py + PagedKVConfig(kv_dtype="int8")):
 quantization primitives and their exactness contracts, the accuracy
-ENVELOPE vs bf16 (greedy-divergence-step + attention-output MAE — pinned
+ENVELOPE vs bf16 (greedy-divergence margin + attention-output MAE — pinned
 bounds, never bit-parity), bitwise pins where int8 must be exact
 against ITSELF (prefix hit == miss, supervisor rebuild, fleet
 migration, speculation on/off, run-to-run), the capacity-doubling
 admission math under a byte budget, the exact per-dispatch byte model
-on both decode impls (int8 <= 0.55x bf16), kv_dtype="auto" resolution
-through the measured crossover store, chaos page exhaustion on a
+on both decode impls (int8 <= 0.55x bf16), chaos page exhaustion on a
 quantized pool, and the zero-retrace guard with int8 + prefix cache +
 speculation stacked."""
 
@@ -21,12 +20,10 @@ from deeplearning4j_tpu.serving import (
     EngineSupervisor, GenerationEngine, PagedKVConfig, SpeculationConfig)
 from deeplearning4j_tpu.serving.paged_kernel import (
     paged_attention, paged_attention_supported, paged_ref_attention)
+from deeplearning4j_tpu.serving import quant
 from deeplearning4j_tpu.serving.quant import (
     KV_DTYPES, dequantize, kv_page_bytes, pool_leaves, pow2ceil,
     quantize)
-from deeplearning4j_tpu.tuning.crossover import (
-    KernelCrossoverStore, quant_fingerprint, reset_default_store)
-from deeplearning4j_tpu.tuning.plan import resolve_kv_dtype
 from deeplearning4j_tpu.util.decoding import prompt_lookup_proposer
 from deeplearning4j_tpu.zoo import TextGenerationTransformer
 
@@ -136,7 +133,7 @@ class TestQuantPrimitives:
                    for s in scales)
 
     def test_kv_dtypes_vocabulary(self):
-        assert KV_DTYPES == ("bf16", "int8", "auto")
+        assert KV_DTYPES == ("bf16", "int8")
 
 
 # ---------------------------------------------------------------------
@@ -161,6 +158,11 @@ def _quantized_case(seed=0, S=3, hkv=2, reps=2, qw=1, d=8, ps=4, nb=5):
     return q, kp, vp, kq, vq, ks, vs, table, lengths
 
 
+#: the error pinned for the int8 read, on the attention output of unit
+#: normal queries, keys and values: on average, and at worst
+READ_MAE, READ_MAX_ERR = 0.02, 0.1
+
+
 class TestQuantReaders:
     def test_ref_attention_mae_envelope(self):
         """The accuracy contract is an ENVELOPE: int8 pools through the
@@ -175,8 +177,8 @@ class TestQuantReaders:
         quant = paged_ref_attention(q, kd, vd, table, lengths,
                                     query_width=1)
         diff = np.abs(np.asarray(exact) - np.asarray(quant))
-        assert diff.mean() <= 0.02
-        assert diff.max() <= 0.1
+        assert diff.mean() <= READ_MAE
+        assert diff.max() <= READ_MAX_ERR
         assert diff.max() > 0          # a real quantizer, not a no-op
 
     @pytest.mark.parametrize("qw", [1, 3])
@@ -239,21 +241,62 @@ class TestInt8Engine:
                                  **impl))
         return got
 
-    def test_greedy_divergence_envelope(self, rope_net):
-        """The pinned accuracy envelope: greedy int8 streams track the
-        bf16 streams for at least the first generated tokens, and most
-        prompts never diverge at all on this model. NOT a bit-parity
-        claim — the pins are the envelope."""
-        g16 = self._greedy(rope_net, "bf16")
-        g8 = self._greedy(rope_net, "int8")
-        divergence = []
+    def _divergence_margins(self, net):
+        """Where a prompt's greedy int8 stream parts from its bf16
+        stream, how far below its best the UNQUANTIZED distribution at
+        that step holds the token int8 chose: one probability gap per
+        prompt that parts (the later steps follow another context and
+        say nothing)."""
+        g16 = self._greedy(net, "bf16")
+        g8 = self._greedy(net, "int8")
+        margins = []
         for a, b, p in zip(g16, g8, PROMPTS):
-            gen_a, gen_b = a[len(p):], b[len(p):]
-            divergence.append(next(
-                (i for i, (x, y) in enumerate(zip(gen_a, gen_b))
-                 if x != y), len(gen_a)))
-        assert min(divergence) >= 2, divergence
-        assert sum(d == 10 for d in divergence) >= 2, divergence
+            step = next((i for i in range(len(p), len(a))
+                         if a[i] != b[i]), None)
+            if step is None:
+                continue
+            x = np.zeros((1, V, step), np.float32)
+            x[0, a[:step], np.arange(step)] = 1.0
+            net.rnn_clear_previous_state()
+            probs = np.asarray(net.rnn_time_step(x))[0, :, -1]
+            net.rnn_clear_previous_state()
+            assert a[step] == int(np.argmax(probs))
+            margins.append(float(probs[a[step]] - probs[b[step]]))
+        return margins
+
+    @staticmethod
+    def _within_read_error(margins):
+        return (max(margins, default=0.0) <= READ_MAX_ERR
+                and float(np.mean(margins or [0.0])) <= READ_MAE)
+
+    def test_greedy_divergence_envelope(self, rope_net):
+        """The pinned accuracy envelope, stated on the margin: this
+        random 16-wide net's logits are nearly flat, so WHERE a greedy
+        int8 stream parts from the bf16 stream is chance (two of six
+        part at the first token). What is pinned is what int8 may pick
+        there: a token the unquantized distribution holds within the
+        int8 read's own pinned error of its best. NOT a bit-parity
+        claim — the pins are the envelope."""
+        margins = self._divergence_margins(rope_net)
+        assert margins, "int8 never parted: a no-op quantizer?"
+        assert self._within_read_error(margins), margins
+
+    def test_greedy_divergence_envelope_catches_a_wrong_scale(
+            self, rope_model, monkeypatch):
+        """The control that makes the envelope a test: the same run
+        with every scale the appends write stored DOUBLED (pages read
+        back at twice their value) parts for tokens far below the
+        unquantized best."""
+        write = quant.quantize_chunk
+
+        def doubled(xt, scales, *args, **kw):
+            xq, new = write(xt, scales, *args, **kw)
+            return xq, jnp.where(new != scales, 2.0 * new, new)
+
+        monkeypatch.setattr(quant, "quantize_chunk", doubled)
+        # a net of its own: its programs trace the patched write
+        margins = self._divergence_margins(rope_model.init())
+        assert not self._within_read_error(margins), margins
 
     @pytest.mark.parametrize("impl", DIRECT_IMPLS)
     def test_deterministic_run_to_run(self, rope_net, impl):
@@ -331,13 +374,10 @@ class TestInt8Engine:
                        rng=np.random.default_rng(0))
         assert drain(eng, [h])[0]
 
-    def test_int8_requires_direct(self):
-        with pytest.raises(ValueError, match="direct"):
-            PagedKVConfig(kv_dtype="int8", direct=False)
-
-    def test_bad_kv_dtype_rejected(self):
+    @pytest.mark.parametrize("kv_dtype", ["fp8", "auto"])
+    def test_bad_kv_dtype_rejected(self, kv_dtype):
         with pytest.raises(ValueError, match="kv_dtype"):
-            PagedKVConfig(kv_dtype="fp8")
+            PagedKVConfig(kv_dtype=kv_dtype)
 
 
 # ---------------------------------------------------------------------
@@ -507,74 +547,6 @@ class TestInt8Traffic:
         eng16 = GenerationEngine(rope_net, V, slots=2,
                                  paging=PagedKVConfig(page_size=4))
         assert eng16.health()["kv_traffic"]["kv_dtype"] == "bf16"
-
-
-# ---------------------------------------------------------------------
-# kv_dtype="auto": opted into by a calibrated measurement
-# ---------------------------------------------------------------------
-class TestAutoResolution:
-    KEY_KW = dict(page_size=4, head_dim=8, n_kv_heads=2,
-                  cache_length=32)
-
-    def _store(self, entries=None):
-        return KernelCrossoverStore(path="/nonexistent/none",
-                                    entries=entries or {})
-
-    def test_uncalibrated_resolves_bf16(self, rope_net):
-        reset_default_store(self._store())
-        try:
-            eng = GenerationEngine(
-                rope_net, V, slots=2,
-                paging=PagedKVConfig(page_size=4, kv_dtype="auto"))
-            assert eng._kv_dtype == "bf16"
-            assert eng._quant_key == quant_fingerprint(
-                dtype="float32", **self.KEY_KW)
-        finally:
-            reset_default_store(None)
-
-    def test_calibrated_win_resolves_int8(self, rope_net):
-        key = quant_fingerprint(dtype="float32", **self.KEY_KW)
-        s = self._store()
-        s.record(key, 1.0, 2.5)       # int8 leg measured 2.5x faster
-        reset_default_store(s)
-        try:
-            eng = GenerationEngine(
-                rope_net, V, slots=2,
-                paging=PagedKVConfig(page_size=4, kv_dtype="auto"))
-            assert eng._kv_dtype == "int8"
-            # and it actually serves quantized
-            h = eng.submit([1, 2, 3], steps=3, top_k=1,
-                           rng=np.random.default_rng(0))
-            assert drain(eng, [h])[0]
-            assert eng.health()["kv_traffic"]["kv_dtype"] == "int8"
-        finally:
-            reset_default_store(None)
-
-    def test_platform_mismatch_refused(self, rope_net):
-        """A TPU-calibrated win must not turn int8 on for CPU runs —
-        the store's platform guard applies to quant entries too."""
-        key = quant_fingerprint(dtype="float32", **self.KEY_KW)
-        s = self._store(entries={key: {
-            "kernel_ms": 1.0, "fallback_ms": 2.5, "platform": "tpu",
-            "device_kind": "TPU v4", "impl_rev": 1, "samples": 1}})
-        reset_default_store(s)
-        try:
-            eng = GenerationEngine(
-                rope_net, V, slots=2,
-                paging=PagedKVConfig(page_size=4, kv_dtype="auto"))
-            assert eng._kv_dtype == "bf16"
-        finally:
-            reset_default_store(None)
-
-    def test_resolver_ineligible_is_bf16(self):
-        assert resolve_kv_dtype(False, "paged_decode_quant|x|f32",
-                                store=self._store()) == "bf16"
-        s = self._store()
-        s.record("paged_decode_quant|x|f32", 1.0, 2.0)
-        assert resolve_kv_dtype(True, "paged_decode_quant|x|f32",
-                                store=s) == "int8"
-        assert resolve_kv_dtype(True, "paged_decode_quant|x|f32",
-                                store=self._store()) == "bf16"
 
 
 # ---------------------------------------------------------------------

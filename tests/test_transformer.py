@@ -910,7 +910,7 @@ class TestBucketedDecoding:
         from deeplearning4j_tpu.nn.conf import layers as L
         fn = net._jit_cache.get(
             ("rnn_step", False, False, net.conf.dtype,
-             L._STREAM_CACHE_SHARDING, L._PAGED_DECODE_IMPL))
+             L._STREAM_CACHE_SHARDING, net._paged_reads()))
         assert fn is not None, "rnn_step jit key drifted from the tests"
         return fn._cache_size()
 
