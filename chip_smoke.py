@@ -327,7 +327,8 @@ def build_transformer(sz: Sizes):
 
 
 def one_hot_tokens(ids: np.ndarray, vocab: int) -> np.ndarray:
-    """[B, T] token ids -> the zoo transformer's [B, V, T] input."""
+    """[B, T] token ids -> the float [B, V, T] one-hot the zoo transformer
+    trains on and still streams (the engine sends it the ids)."""
     b, t = ids.shape
     x = np.zeros((b, vocab, t), np.float32)
     x[np.arange(b)[:, None], ids, np.arange(t)[None, :]] = 1.0

@@ -2296,6 +2296,45 @@ class SequenceEmbeddingLayer(FeedForwardLayerConf):
 
 @register_layer
 @dataclass
+class TokenProjectionLayer(Convolution1DLayer):
+    """The token projection of a sequence model whose leaves are a
+    kernel-1 ``Convolution1DLayer``'s — ``W`` [n_out, V, 1], ``b``
+    [n_out], that layer's ``init`` and ``output_type`` — and whose
+    ``apply`` looks at its input: integer ids ``[N, T]`` give column
+    ``id`` of ``W`` plus ``b`` as ``[N, n_out, T]``, which is what the
+    convolution makes of their one-hot (one column times ``W`` is that
+    column; in the dtype the convolution hands on); a float ``[N, V, T]``
+    (one-hot training data, soft distributions) goes through the
+    convolution. A net whose input feeds this layer ``takes_ids``, as with
+    ``SequenceEmbeddingLayer``; a saved configuration that names
+    ``Convolution1DLayer`` here stays the one-hot net it was."""
+
+    kernel: int = 1
+    convolution_mode: str = "same"
+
+    takes_ids = True
+
+    def apply(self, params, x, state, *, train=False, rng=None, mask=None):
+        if x.ndim == 2:
+            if not jnp.issubdtype(x.dtype, jnp.integer):
+                raise ValueError(
+                    f"TokenProjectionLayer takes integer ids [N, T] or a "
+                    f"float [N, V, T], got {x.dtype} [N, T]")
+            # the one-hot the host used to build and upload, made inside
+            # the program in the leaves' dtype: XLA feeds the comparison
+            # to the product as its operand. A column gather of W
+            # [E, V, 1] copies all of W transposed first, every dispatch:
+            # 0.95 ms against 0.42 for 32 decode rows on a v5e (PERF.md,
+            # PR 32), so the product is the one form
+            with jax.named_scope("embed.ids"):
+                x = jax.nn.one_hot(x, params["W"].shape[1], axis=1,
+                                   dtype=params["W"].dtype)
+        return super().apply(params, x, state, train=train, rng=rng,
+                             mask=mask)
+
+
+@register_layer
+@dataclass
 class LastStepOutputLayer(RnnOutputLayer):
     """``RnnOutputLayer`` whose STREAMING form answers for the chunk's
     last position only: ``[N, V]`` out of ``rnn_time_step``, the

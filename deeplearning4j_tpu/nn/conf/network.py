@@ -47,7 +47,8 @@ _EXPECTS = {
             "Yolo2OutputLayer", "SpaceToDepthLayer"},
     "rnn": {"LSTM", "GravesLSTM", "GravesBidirectionalLSTM", "SimpleRnn",
             "RnnOutputLayer", "Convolution1DLayer", "Subsampling1DLayer",
-            "LastTimeStepLayer", "ZeroPadding1DLayer", "Upsampling1DLayer"},
+            "LastTimeStepLayer", "ZeroPadding1DLayer", "Upsampling1DLayer",
+            "TokenProjectionLayer"},
 }
 
 

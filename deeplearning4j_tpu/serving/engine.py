@@ -110,10 +110,12 @@ the host was doing. In cycle order: ``engine.reap`` (expiry,
 cancellation, overload control), ``engine.admit`` (from a request
 popped: page reservation, prefix lookup and install), ``prefill.input`` /
 ``prefill.forward`` / ``prefill.fetch`` (``util.decoding.prime_prompt``:
-the host-built prompt tensor; upload and launch; the result coming
+the padded prompt as the net takes it — int32 ids, or the host-built
+one-hot of a net that takes none; upload and launch; the result coming
 back), ``engine.seat`` (first draw, arena join, page-table update),
 ``decode.input`` (token vector, position mirrors, paged-view install,
-under speculation the host draft, the one-hot), ``decode.forward`` (the
+under speculation the host draft; the one-hot block only for a net that
+takes no ids), ``decode.forward`` (the
 dispatch, and the greedy argmax queued behind it), ``decode.fetch`` (the
 host waits for the device and copies: the [S] ids of a plain cycle —
 its one required sync — and the [S, V] block only when a seated request
@@ -133,9 +135,11 @@ counts at the same boundaries: ``decode_dispatch.rows``, ``sample``
 from their row, ``block_fetches`` = plain cycles that fetched [S, V]),
 ``prefill`` (tokens fed, padded widths dispatched; tokens the prefix
 cache served instead are ``prefix_cache.reused_tokens``) and ``host_io``
-(bytes of the numpy arrays that cross around ``rnn_time_step``: int32
-ids for a net that takes ids, the one-hot block otherwise — the engine
-asks the net, ``util.decoding.takes_ids``). A net with routed-expert
+(bytes of the numpy arrays that cross around ``rnn_time_step``, and
+``input_form``, which says what goes up: ``"ids"``, int32, for a net that
+takes ids — every zoo transformer — and ``"one-hot"``, the float32
+``[B, V, T]`` block, otherwise; the engine asks the net,
+``util.decoding.takes_ids``). A net with routed-expert
 layers adds ``experts`` (their ``moe_stats``), one with sparse-selection
 attention ``sparse_attn`` (host counts from each dispatch's rows, and
 the layers' ``attn_stats``); what the layers count is joined on the
@@ -200,7 +204,7 @@ from deeplearning4j_tpu.serving.scheduler import AdmissionQueue
 from deeplearning4j_tpu.util.decoding import (
     ArgmaxRow, RoundTrip, _check_seed, _stream_layers, _width_bucket,
     accept_proposals, draw, filter_probs, prime_prompt, selects_one,
-    step_greedy, stop_reason, verify_tokens)
+    step_greedy, stop_reason, takes_ids, verify_tokens)
 
 log = logging.getLogger(__name__)
 
@@ -861,8 +865,9 @@ class GenerationEngine:
                "prefill": {
                    "fed_tokens": self._prefill_fed,
                    "bucket_tokens": self._io["prefill"].width},
-               "host_io": {k: io.as_dict()
-                           for k, io in self._io.items()}}
+               "host_io": dict(
+                   {k: io.as_dict() for k, io in self._io.items()},
+                   input_form="ids" if takes_ids(self.net) else "one-hot")}
         if self._expert_names:
             out["experts"] = self._expert_counters()
         if self._sparse_names:

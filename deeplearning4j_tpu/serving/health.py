@@ -63,7 +63,9 @@ SERVING_DISPATCH_LATENCY = "dl4jtpu_serving_decode_dispatch_seconds"
 #: ``kind`` (fed / bucket = the padded width dispatched; tokens the prefix
 #: cache served instead: SERVING_PREFIX_REUSED_TOKENS); bytes of the numpy
 #: arrays that cross the host boundary around ``rnn_time_step``, by
-#: ``phase`` (decode / prefill) and ``direction`` (h2d / d2h)
+#: ``phase`` (decode / prefill) and ``direction`` (h2d / d2h);
+#: ``health()["host_io"]["input_form"]`` says what the h2d bytes are:
+#: ``"ids"`` (int32, 4 bytes a token) or ``"one-hot"`` (float32 [B, V, T])
 SERVING_DECODE_ROWS = "dl4jtpu_serving_decode_rows_total"
 SERVING_PREFILL_TOKENS = "dl4jtpu_serving_prefill_tokens_total"
 SERVING_HOST_IO_BYTES = "dl4jtpu_serving_host_io_bytes_total"

@@ -42,8 +42,10 @@ def _one_hot(rows: np.ndarray, vocab: int) -> np.ndarray:
 
 def takes_ids(net) -> bool:
     """Whether `net` is fed token ids ``[B, T]`` (a layer of it says
-    ``takes_ids``: ``SequenceEmbeddingLayer``) and no one-hot
-    ``[B, V, T]``. Asked of the net, once; no caller passes an option."""
+    ``takes_ids``: ``SequenceEmbeddingLayer``, ``TokenProjectionLayer`` —
+    so every zoo transformer) and no one-hot ``[B, V, T]`` (a recurrent
+    or imported net whose first layer reads a float sequence). Asked of
+    the net, once; no caller passes an option."""
     known = getattr(net, "_takes_ids", None)
     if known is None:
         known = net._takes_ids = any(
@@ -464,10 +466,10 @@ def prime_prompt(net, ids, vocab_size: int, padded: bool = False,
     by the padded-prime tests. Does NOT clear previous state: the
     caller owns the stream lifecycle (sample_stream clears first; the
     serving engine primes into a fresh state it then joins to its slot
-    arena). `io` (``RoundTrip``) sees the prime as input (padding, the
-    host-built one-hot), then forward (upload and launch; chunked priming
-    alternates the two per chunk), then one fetch (the result coming
-    back), which is left for the caller to end."""
+    arena). `io` (``RoundTrip``) sees the prime as input (padding; the
+    one-hot only for a net that takes no ids), then forward (upload and
+    launch; chunked priming alternates the two per chunk), then one fetch
+    (the result coming back), which is left for the caller to end."""
     out = (_prime_padded(net, ids, vocab_size, chunk_max, io) if padded
            else _prime(net, ids, vocab_size, chunk_max, io))
     io.step("fetch")
@@ -490,10 +492,10 @@ def step_tokens(net, tokens, vocab_size: int,
     caller must treat the pre-call state as consumed — the state the
     net carries after the call is the only live copy.
 
-    `io` (``RoundTrip``) sees input while the one-hot is built, forward
-    around the dispatch, fetch from the result coming back — left for the
-    caller to end. A cycle passes it to ONE such call, on its own
-    thread."""
+    `io` (``RoundTrip``) sees input while the ids (or the one-hot) are
+    laid out, forward around the dispatch, fetch from the result coming
+    back — left for the caller to end. A cycle passes it to ONE such
+    call, on its own thread."""
     return _last(_decode(net, np.asarray(tokens, np.int64)[:, None],
                          vocab_size, donate_state, io))
 

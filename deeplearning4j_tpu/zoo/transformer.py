@@ -5,7 +5,11 @@ TextGenerationLSTM; this is its modern long-context counterpart built
 from the same config DSL): pre-LN transformer blocks —
 LN → causal multi-head SelfAttentionLayer → residual add →
 LN → position-wise FFN (Convolution1D kernel=1) → residual add —
-over RNN-format [N, V, T] one-hot input, RnnOutputLayer softmax head.
+behind a TokenProjectionLayer, RnnOutputLayer softmax head. The net takes
+token ids [N, T] (what the decoders of util/decoding and the serving
+engine send: 4 bytes a token) and, through the same two leaves, the
+RNN-format float [N, V, T] — one-hot training data for ``fit``,
+``sample()``'s padded one-hot, soft distributions.
 The attention core is the flash-style blockwise kernel, so contexts of
 tens of thousands of tokens train on a single chip; sequence sharding
 over a mesh uses ring/Ulysses attention on the same math
@@ -20,7 +24,7 @@ from deeplearning4j_tpu.nn.conf.graph_conf import ElementWiseVertex
 from deeplearning4j_tpu.nn.conf.inputs import InputType
 from deeplearning4j_tpu.nn.conf.layers import (
     Convolution1DLayer, LayerNormalization, PositionalEmbeddingLayer,
-    RnnOutputLayer, SelfAttentionLayer,
+    RnnOutputLayer, SelfAttentionLayer, TokenProjectionLayer,
 )
 from deeplearning4j_tpu.nn.conf.network import NeuralNetConfiguration
 from deeplearning4j_tpu.nn.updater import Adam
@@ -63,11 +67,10 @@ class TextGenerationTransformer(ZooModel):
              .add_inputs("in")
              .set_input_types(InputType.recurrent(self.vocab_size,
                                                   self.max_length)))
-        # token projection: one-hot [N,V,T] -> [N,E,T] (kernel-1 conv =
-        # position-wise embedding matmul)
-        g.add_layer("embed", Convolution1DLayer(
-            n_out=E, kernel=1, convolution_mode="same",
-            activation="identity"), "in")
+        # token projection: ids [N,T] -> [N,E,T], column id of W [E,V,1];
+        # a float [N,V,T] -> the kernel-1 convolution over the same W
+        g.add_layer("embed", TokenProjectionLayer(
+            n_out=E, activation="identity"), "in")
         if self.positional == "learned":
             g.add_layer("pos", PositionalEmbeddingLayer(
                 max_length=self.max_length), "embed")

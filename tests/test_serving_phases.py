@@ -258,14 +258,16 @@ class TestScriptedRun:
 
     def test_the_four_byte_counters(self, net):
         _, _, _, _, h1, _, cycles = self._run(net)
-        f32 = 4
+        f32 = i32 = 4
         assert h1["host_io"] == {
-            # [S, V, 1] float32 one-hot up; every row is greedy, so the
-            # [S] int32 ids come down and the [S, V, 1] block stays put
-            "decode": {"h2d_bytes": cycles * 2 * V * f32,
-                       "d2h_bytes": cycles * 2 * 4},
-            # [1, V, P] up and [1, V, P] down per prime
-            "prefill": {"h2d_bytes": (8 + 16 + 2) * V * f32,
+            # a zoo transformer takes ids
+            "input_form": "ids",
+            # [S, 1] int32 ids up; every row is greedy, so the [S] int32
+            # ids come down and the [S, V, 1] block stays put
+            "decode": {"h2d_bytes": cycles * 2 * i32,
+                       "d2h_bytes": cycles * 2 * i32},
+            # [1, P] int32 up and [1, V, P] float32 down per prime
+            "prefill": {"h2d_bytes": (8 + 16 + 2) * i32,
                         "d2h_bytes": (8 + 16 + 2) * V * f32}}
 
     def test_the_registry_reads_the_same_counts_at_scrape_time(self, net):
@@ -315,7 +317,7 @@ class TestOtherPaths:
         h = eng.health()
         assert h["prefill"]["fed_tokens"] == \
             h["prefill"]["bucket_tokens"] == 5
-        assert h["host_io"]["prefill"] == {"h2d_bytes": 5 * V * 4,
+        assert h["host_io"]["prefill"] == {"h2d_bytes": 5 * 4,
                                            "d2h_bytes": 1 * V * 4}
 
     def test_a_speculative_engine_runs_the_same_phases(self, net):
@@ -334,9 +336,10 @@ class TestOtherPaths:
         assert all("/" not in p for p in paths.names())
         n = h1["decode_dispatch"]["count"] - h0["decode_dispatch"]["count"]
         assert d["decode.fetch"] == d["engine.sample"] == n == cycles
-        # the verify chunk is [S, 1 + gamma] wide, both ways
+        # the verify chunk is [S, 1 + gamma] wide: int32 ids up, the
+        # float32 distributions of every position down
         assert h1["host_io"]["decode"] == {
-            "h2d_bytes": n * 2 * V * 3 * 4, "d2h_bytes": n * 2 * V * 3 * 4}
+            "h2d_bytes": n * 2 * 3 * 4, "d2h_bytes": n * 2 * V * 3 * 4}
 
     def test_a_rebuild_inside_a_cycle_stays_flat(self, net):
         """A decode fault mid-cycle: the supervisor re-primes both
