@@ -28,6 +28,7 @@ from deeplearning4j_tpu.nn import activations as _act
 from deeplearning4j_tpu.nn import losses as _losses
 from deeplearning4j_tpu.nn.conf.inputs import InputType
 from deeplearning4j_tpu.nn.layers import convolution as _conv
+from deeplearning4j_tpu.nn.layers import linear_attention as _la
 from deeplearning4j_tpu.nn.layers import normalization as _norm
 from deeplearning4j_tpu.nn.layers import recurrent as _rnn
 from deeplearning4j_tpu.nn.layers import routed_experts as _re
@@ -49,18 +50,22 @@ import numpy as np
 #: rotated key and the index key LatentAttentionLayer caches per token;
 #: moe_stats: RoutedExpertsLayer's router-load counters; attn_stats: the
 #: positions LatentAttentionLayer's streaming forms scored)
+#: (gdn_s / gdn_conv: GatedDeltaNetLayer's float32 state a head and the
+#: K - 1 inputs before its convolution, one row a stream whatever its
+#: length; gdn_stats: the positions its two forms computed)
 STREAM_STATE_KEYS = frozenset(
     {"h", "c", "kv_k", "kv_v", "kv_pos", "kv_abs", "kv_mask",
      "pos_offset", "kv_page_k", "kv_page_v", "kv_page_table",
      "kv_page_scale_k", "kv_page_scale_v", "kv_page_prime",
      "kv_c", "kv_r", "kv_i", "kv_page_c", "kv_page_r", "kv_page_i",
-     "moe_stats", "attn_stats"})
+     "moe_stats", "attn_stats", "gdn_s", "gdn_conv", "gdn_stats"})
 
 #: streaming-state keys whose LEADING axis is the batch dimension (beam
 #: search gathers these when pruning beams; kv_pos/kv_abs/pos_offset are
 #: batch-independent scalars/vectors)
 BATCHED_STREAM_KEYS = frozenset({"h", "c", "kv_k", "kv_v", "kv_mask",
-                                 "kv_c", "kv_r", "kv_i"})
+                                 "kv_c", "kv_r", "kv_i", "gdn_s",
+                                 "gdn_conv"})
 
 
 def reorder_stream_state(net, indices) -> None:
@@ -103,9 +108,9 @@ def rewind_stream_state(net, n) -> None:
     PositionalEmbeddingLayer's pos_offset stays scalar, so nets with
     learned positional tables reject array rewinds.
 
-    Only position-indexed state can rewind: recurrent h/c carries the
-    rejected steps irreversibly, so nets with streaming LSTM state
-    raise. Rolling (windowed) caches additionally need
+    Only position-indexed state can rewind: recurrent state (LSTM h/c,
+    linear-attention state) carries the rejected steps irreversibly, so
+    nets with such layers raise. Rolling (windowed) caches additionally need
     cache_length >= window + n — a rejected write may have evicted the
     slot n positions short of the window edge."""
     per_row = np.ndim(n) > 0
@@ -175,6 +180,12 @@ def _rewind_counters(vals, n):
     return [jnp.maximum(v - n, 0) for v in vals]
 
 
+_NO_REWIND = ("rewind_stream_state: recurrent state (LSTM h/c, "
+              "linear-attention state) is a function of every token fed "
+              "and cannot be rewound: such layers do not support "
+              "speculative rollback")
+
+
 def check_rewindable(net, n: int) -> None:
     """Validate that `net` can rewind up to `n` streamed positions
     (rewind_stream_state preconditions) — speculative_sample calls this
@@ -183,11 +194,8 @@ def check_rewindable(net, n: int) -> None:
     if n < 0:
         raise ValueError(f"rewind must be >= 0, got {n}")
     for s in net.state.values():
-        if isinstance(s, dict) and ("h" in s or "c" in s):
-            raise ValueError(
-                "rewind_stream_state: recurrent h/c streaming state "
-                "cannot be rewound (LSTM layers do not support "
-                "speculative rollback)")
+        if isinstance(s, dict) and ("h" in s or "c" in s or "gdn_s" in s):
+            raise ValueError(_NO_REWIND)
     layers = list(getattr(net, "layers", None) or []) or [
         getattr(v, "layer", None)
         for v in (getattr(net.conf, "vertices", None) or {}).values()]
@@ -195,10 +203,7 @@ def check_rewindable(net, n: int) -> None:
         # static check too: a freshly-cleared stream has no h/c in state
         # yet, but the layer WILL carry it as soon as it streams
         if getattr(l, "carries_recurrent_state", False):
-            raise ValueError(
-                "rewind_stream_state: recurrent h/c streaming state "
-                "cannot be rewound (LSTM layers do not support "
-                "speculative rollback)")
+            raise ValueError(_NO_REWIND)
         w = getattr(l, "window", None)
         if w and getattr(l, "supports_streaming", False):
             L = getattr(l, "cache_length", 0)
@@ -1057,6 +1062,18 @@ class SelfAttentionLayer(FeedForwardLayerConf):
     depend only on RELATIVE offsets — no learned position table, clean
     extrapolation, and streaming decode rotates by absolute kv_pos
     (cached keys are rotated at insert time). Head dim must be even.
+    ``rope=False`` (the default) rotates nothing: a decoder whose other
+    layers carry order (linear attention) runs its full-attention layers
+    that way.
+
+    ``has_bias=False`` drops bq/bk/bv/bo (the leaves do not exist).
+    ``qk_norm=True`` passes the projected queries and keys, each over its
+    whole projected width and before the split into heads, through an
+    RMSNorm with a gain (leaves ``q_norm`` [n_out], ``k_norm``
+    [Hkv * D]; statistics in float32, ``qk_norm_eps``) — the decoders
+    that bound their attention logits this way. Both off by default: a
+    saved configuration and its programs stay as they were.
+    ``stream_query_block`` bounds what a long prime keeps of its scores.
     """
 
     n_heads: int = 4
@@ -1070,6 +1087,15 @@ class SelfAttentionLayer(FeedForwardLayerConf):
     #: most recent positions (Mistral-style local attention; the Pallas
     #: kernel skips out-of-window blocks). None = full attention.
     window: Optional[int] = None
+    has_bias: bool = True
+    qk_norm: bool = False
+    qk_norm_eps: float = 1e-6
+    #: queries a streaming chunk attends at once (None: all of them, one
+    #: [H, T, L] float32 score tensor). A prime of T positions against a
+    #: cache of L keeps T * L * H * 4 bytes of scores and as many of
+    #: probabilities; in blocks of this many queries (a ``lax.map``, the
+    #: same arithmetic a row) it keeps a block's.
+    stream_query_block: Optional[int] = None
 
     supports_streaming = True
     #: (impl, interpret): how a decode step reads the page pool when a
@@ -1127,7 +1153,11 @@ class SelfAttentionLayer(FeedForwardLayerConf):
             n_out = hkv * d if name in ("k", "v") else self.n_out
             p["W" + name] = init_weights(keys[i], (n_in, n_out), n_in,
                                          n_out, self.weight_init, self.dist)
-            p["b" + name] = jnp.zeros((n_out,), jnp.float32)
+            if self.has_bias:
+                p["b" + name] = jnp.zeros((n_out,), jnp.float32)
+        if self.qk_norm:
+            p["q_norm"] = jnp.ones((self.n_out,), jnp.float32)
+            p["k_norm"] = jnp.ones((hkv * d,), jnp.float32)
         return p, {}
 
     def apply(self, params, x, state, *, train=False, rng=None, mask=None,
@@ -1143,7 +1173,13 @@ class SelfAttentionLayer(FeedForwardLayerConf):
         xt = jnp.transpose(x, (0, 2, 1))                    # [N,T,F]
 
         def proj(name, heads):
-            y = xt @ params["W" + name] + params["b" + name]
+            y = xt @ params["W" + name]
+            if self.has_bias:
+                y = y + params["b" + name]
+            if self.qk_norm and name in ("q", "k"):
+                with jax.named_scope("attn.qk_norm"):
+                    y = _rms_norm(y, params[name + "_norm"],
+                                  self.qk_norm_eps)
             return y.reshape(n, t, heads, d).transpose(0, 2, 1, 3)
 
         q = proj("q", h)                                    # [N,H,T,D]
@@ -1165,7 +1201,9 @@ class SelfAttentionLayer(FeedForwardLayerConf):
                                     block_size=self.block_size,
                                     key_mask=mask, window=self.window)
         o = o.transpose(0, 2, 1, 3).reshape(n, t, self.n_out)
-        o = o @ params["Wo"] + params["bo"]
+        o = o @ params["Wo"]
+        if self.has_bias:
+            o = o + params["bo"]
         y = jnp.transpose(o, (0, 2, 1))                     # [N,F,T]
         return _act.get(self.activation)(y), state
 
@@ -1482,6 +1520,15 @@ class SelfAttentionLayer(FeedForwardLayerConf):
         """Masked attention of [N,H,T,D] queries against the un-expanded
         [N,Hkv,L,D] cache (GQA groups share KV heads); valid: [N|1, T, L]."""
         n, _, t, d = q.shape
+        blk = self.stream_query_block
+        if blk and t > blk and t % blk == 0:
+            nb, bound = t // blk, valid.shape[0]
+            qb = jnp.moveaxis(q.reshape(n, self.n_heads, nb, blk, d), 2, 0)
+            vb = jnp.moveaxis(valid.reshape(bound, nb, blk, -1), 1, 0)
+            o = jax.lax.map(
+                lambda x: self._grouped_attend(x[0], kc, vc, x[1]),
+                (qb, vb))                          # [nb, N, H, blk, D]
+            return jnp.moveaxis(o, 0, 2).reshape(n, self.n_heads, t, d)
         hkv = kc.shape[1]
         reps = self.n_heads // hkv
         qg = q.astype(jnp.float32).reshape(n, hkv, reps, t, d)
@@ -2180,6 +2227,54 @@ def paged_leaves(layer) -> Tuple[PagedLeaf, ...]:
     return tuple(declare()) if declare is not None else ()
 
 
+class SlotLeaf(NamedTuple):
+    """One leaf of the state a streaming layer keeps a STREAM and not a
+    token (recurrent state): ``[N, *row_shape]`` under ``key`` in the
+    layer's streaming state, ``dtype`` (None: the dtype activations come
+    in). A serving engine keeps one row a slot beside its page pool and
+    seats a primed row whole; the size does not grow with the context."""
+
+    key: str
+    row_shape: Tuple[int, ...]
+    dtype: Optional[str] = None
+
+    def row_bytes(self, compute_dtype) -> int:
+        return int(np.prod(self.row_shape, dtype=np.int64)) \
+            * jnp.dtype(self.dtype or compute_dtype).itemsize
+
+
+def slot_leaves(layer) -> Tuple[SlotLeaf, ...]:
+    """What a streaming layer keeps per stream: its own
+    ``slot_leaves()``, or none."""
+    declare = getattr(layer, "slot_leaves", None)
+    return tuple(declare()) if declare is not None else ()
+
+
+class StreamCounters(NamedTuple):
+    """The counters a layer's streaming forms keep in their state: an
+    integer vector (or a scalar, for one field) under ``key``, one number
+    a ``fields`` name, added to by every call (``maxima``: fields that
+    take the maximum instead). A serving engine takes them out of the
+    state each dispatch returns, joins them on the device and shows the
+    sums over the layers of a ``kind`` under ``health()[kind]``.
+    ``host``, where given, is called with the contexts (positions seen,
+    itself included) of each real query of a dispatch and returns what
+    that dispatch adds to further fields of the same key, counted on the
+    host from the rows alone."""
+
+    key: str
+    kind: str
+    fields: Tuple[str, ...]
+    maxima: Tuple[str, ...] = ()
+    host: Optional[Any] = None
+
+
+def stream_counters(layer) -> Optional[StreamCounters]:
+    """A layer's own ``stream_counters()``, or None."""
+    declare = getattr(layer, "stream_counters", None)
+    return declare() if declare is not None else None
+
+
 def _rms_norm(x, gamma, eps: float):
     """RMSNorm over the last axis, float32 statistics."""
     xf = x.astype(jnp.float32)
@@ -2430,6 +2525,21 @@ class LatentAttentionLayer(FeedForwardLayerConf):
         return (PagedLeaf("kv_c", (self.kv_lora_rank,), 0),
                 PagedLeaf("kv_r", (self.qk_rope_head_dim,), 0),
                 PagedLeaf("kv_i", (self.index_head_dim,), 0))
+
+    def stream_counters(self):
+        return StreamCounters(
+            "attn_stats", "sparse_attn", ("attended_positions",),
+            host=self._selected_counts)
+
+    def _selected_counts(self, contexts) -> Dict[str, int]:
+        """A dispatch's queries by the positions each may see: how many
+        there were, how many positions lay before them, and how many of
+        those the selection keeps."""
+        contexts = np.asarray(contexts, np.int64)
+        return {"query_positions": len(contexts),
+                "context_positions": int(contexts.sum()),
+                "selected_positions": int(
+                    np.minimum(contexts, self.index_topk).sum())}
 
     def paged_read_tokens(self) -> Dict[str, int]:
         """Tokens of each leaf one row's paged decode reads (the serving
@@ -2829,6 +2939,12 @@ class RoutedExpertsLayer(FeedForwardLayerConf):
             raise ValueError("groups must divide router_experts, and "
                              "top_groups cannot pass groups")
 
+    def stream_counters(self):
+        return StreamCounters(
+            "moe_stats", "experts",
+            ("tokens", "held_pairs", "rows_computed", "max_expert_load"),
+            maxima=("max_expert_load",))
+
     def output_type(self, it):
         if it.kind != "rnn":
             raise ValueError("RoutedExpertsLayer needs RNN input [N,F,T]")
@@ -2898,3 +3014,192 @@ class RoutedExpertsLayer(FeedForwardLayerConf):
                                       params["Ws_d"])
         y = jnp.moveaxis(y.astype(x.dtype).reshape(n, t, -1), 2, 1)
         return _act.get(self.activation)(y), state
+
+
+@register_layer
+@dataclass
+class GatedDeltaNetLayer(FeedForwardLayerConf):
+    """Linear attention by the gated delta rule over RNN-format input
+    [N,F,T] (Yang, Kautz & Hatamizadeh 2024; the arithmetic and its two
+    forms are nn/layers/linear_attention.py). ``n_heads`` heads, keys and
+    queries ``key_dim`` wide, values ``value_dim``. For a token ``x``:
+
+        q, k, v, z = x Wq, x Wk, x Wv, x Wz;    a, b = x Wa, x Wb
+        (q, k, v) <- silu(causal depthwise conv, width conv_kernel)
+        q <- q / |q| * key_dim^-1/2,  k <- k / |k|      (per head)
+        beta = (2 if allow_neg_eigval else 1) * sigmoid(b)
+        alpha = exp(-exp(A_log) * softplus(a + dt_bias))
+        S <- alpha S + beta k (v - alpha S^T k)^T,   o = S^T q
+        y = RMSNorm(o) * norm * silu(z)   (per head);   out = y Wo
+
+    No biases. Leaves: ``Wq Wk`` [F, H*dk], ``Wv Wz`` [F, H*dv], ``Wa
+    Wb`` [F, H], ``Wo`` [H*dv, n_out], ``conv`` [K, H*(2dk+dv)],
+    ``A_log dt_bias`` [H], ``norm`` [dv]. Products take operands in the
+    input's dtype and accumulate in float32; decays, beta, both norms'
+    statistics and the state are float32.
+
+    One form a shape of input: a chunk one position wide takes the
+    one-step update, a wider one the chunked scan (whole chunks of
+    ``linear_attention.CHUNK``, padded on the left). A masked position —
+    ``pad_left`` of a packed prime, or a key ``mask`` — has alpha 1 and
+    beta 0, feeds the convolution a zero and leaves its tail where it
+    was, so a left-padded bucket primes to exactly the state of the
+    unpadded prompt; with a ``mask`` the real positions are first moved
+    to the right of their row, in order, and the outputs moved back.
+
+    Streaming (``rnn_time_step``) carries the state a STREAM keeps and
+    not a token (``carries_recurrent_state``; declared by
+    ``slot_leaves()``): ``gdn_s`` [N, H, dk, dv] float32 and ``gdn_conv``
+    [N, K-1, H*(2dk+dv)], the convolution's last inputs. It cannot be
+    rewound, nor taken up at a prefix. ``gdn_stats`` int32 [3] counts
+    what the forms computed (``stream_counters()``): positions the
+    chunked scan went over, left pads and the fill to whole chunks
+    included; the real ones among them; one-step updates (rows)."""
+
+    n_heads: int = 4
+    key_dim: int = 32
+    value_dim: int = 64
+    conv_kernel: int = 4
+    allow_neg_eigval: bool = True
+    eps: float = 1e-6
+    l2_eps: float = 1e-6
+
+    supports_streaming = True
+    carries_recurrent_state = True
+
+    @property
+    def conv_channels(self) -> int:
+        return self.n_heads * (2 * self.key_dim + self.value_dim)
+
+    def output_type(self, it):
+        if it.kind != "rnn":
+            raise ValueError("GatedDeltaNetLayer needs RNN input [N,F,T]")
+        return InputType.recurrent(self.n_out or it.size, it.timesteps)
+
+    def slot_leaves(self):
+        return (SlotLeaf("gdn_s", (self.n_heads, self.key_dim,
+                                   self.value_dim), "float32"),
+                SlotLeaf("gdn_conv", (self.conv_kernel - 1,
+                                      self.conv_channels)))
+
+    def stream_counters(self):
+        return StreamCounters(
+            "gdn_stats", "linear_attn",
+            ("scanned_positions", "fed_positions", "state_updates"))
+
+    def init(self, key, it):
+        if self.n_in is None:
+            self.n_in = it.size
+        if self.n_out is None:
+            self.n_out = self.n_in
+        f, h = self.n_in, self.n_heads
+        qk, vz = h * self.key_dim, h * self.value_dim
+        ks = jax.random.split(key, 10)
+
+        def w(k, a, b):
+            return init_weights(k, (a, b), a, b, self.weight_init,
+                                self.dist)
+
+        # decay rates and time steps as the rule's authors draw them: A
+        # uniform in (0, 16), a time step log-uniform in (1e-3, 0.1)
+        dt = jnp.exp(jax.random.uniform(
+            ks[9], (h,), jnp.float32, np.log(1e-3), np.log(0.1)))
+        return {
+            "Wq": w(ks[0], f, qk), "Wk": w(ks[1], f, qk),
+            "Wv": w(ks[2], f, vz), "Wz": w(ks[3], f, vz),
+            "Wa": w(ks[4], f, h), "Wb": w(ks[5], f, h),
+            "Wo": w(ks[6], vz, self.n_out),
+            "conv": jax.random.normal(
+                ks[7], (self.conv_kernel, self.conv_channels),
+                jnp.float32) / np.sqrt(self.conv_kernel),
+            "A_log": jnp.log(jax.random.uniform(
+                ks[8], (h,), jnp.float32, 1.0, 16.0)),
+            "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+            "norm": jnp.ones((self.value_dim,), jnp.float32)}, {}
+
+    def apply(self, params, x, state, *, train=False, rng=None, mask=None,
+              stream=False, pad_left=None):
+        if pad_left is not None and not stream:
+            raise ValueError("pad_left is only meaningful for streaming")
+        x = self.maybe_dropout_input(x, train, rng)
+        n, _, t = x.shape
+        h, dk, dv = self.n_heads, self.key_dim, self.value_dim
+        cd = x.dtype
+        xt = jnp.moveaxis(x, 1, 2)                             # [N, T, F]
+        valid = order = None
+        if pad_left is not None:
+            if mask is not None:
+                raise ValueError("pad_left and mask are mutually "
+                                 "exclusive in streaming")
+            valid = jnp.broadcast_to(jnp.arange(t) >= pad_left, (n, t))
+        elif mask is not None:
+            # real positions to the right of their row, in order: every
+            # row is then left-padded, whatever the mask's shape was
+            valid = jnp.asarray(mask).reshape(n, t) > 0
+            order = jnp.argsort(valid, axis=1, stable=True)
+            xt = jnp.take_along_axis(xt, order[..., None], axis=1)
+            valid = jnp.take_along_axis(valid, order, axis=1)
+        pad = (jnp.zeros((n,), jnp.int32) if valid is None
+               else t - jnp.sum(valid, axis=1, dtype=jnp.int32))
+
+        def proj(name):
+            return jnp.dot(xt, params[name],
+                           preferred_element_type=jnp.float32)
+
+        with jax.named_scope("gdn.project"):
+            qkv = jnp.concatenate(
+                [proj("Wq"), proj("Wk"), proj("Wv")], axis=-1).astype(cd)
+            z = proj("Wz").astype(cd).reshape(n, t, h, dv)
+            rate = jnp.exp(params["A_log"].astype(jnp.float32))
+            log_alpha = -rate * jax.nn.softplus(
+                proj("Wa") + params["dt_bias"].astype(jnp.float32))
+            beta = (2.0 if self.allow_neg_eigval else 1.0) \
+                * jax.nn.sigmoid(proj("Wb"))
+            if valid is not None:
+                qkv = jnp.where(valid[..., None], qkv, 0)
+                log_alpha = jnp.where(valid[..., None], log_alpha, 0.0)
+                beta = jnp.where(valid[..., None], beta, 0.0)
+        with jax.named_scope("gdn.conv"):
+            tail = state.get("gdn_conv") if stream else None
+            if tail is None:
+                tail = jnp.zeros((n, self.conv_kernel - 1,
+                                  self.conv_channels), cd)
+            y, tail = _la.causal_conv(qkv, params["conv"], tail, pad)
+            y = jax.nn.silu(y).astype(cd)
+            q, k, v = jnp.split(y, [h * dk, 2 * h * dk], axis=-1)
+            q = _la.l2_normalize(q.reshape(n, t, h, dk), self.l2_eps)
+            q = (q.astype(jnp.float32) * dk ** -0.5).astype(cd)
+            k = _la.l2_normalize(k.reshape(n, t, h, dk), self.l2_eps)
+            v = v.reshape(n, t, h, dv)
+        s0 = state.get("gdn_s") if stream else None
+        if s0 is None:
+            s0 = jnp.zeros((n, h, dk, dv), jnp.float32)
+        if t == 1:
+            with jax.named_scope("gdn.update"):
+                o, s1 = _la.gdn_step(q[:, 0], k[:, 0], v[:, 0],
+                                     log_alpha[:, 0], beta[:, 0], s0)
+            o = o[:, None]                                 # [N, 1, H, dv]
+            counts = (0, 0, n)
+        else:
+            with jax.named_scope("gdn.scan"):
+                o, s1 = _la.gdn_chunked(
+                    *(jnp.moveaxis(a, 1, 2) for a in
+                      (q, k, v, log_alpha, beta)), s0)
+            o = jnp.moveaxis(o, 2, 1)                      # [N, T, H, dv]
+            fed = n * t if valid is None else jnp.sum(valid)
+            counts = (n * (t + -t % _la.CHUNK), fed, 0)
+        with jax.named_scope("gdn.gate"):
+            y = _la.gated_rms_norm(o, z, params["norm"], self.eps)
+            y = jnp.dot(y.astype(cd).reshape(n, t, h * dv), params["Wo"],
+                        preferred_element_type=jnp.float32).astype(cd)
+        if order is not None:
+            y = jnp.take_along_axis(
+                y, jnp.argsort(order, axis=1)[..., None], axis=1)
+        if stream:
+            prev = state.get("gdn_stats")
+            if prev is None:
+                prev = jnp.zeros((3,), jnp.int32)
+            state = {**state, "gdn_s": s1, "gdn_conv": tail,
+                     "gdn_stats": prev + jnp.stack(
+                         [jnp.asarray(c, jnp.int32) for c in counts])}
+        return _act.get(self.activation)(jnp.moveaxis(y, 1, 2)), state

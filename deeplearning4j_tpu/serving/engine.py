@@ -8,7 +8,8 @@ arXiv:1804.04806) while keeping the dispatch loop free of per-request
 shape work (the framework-overhead lesson of arXiv:2001.04206):
 
 - **Slot arena**: the net's carried streaming state (attention KV
-  caches, LSTM h/c) lives at a fixed batch of S slots — ONE canonical
+  caches; recurrent state: LSTM h/c, linear-attention state) lives at a
+  fixed batch of S slots — ONE canonical
   ``[S, V, 1]`` decode dispatch advances every active request per step,
   so after warmup the steady state never retraces regardless of request
   mix. Per-slot positions ride the per-row ``kv_pos`` vector the
@@ -36,8 +37,8 @@ rule — the lowest-index maximum of the distribution the program
 returned, no random numbers consumed — which a plain cycle answers for
 all S rows with one on-device argmax (``util.decoding.greedy_ids``).
 
-Exactness conditions are ``sample_stream_batch``'s: recurrent (LSTM)
-state or attention with rope / no positions. Models with LEARNED
+Exactness conditions are ``sample_stream_batch``'s: recurrent state
+(LSTM h/c, linear-attention state) or attention with rope / no positions. Models with LEARNED
 positional tables are rejected at construction (``pos_offset`` is a
 scalar shared across the batch — it cannot track per-slot positions).
 
@@ -139,18 +140,39 @@ cache served instead are ``prefix_cache.reused_tokens``) and ``host_io``
 ``input_form``, which says what goes up: ``"ids"``, int32, for a net that
 takes ids — every zoo transformer — and ``"one-hot"``, the float32
 ``[B, V, T]`` block, otherwise; the engine asks the net,
-``util.decoding.takes_ids``). A net with routed-expert
-layers adds ``experts`` (their ``moe_stats``), one with sparse-selection
-attention ``sparse_attn`` (host counts from each dispatch's rows, and
-the layers' ``attn_stats``); what the layers count is joined on the
-device behind every dispatch and fetched when ``health()`` is called,
-never in the cycle. ``serving/health.py`` documents both.
+``util.decoding.takes_ids``). Layers declare what they count
+(``stream_counters()`` beside ``paged_leaves()``) and ``health()`` shows
+it under the key of their kind: routed experts ``experts``,
+sparse-selection attention ``sparse_attn`` (with the host counts its
+declaration makes from each dispatch's rows), linear attention
+``linear_attn`` (with ``layers``, ``state_bytes_per_slot`` and
+``seated_state_bytes`` from what the layers declare they keep a stream,
+``slot_leaves()``); what the layers count is joined on the device
+behind every dispatch and fetched when ``health()`` is called, never in
+the cycle. ``serving/health.py`` documents them.
+
+Two kinds of cache in one manager. A net whose layers declare pages
+(``paged_leaves()``) AND a state a stream (``slot_leaves()``: recurrent
+state, whose size does not grow with the context) runs with
+``PagedKVConfig(prefix_cache=False)``: the pages of a request go to the
+pool through its table, the state rows stay in the slot arena beside it,
+and the ONE jitted scatter of an admission seats both the row's
+positions and its whole state on the device. A reused slot's row is
+overwritten by the seat; a free row's updates touch its own row only.
+``prefix_cache=True``, ``kv_dtype="int8"`` and ``speculation=`` raise
+for such a net (a prefix hit would need a state snapshot at the block
+boundary; the int8 prime runs through the pool; a rejected token cannot
+be taken back out of a recurrent state). The supervisor's rebuild and
+``admit_from_ledger`` carry such a state by recomputing it: the re-prime
+feeds prompt + committed tokens, so the row holds the same state to
+rounding (the chunked scan where the lost row had taken single steps).
 Between cycles, in no span: the serving loop's ``HANDOFF_WAIT_S`` park
 after a cycle that freed a slot.
 """
 
 from __future__ import annotations
 
+import functools
 import logging
 import os
 import threading
@@ -170,7 +192,8 @@ from deeplearning4j_tpu.monitoring.metrics import (
 from deeplearning4j_tpu.monitoring.tracing import next_phase, phases
 from deeplearning4j_tpu.nn.conf.layers import (
     BATCHED_STREAM_KEYS, PositionalEmbeddingLayer, check_rewindable,
-    paged_leaves, rewind_stream_state, stream_capacity)
+    paged_leaves, rewind_stream_state, slot_leaves, stream_capacity,
+    stream_counters)
 from deeplearning4j_tpu.resilience.chaos import fire as _fire_chaos
 from deeplearning4j_tpu.resilience.retry import RetryPolicy, retry_call
 from deeplearning4j_tpu.serving.errors import (
@@ -299,8 +322,7 @@ class _HostIO(RoundTrip):
         return {"h2d_bytes": self.h2d_bytes, "d2h_bytes": self.d2h_bytes}
 
 
-@jax.jit
-def _scatter_rows(arena, primed, slot):
+def _seat_rows(arena, primed, slot):
     """Join one primed request's stream state into the arena at `slot`:
     batch-leading leaves take the primed row 0, per-row counters
     (kv_pos [S] <- scalar, kv_abs [S, L] <- [L]) take the primed value.
@@ -309,6 +331,15 @@ def _scatter_rows(arena, primed, slot):
     for a, p in zip(arena, primed):
         out.append(a.at[slot].set(p[0] if p.ndim == a.ndim else p))
     return out
+
+
+#: the seat's one program. Off the CPU the arena is DONATED: its leaves
+#: are written in place, a row each — a net that keeps a state a stream
+#: (a float32 state a head a layer) would otherwise copy the whole arena,
+#: and hold two of them, at every admission. The caller treats the arena
+#: it passed as consumed (``_merge``).
+_scatter_rows_donated = jax.jit(_seat_rows, donate_argnums=(0,))
+_scatter_rows = jax.jit(_seat_rows)
 
 
 def _page_key(key: str) -> str:
@@ -321,39 +352,32 @@ def _scale_key(key: str) -> str:
     return "kv_page_scale_" + key[len("kv_"):]
 
 
-#: ``_join_stats`` keeps the scored positions as (high, low) uint32 with
-#: the low word under 2^30: a prime of 8,192 scores 2^25 positions a
-#: layer, so five layers would pass one int32 in ten primes
+#: ``_join_stats`` keeps every counter as (high, low) uint32 with the low
+#: word under 2^30: a prime of 8,192 scores 2^25 positions a sparse layer,
+#: so five layers would pass one int32 in ten primes. One dispatch's own
+#: count (an int32 out of the layer's state) stays under 2^31.
 _LOW_BITS = 30
 
 
-def _join_experts(total, stats):
-    """``experts`` [layers, 4] with a ``moe_stats`` [4] a layer: tokens,
-    pairs and rows add (int32, wrapping: ``_expert_counters`` takes
-    differences), the fullest expert's load is a maximum."""
-    new = jnp.stack(stats)
-    return jnp.concatenate([total[:, :3] + new[:, :3],
-                            jnp.maximum(total[:, 3:], new[:, 3:])], 1)
-
-
-def _join_attended(total, stats):
-    """``attended`` [2] (high, low) with an ``attn_stats`` scalar a layer,
-    carried from the low word into the high."""
-    low = total[1] + sum(a.astype(jnp.uint32) for a in stats)
-    return jnp.stack([total[0] + (low >> _LOW_BITS),
-                      low & ((1 << _LOW_BITS) - 1)])
-
-
-_JOIN = {"experts": _join_experts, "attended": _join_attended}
-
-
-@jax.jit
-def _join_stats(acc, stats):
-    """One dispatch's layer counters joined to the accumulators, each
-    kind under its key in both dicts (a net has either kind of layer,
-    both or neither): one program whatever the net has."""
-    return {kind: _JOIN[kind](total, stats[kind])
-            for kind, total in acc.items()}
+@functools.partial(jax.jit, static_argnames=("maxima",))
+def _join_stats(acc, stats, maxima):
+    """One dispatch's layer counters joined to the accumulators: per kind
+    ``acc`` [layers, fields, 2] (high, low) and ``stats`` a list of one
+    vector a layer. A field adds, carrying from the low word into the
+    high, or — where ``maxima`` (kind -> a flag a field) says so — takes
+    the maximum. One program whatever kinds the net has."""
+    out = {}
+    for kind, total in acc.items():
+        new = jnp.stack([a.reshape(-1) for a in stats[kind]]
+                        ).astype(jnp.uint32)
+        high, low = total[..., 0], total[..., 1]
+        added = low + new
+        is_max = np.array(dict(maxima)[kind])       # a constant a field
+        out[kind] = jnp.stack(
+            [jnp.where(is_max, high, high + (added >> _LOW_BITS)),
+             jnp.where(is_max, jnp.maximum(low, new),
+                       added & ((1 << _LOW_BITS) - 1))], axis=-1)
+    return out
 
 
 class GenerationEngine:
@@ -515,10 +539,10 @@ class GenerationEngine:
                     for l in layers):
                 raise ValueError(
                     "kv_dtype='int8' quantizes position-indexed KV "
-                    "pages only; recurrent h/c state is a function of "
-                    "the whole prefix and cannot re-prime through the "
-                    "paged path (use kv_dtype='bf16', or a pure-"
-                    "attention model)")
+                    "pages only; recurrent state (LSTM h/c, "
+                    "linear-attention state) is a function of the whole "
+                    "prefix and cannot re-prime through the paged path "
+                    "(use kv_dtype='bf16', or a pure-attention model)")
             self._kv_dtype = paging.kv_dtype
             if paging.total_bytes is not None and not plain:
                 usable = paging.resolve_pages_bytes(
@@ -556,9 +580,10 @@ class GenerationEngine:
                        for l in layers):
                     raise ValueError(
                         "the prefix cache reuses position-indexed KV "
-                        "pages only; recurrent h/c state is a function "
-                        "of the whole prefix and lives outside the "
-                        "pages — construct with "
+                        "pages only; recurrent state (LSTM h/c, "
+                        "linear-attention state) is a function of the "
+                        "whole prefix and lives outside the pages — "
+                        "construct with "
                         "PagedKVConfig(prefix_cache=False)")
                 self._prefix = PrefixCache(self._pool)
             if self._kv_dtype == "int8":
@@ -571,16 +596,17 @@ class GenerationEngine:
                 self._init_quant_store()
         # -- in-engine speculation (SpeculationConfig) -----------------
         self._speculation = speculation
+        if speculation is not None:
+            # rewind up to the full uniform chunk (gamma + 1 — a free
+            # row keeps nothing); fails fast for recurrent state (LSTM
+            # h/c, linear-attention state) / tight windows
+            check_rewindable(net, speculation.gamma + 1)
         if speculation is not None and any(
                 getattr(l, "last_step_only", False) for l in layers):
             raise ValueError(
                 "in-engine speculation verifies every position of a "
                 "widened chunk; this net's head answers for the last "
                 "position only (LastStepOutputLayer)")
-        if speculation is not None:
-            # rewind up to the full uniform chunk (gamma + 1 — a free
-            # row keeps nothing); fails fast for LSTMs / tight windows
-            check_rewindable(net, speculation.gamma + 1)
         self._admissions = 0
         self._dispatches = 0
         #: active rows summed over dispatches; tokens the primes fed
@@ -591,37 +617,50 @@ class GenerationEngine:
         #: that took the device's argmax, rows that sampled from their row,
         #: cycles that fetched the [S, V] block for the latter
         self._greedy_rows = self._drawn_rows = self._block_fetches = 0
-        #: layers whose streaming state carries counters, by state name
-        #: and counter: routed experts' ``moe_stats``, sparse-selection
-        #: attention's ``attn_stats``. Every dispatch's counts are taken
-        #: out of the state it returns and joined to ``_stats_acc`` ON THE
-        #: DEVICE (one tiny program queued behind the dispatch; nothing is
-        #: fetched in the cycle). The accumulators are no part of the
-        #: donated state, so ``health()`` reads them from any thread
-        #: without the step lock; ``_expert_seen`` / ``_expert_host`` are
-        #: what it has read of the experts' so far
-        layers = self._named_layers()
-        self._expert_names = [n for n, l in layers
-                              if hasattr(l, "router_experts")]
-        self._sparse_names = [n for n, l in layers
-                              if hasattr(l, "index_topk")]
-        self._stats_acc = {}
-        if self._expert_names:
-            self._stats_acc["experts"] = jnp.zeros(
-                (len(self._expert_names), 4), jnp.int32)
-        if self._sparse_names:
-            self._stats_acc["attended"] = jnp.zeros((2,), jnp.uint32)
-        self._expert_seen = np.zeros((len(self._expert_names), 3),
-                                     np.uint32)
-        self._expert_host = [0, 0, 0, 0]
-        self._expert_mutex = threading.Lock()
-        #: how much of a context each sparse-selection layer keeps, and
-        #: the host's counts of ``health()["sparse_attn"]``
-        self._sparse_topk = [l.index_topk for n, l in layers
-                             if n in self._sparse_names]
-        self._sparse = dict.fromkeys(
-            ("query_positions", "context_positions", "selected_positions"),
-            0)
+        #: what the layers declare they count (``stream_counters()``), by
+        #: the ``health()`` key of their kind: the declaration and the
+        #: state names of the kind's layers. Every dispatch's counts are
+        #: taken out of the state it returns and joined to ``_stats_acc``
+        #: ON THE DEVICE (one tiny program queued behind the dispatch;
+        #: nothing is fetched in the cycle). The accumulators are no part
+        #: of the donated state, so ``health()`` reads them from any
+        #: thread without the step lock. ``_host_counts`` holds what a
+        #: kind's ``host`` function counts from each dispatch's rows.
+        #: ``_slot_state``: what the layers declare they keep a stream
+        #: (``slot_leaves()``), under the same key: layers, bytes a slot,
+        #: and the bytes admissions have seated into arena rows.
+        self._counted = {}
+        self._host_counts = {}
+        self._slot_state = {}
+        native = getattr(getattr(net, "conf", None), "dtype", None) \
+            or "float32"
+        for n, l in self._named_layers():
+            decl = stream_counters(l)
+            if decl is None:
+                continue
+            kept = self._counted.setdefault(decl.kind, (decl, [], []))
+            if kept[0][:4] != decl[:4]:
+                raise ValueError(
+                    f"layers of kind {decl.kind!r} declare different "
+                    f"counters: {kept[0][:4]} and {decl[:4]}")
+            kept[1].append(n)
+            if decl.host is not None:
+                kept[2].append(decl.host)
+                self._host_counts.setdefault(decl.kind, {})
+            leaves = slot_leaves(l)
+            if leaves:
+                row = self._slot_state.setdefault(
+                    decl.kind, {"layers": 0, "state_bytes_per_slot": 0,
+                                "seated_state_bytes": 0})
+                row["layers"] += 1
+                row["state_bytes_per_slot"] += sum(
+                    leaf.row_bytes(native) for leaf in leaves)
+        self._stats_acc = {
+            kind: jnp.zeros((len(names), len(decl.fields), 2), jnp.uint32)
+            for kind, (decl, names, _) in self._counted.items()}
+        self._stats_maxima = tuple(sorted(
+            (kind, tuple(f in decl.maxima for f in decl.fields))
+            for kind, (decl, _, _) in self._counted.items()))
         self._io = {"decode": _HostIO("decode"),
                     "prefill": _HostIO("prefill", widths=True)}
         self._prefill_chaos = prefill_chaos
@@ -868,14 +907,10 @@ class GenerationEngine:
                "host_io": dict(
                    {k: io.as_dict() for k, io in self._io.items()},
                    input_form="ids" if takes_ids(self.net) else "one-hot")}
-        if self._expert_names:
-            out["experts"] = self._expert_counters()
-        if self._sparse_names:
-            high, low = (int(v) for v in
-                         np.asarray(self._stats_acc["attended"]))
-            out["sparse_attn"] = dict(
-                self._sparse,
-                attended_positions=(high << _LOW_BITS) + low)
+        for kind in self._counted:
+            out[kind] = {**self._slot_state.get(kind, {}),
+                         **self._host_counts.get(kind, {}),
+                         **self._layer_counters(kind)}
         if self._pool is not None:
             out["kv_pages"] = {"total": self._pool.usable,
                                "used": self._pool.used_count(),
@@ -915,19 +950,17 @@ class GenerationEngine:
         out["last_events"] = list(self._own_events)
         return out
 
-    def _count_sparse(self, contexts) -> None:
-        """One dispatch's share of the host counts of
-        ``health()["sparse_attn"]``, from its rows alone, summed over the
-        sparse-attention layers: `contexts` = positions each REAL query
-        of the dispatch may see (itself included); ``selected`` is what
-        the selection keeps of them. (What the programs scored,
-        ``attended_positions``, the layers count themselves.)"""
-        contexts = np.asarray(contexts, np.int64)
-        c = self._sparse
-        for top in self._sparse_topk:
-            c["query_positions"] += len(contexts)
-            c["context_positions"] += int(contexts.sum())
-            c["selected_positions"] += int(np.minimum(contexts, top).sum())
+    def _count_rows(self, contexts) -> None:
+        """One dispatch's share of the host counts of ``health()``, from
+        its rows alone: `contexts` = positions each REAL query of the
+        dispatch may see (itself included), handed to every layer that
+        declared a ``host`` function beside its counters (sparse
+        selection: what it keeps of them. What the programs scored the
+        layers count themselves)."""
+        for kind, total in self._host_counts.items():
+            for host in self._counted[kind][2]:
+                for field, n in host(contexts).items():
+                    total[field] = total.get(field, 0) + n
 
     def _take_stats(self, state: dict) -> dict:
         """Take one dispatch's layer counters out of the stream state it
@@ -938,35 +971,25 @@ class GenerationEngine:
             return state
         state = dict(state)
         stats = {}
-        for kind, key, names in (
-                ("experts", "moe_stats", self._expert_names),
-                ("attended", "attn_stats", self._sparse_names)):
+        for kind, (decl, names, _) in self._counted.items():
             for n in names:
                 state[n] = d = dict(state[n])
-                stats.setdefault(kind, []).append(d.pop(key))
-        self._stats_acc = _join_stats(self._stats_acc, stats)
+                stats.setdefault(kind, []).append(d.pop(decl.key))
+        self._stats_acc = _join_stats(self._stats_acc, stats,
+                                      maxima=self._stats_maxima)
         return state
 
-    def _expert_counters(self) -> dict:
-        """``health()["experts"]``: the device accumulator fetched HERE
+    def _layer_counters(self, kind: str) -> dict:
+        """What the layers of `kind` have counted, summed over them (a
+        maximum field: the largest): the device accumulator fetched HERE
         (from any thread, no step lock: it is complete up to the last
-        dispatch that finished) and summed over the expert layers. The
-        device sums are int32 and wrap; this keeps Python integers and
-        adds the difference since its last reading, so it is exact as
-        long as ``health()`` is read once in 2^32 routed tokens a
-        layer."""
-        now = np.asarray(self._stats_acc["experts"])
-        sums = now[:, :3].astype(np.int32).view(np.uint32)
-        with self._expert_mutex:
-            moved = (sums - self._expert_seen).sum(axis=0, dtype=np.uint64)
-            self._expert_seen = sums.copy()
-            for i in range(3):
-                self._expert_host[i] += int(moved[i])
-            self._expert_host[3] = max(self._expert_host[3],
-                                       int(now[:, 3].max()))
-            tokens, pairs, rows, load = self._expert_host
-        return {"tokens": tokens, "held_pairs": pairs,
-                "rows_computed": rows, "max_expert_load": load}
+        dispatch that finished), as Python integers."""
+        decl = self._counted[kind][0]
+        now = np.asarray(self._stats_acc[kind]).astype(np.uint64)
+        values = (now[..., 0] << _LOW_BITS) + now[..., 1]   # [layers, fields]
+        return {f: int(values[:, i].max() if f in decl.maxima
+                       else values[:, i].sum())
+                for i, f in enumerate(decl.fields)}
 
     @property
     def page_pool(self) -> Optional[PagePool]:
@@ -1492,8 +1515,8 @@ class GenerationEngine:
             p0 = prime_prompt(net, prime_ids[hit_len:], self.V,
                               padded=self._prime_padded,
                               io=self._io["prefill"])
-            if self._sparse_names:
-                self._count_sparse(np.arange(hit_len, len(prime_ids)) + 1)
+            if self._host_counts:
+                self._count_rows(np.arange(hit_len, len(prime_ids)) + 1)
             req.trace.record("prefill_end")
             next_phase("engine.seat")
             primed_pos = self._net_pos(net)
@@ -1574,6 +1597,8 @@ class GenerationEngine:
                                       "admission unaffected")
         self._slots[slot] = req
         self._row_pos[slot] = primed_pos
+        for row in self._slot_state.values():
+            row["seated_state_bytes"] += row["state_bytes_per_slot"]
         req.pending_token = tok
         req.trace.record("seat", engine=self.trace_identity, slot=slot)
         self._sync_accounting()
@@ -2258,9 +2283,9 @@ class GenerationEngine:
         self._greedy_rows += live - drawn
         self._drawn_rows += drawn
         self._block_fetches += drawn > 0
-        if self._sparse_names:
+        if self._host_counts:
             seated = [s for s, r in enumerate(self._slots) if r is not None]
-            self._count_sparse(self._row_pos[seated] + 1)
+            self._count_rows(self._row_pos[seated] + 1)
         for s, req in enumerate(self._slots):
             if req is not None:
                 self._row_pos[s] += 1
@@ -2534,8 +2559,9 @@ class GenerationEngine:
                 if k in _SCATTER_KEYS and (n, k) not in excl]
         arena_leaves = [arena_state[n][k] for n, k in self._merge_keys]
         primed_leaves = [primed_state[n][k] for n, k in self._merge_keys]
-        new_leaves = _scatter_rows(arena_leaves, primed_leaves,
-                                   np.int32(slot))
+        scatter = (_scatter_rows_donated if self._state_donated
+                   else _scatter_rows)
+        new_leaves = scatter(arena_leaves, primed_leaves, np.int32(slot))
         out = {n: (dict(v) if isinstance(v, dict) else v)
                for n, v in arena_state.items()}
         for (n, k), leaf in zip(self._merge_keys, new_leaves):

@@ -79,7 +79,11 @@ SERVING_SAMPLE_ROWS = "dl4jtpu_serving_sample_rows_total"
 SERVING_BLOCK_FETCHES = "dl4jtpu_serving_block_fetches_total"
 
 #: ``engine.health()`` keys that are no registry series (read where the
-#: payload is read; a net without such layers has neither key):
+#: payload is read; a net without such layers has none of them). The
+#: layers DECLARE them (``nn.conf.layers.StreamCounters``: the key of
+#: their kind, the fields, which of them are maxima, and a ``host``
+#: function where part is counted from a dispatch's rows); the engine
+#: tests no attribute of a layer for them:
 #:
 #: ``experts`` — a net with ``RoutedExpertsLayer``s: the layers'
 #: ``moe_stats`` summed over layers and over everything served so far.
@@ -109,6 +113,20 @@ SERVING_BLOCK_FETCHES = "dl4jtpu_serving_block_fetches_total"
 #: chunked priming) every cache slot of every row of its query blocks,
 #: in paged decode the gathered index_topk of all S rows. 1 - selected /
 #: attended is attention work the selection had already ruled out.
+#:
+#: ``linear_attn`` — a net with linear-attention layers
+#: (``GatedDeltaNetLayer``), summed over those layers. From what they
+#: declare they keep a stream (``slot_leaves()``): ``layers``,
+#: ``state_bytes_per_slot`` (the float32 state and the convolution's
+#: tail of every such layer, one slot's row), ``seated_state_bytes``
+#: (that, times the admissions seated: what the seats' scatter wrote).
+#: Counted by the layers inside the device programs (``gdn_stats``,
+#: joined and fetched like the experts' counters): ``scanned_positions``
+#: every position a prime's chunked scan went over (left pads and the
+#: fill to whole chunks included), ``fed_positions`` the real ones among
+#: them (1 - fed / scanned is scan work a bucket's padding cost),
+#: ``state_updates`` one-step updates (every row of every decode
+#: dispatch, idle slots too).
 
 #: fleet layer (serving/fleet/router.py registers these): multi-replica
 #: routing, prefix-affinity placement, ledger migration, autoscaling.

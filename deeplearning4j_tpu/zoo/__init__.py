@@ -19,4 +19,5 @@ from deeplearning4j_tpu.zoo.inception_resnet import InceptionResNetV1, FaceNetNN
 from deeplearning4j_tpu.zoo.text_lstm import TextGenerationLSTM
 from deeplearning4j_tpu.zoo.transformer import TextGenerationTransformer  # noqa: F401
 from deeplearning4j_tpu.zoo.sparse_latent_moe import SparseLatentMoETransformer  # noqa: F401
+from deeplearning4j_tpu.zoo.hybrid_linear import HybridLinearTransformer  # noqa: F401
 from deeplearning4j_tpu.zoo.imagenet import ImageNetLabels  # noqa: F401
