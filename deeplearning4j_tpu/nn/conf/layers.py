@@ -1032,6 +1032,25 @@ class PositionalEmbeddingLayer(FeedForwardLayerConf):
         return _act.get(self.activation)(y), new_state
 
 
+def _paged_append(pool, page, off, rows):
+    """Write a chunk's rows into a [P, Hkv, page_size, D] pool leaf:
+    ``rows[n, t, h]`` (a [D] vector) lands at ``pool[page[n, t], h,
+    off[n, t]]``. One scatter of N·T·Hkv rows into the leaf seen as
+    rows, [P·Hkv·page_size, D] (a bitcast of the row-major leaf): XLA
+    then keeps the leaf in the layout the Mosaic kernel and the
+    program's entry and result hold it in, and updates the donated leaf
+    in place. (Indexed over page and row-in-page alone —
+    ``pool.at[page, :, off, :]``, a [Hkv, D] window a token — the TPU
+    compiler lays the leaf out head-minor for the scatter and copies
+    the whole leaf before and after it, every layer, every step.)
+    Duplicate targets (idle and masked rows on the null page 0) are as
+    harmless as any write there: nothing reads it."""
+    p, hkv, ps, d = pool.shape
+    row = (page[..., None] * hkv + jnp.arange(hkv)) * ps + off[..., None]
+    return pool.reshape(p * hkv * ps, d).at[row].set(rows).reshape(
+        pool.shape)
+
+
 @register_layer
 @dataclass
 class SelfAttentionLayer(FeedForwardLayerConf):
@@ -1343,10 +1362,12 @@ class SelfAttentionLayer(FeedForwardLayerConf):
         (``kv_page_k``/``kv_page_v`` — [P, Hkv, page_size, D]) and the
         per-row page table (``kv_page_table`` — [N, n_max], 0 = null
         page), installed by the serving engine around its decode
-        dispatches. The chunk's new tokens append with ONE
-        [N, T, Hkv, D] scatter at each row's ``(page, offset)`` — an
-        O(one-token) write — then the queries attend against the pool
-        through the table, by this layer's ``paged_read``:
+        dispatches. The chunk's new tokens append with one scatter a
+        leaf (``_paged_append``): N·T·Hkv rows of [D], each at the row
+        its ``(page, head, offset)`` names — an O(one-token) write into
+        the donated leaf, in the layout it already has — then the
+        queries attend against the pool through the table, by this
+        layer's ``paged_read``:
 
         - ``"xla"`` impl (any backend): the ``pool[table]`` gather is
           folded into this dispatch and feeds the SAME
@@ -1454,11 +1475,11 @@ class SelfAttentionLayer(FeedForwardLayerConf):
             vq, vsc = quantize_chunk(vt, vsc, page, q_pos, pos,
                                      writable, page_size=ps,
                                      chunk0=chunk0)
-            kp = kp.at[page, :, off, :].set(kq)
-            vp = vp.at[page, :, off, :].set(vq)
+            kp = _paged_append(kp, page, off, kq)
+            vp = _paged_append(vp, page, off, vq)
         else:
-            kp = kp.at[page, :, off, :].set(kt.astype(kp.dtype))
-            vp = vp.at[page, :, off, :].set(vt.astype(vp.dtype))
+            kp = _paged_append(kp, page, off, kt.astype(kp.dtype))
+            vp = _paged_append(vp, page, off, vt.astype(vp.dtype))
         impl, interpret = self.paged_read
         if impl == "pallas" and not prime:
             from deeplearning4j_tpu.serving.paged_kernel import (

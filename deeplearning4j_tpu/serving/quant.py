@@ -31,8 +31,13 @@ logit MAE on the test models — tests/test_serving_quant.py), never
 bit-parity with bf16: the round-trip error per element is bounded by
 sigma / 2 <= amax * 2 / 127 (pow2ceil at most doubles amax / 127).
 
-The write path lives in ``SelfAttentionLayer._stream_attend_paged``
-(quantize_chunk below is its per-leaf worker); the read paths dequant
+The write path lives in ``SelfAttentionLayer._stream_attend_paged``:
+quantize_chunk below prices a chunk of a leaf and ratchets its sidecar,
+and the int8 rows then go into the pool through the same
+``_paged_append`` the bf16 pool uses (one scatter a leaf: a [D] row
+for each ``(page, head, offset)`` into the leaf seen as rows; the
+``[P, Hkv]`` sidecar has its own small scatter over pages). The read
+paths dequant
 in ``_stream_attend_paged``'s folded gather (XLA) and in
 ``serving/paged_kernel.py``'s VMEM inner loop (Pallas, scales riding
 the scalar-prefetch refs).

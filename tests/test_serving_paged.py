@@ -4,12 +4,14 @@ arena, token-budget admission (incl. the oversized-request submit
 rejection), page lifecycle/eviction, chaos page exhaustion, telemetry,
 and the zero-retraces-after-warmup guard with every mode on."""
 
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from deeplearning4j_tpu import monitoring
 from deeplearning4j_tpu.monitoring import runtime
 from deeplearning4j_tpu.monitoring.metrics import MetricsRegistry
+from deeplearning4j_tpu.nn.conf import layers as conf_layers
 from deeplearning4j_tpu.resilience import chaos
 from deeplearning4j_tpu.serving import (
     GenerationEngine, PagedKVConfig, SpeculationConfig)
@@ -482,3 +484,120 @@ class TestNoRetracePagedAfterWarmup:
         assert eng.prefix_cache.hits > 0      # the hit path really ran
         assert _compile_total() == warm, (
             "paged/speculative serving retraced after warmup")
+
+
+# ---------------------------------------------------------------------
+# the append's form: same bytes at the same places as the scatter over
+# page and row alone, which it replaced (PR 35)
+# ---------------------------------------------------------------------
+def _append_page_and_row(pool, page, off, rows):
+    """The form the layer had: a [Hkv, D] window a token."""
+    return pool.at[page, :, off, :].set(rows)
+
+
+class TestPagedAppendForm:
+    """``_stream_attend_paged`` run twice over the same state, once with
+    ``_paged_append`` and once with the old scatter in its place: the
+    pools (and the int8 sidecars) come out byte for byte the same, and
+    every page no row appends to is as it was."""
+    PS, HKV, D, L, N_BLK, PAGES = 4, 2, 8, 32, 8, 24
+
+    def layer(self):
+        return conf_layers.SelfAttentionLayer(
+            n_in=32, n_out=32, n_heads=4, n_kv_heads=self.HKV, rope=True,
+            cache_length=self.L)
+
+    def state(self, kv_dtype, pos, table, prime=False):
+        rng = np.random.default_rng(3)
+        shape = (self.PAGES, self.HKV, self.PS, self.D)
+        st = {"kv_pos": jnp.asarray(pos, jnp.int32),
+              "kv_page_table": jnp.asarray(table, jnp.int32)}
+        for name in ("k", "v"):
+            if kv_dtype == "int8":
+                st["kv_page_" + name] = jnp.asarray(
+                    rng.integers(-127, 128, shape), jnp.int8)
+                st["kv_page_scale_" + name] = jnp.asarray(
+                    2.0 ** rng.integers(-6, -2, shape[:2]), jnp.float32)
+            else:
+                st["kv_page_" + name] = jnp.asarray(
+                    rng.normal(size=shape), jnp.bfloat16)
+        if prime:
+            st["kv_page_prime"] = jnp.zeros((), jnp.int32)
+        return st
+
+    def both_forms(self, monkeypatch, st, t, pad_left=None):
+        rng = np.random.default_rng(4)
+        n = st["kv_pos"].shape[0]
+        q = jnp.asarray(rng.normal(size=(n, 4, t, self.D)), jnp.bfloat16)
+        k, v = (jnp.asarray(rng.normal(size=(n, self.HKV, t, self.D)),
+                            jnp.bfloat16) for _ in range(2))
+        layer = self.layer()
+        _, new = layer._stream_attend_paged(q, k, v, dict(st),
+                                            pad_left=pad_left)
+        monkeypatch.setattr(conf_layers, "_paged_append",
+                            _append_page_and_row)
+        _, old = layer._stream_attend_paged(q, k, v, dict(st),
+                                            pad_left=pad_left)
+        return new, old
+
+    def check(self, st, new, old, written):
+        """Same bytes off the null page (where masked rows collide and
+        nothing reads), and no page but ``written`` touched."""
+        rest = np.setdiff1d(np.arange(1, self.PAGES), written)
+        for key in sorted(k for k in st if k.startswith("kv_page_")
+                          and k not in ("kv_page_table", "kv_page_prime")):
+            a, b, was = (np.asarray(x[key]) for x in (new, old, st))
+            assert a.dtype == b.dtype == was.dtype, key
+            assert a[1:].tobytes() == b[1:].tobytes(), key
+            assert a[rest].tobytes() == was[rest].tobytes(), key
+        touched = np.asarray(new["kv_page_k"]) != np.asarray(st["kv_page_k"])
+        assert touched[np.asarray(written)].any()
+        assert np.array_equal(np.asarray(new["kv_pos"]),
+                              np.asarray(old["kv_pos"]))
+
+    @pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+    @pytest.mark.parametrize("t", [1, 5])
+    def test_decode_and_verify_chunks_write_the_same_bytes(
+            self, monkeypatch, kv_dtype, t):
+        """Rows 0 and 1 share the read-only prefix page 9 and append in
+        pages of their own; row 2 is idle (an empty table: the null
+        page); row 3 runs past its capacity inside the chunk."""
+        table = np.zeros((4, self.N_BLK), np.int32)
+        table[0, :4] = [9, 2, 3, 4]
+        table[1, :4] = [9, 5, 6, 7]
+        table[3, :] = np.arange(10, 18)
+        pos = [6, 4, 0, self.L - 2]
+        st = self.state(kv_dtype, pos, table)
+        new, old = self.both_forms(monkeypatch, st, t)
+        written = {1: [2, 5, 17], 5: [2, 3, 5, 6, 17]}[t]
+        self.check(st, new, old, written)
+        for out in (new, old):                  # the shared page is read-only
+            assert np.asarray(out["kv_page_k"])[9].tobytes() == \
+                np.asarray(st["kv_page_k"])[9].tobytes()
+
+    @pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+    def test_a_left_padded_prime_chunk_writes_the_same_bytes(
+            self, monkeypatch, kv_dtype):
+        """Prime through the pool, one row: 3 pads and the 4 positions
+        of a prefix hit (page 9) go to the null page, the 5 fresh ones
+        to pages 2 and 3."""
+        table = np.zeros((1, self.N_BLK), np.int32)
+        table[0, :3] = [9, 2, 3]
+        st = self.state(kv_dtype, [4], table, prime=True)
+        new, old = self.both_forms(monkeypatch, st, 8,
+                                   pad_left=jnp.asarray(3, jnp.int32))
+        self.check(st, new, old, [2, 3])
+        assert int(new["kv_pos"][0]) == 9
+
+    def test_the_helper_alone_writes_the_null_page_too(self):
+        """No two rows collide here, so the null page compares as well."""
+        rng = np.random.default_rng(5)
+        pool = jnp.asarray(rng.normal(size=(6, 3, 4, 8)), jnp.bfloat16)
+        page = jnp.asarray([[0, 0], [2, 3], [5, 5]], jnp.int32)
+        off = jnp.asarray([[1, 2], [3, 0], [0, 1]], jnp.int32)
+        rows = jnp.asarray(rng.normal(size=(3, 2, 3, 8)), jnp.bfloat16)
+        a = np.asarray(conf_layers._paged_append(pool, page, off, rows))
+        b = np.asarray(_append_page_and_row(pool, page, off, rows))
+        assert a.tobytes() == b.tobytes()
+        assert a[0, 1, 2].tobytes() == np.asarray(rows)[0, 1, 1].tobytes()
+        assert a[[1, 4]].tobytes() == np.asarray(pool)[[1, 4]].tobytes()
