@@ -16,6 +16,7 @@ LSTMParamInitializer...). Param names follow the reference ("W", "b", "RW",
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 from dataclasses import dataclass, field
 from typing import (Any, Dict, List, NamedTuple, Optional, Sequence,
@@ -1045,10 +1046,47 @@ def _paged_append(pool, page, off, rows):
     the whole leaf before and after it, every layer, every step.)
     Duplicate targets (idle and masked rows on the null page 0) are as
     harmless as any write there: nothing reads it."""
+    rows_of, row = _paged_rows(pool, page, off)
+    return rows_of.at[row].set(rows).reshape(pool.shape)
+
+
+def _paged_rows(pool, page, off):
+    """A [P, Hkv, page_size, D] leaf seen as rows, [P·Hkv·page_size, D],
+    and the rows ``[..., Hkv]`` that hold the tokens at ``(page, off)``."""
     p, hkv, ps, d = pool.shape
     row = (page[..., None] * hkv + jnp.arange(hkv)) * ps + off[..., None]
-    return pool.reshape(p * hkv * ps, d).at[row].set(rows).reshape(
-        pool.shape)
+    return pool.reshape(p * hkv * ps, d), row
+
+
+#: the least width of a cache leaf's rows that the TPU runtime keeps
+#: row-major on the device. A narrower leaf (an index key of 64) is given a
+#: page-minor layout, and every program that appends to it or gathers from
+#: it copies the whole leaf there and back (seen in a compile for a
+#: described v5e: two pool-shaped copies a layer a decode step), so such a
+#: leaf is kept this wide, zeros past its own width
+_LEAF_LANES = 128
+
+
+#: the most key slots a block of the masked form scores at once. One
+#: product-mask-softmax over a block's [Hkv, reps, 128, S] float32 scores
+#: is a single fusion on the TPU, and past some S between 6,144 and 8,192
+#: it falls off a cliff (alone on a v5e, 32 heads of 128: 0.04 ms a block
+#: at 6,144 slots, 11.7 ms at 8,192; a prime of 8,192 took 208 ms a layer
+#: of which 188 were the blocks of its last causal group), so a longer
+#: span goes in pieces of this many slots joined by the running maximum
+_KEY_SPAN = 4096
+
+
+def _paged_gather(pool, page, off):
+    """Tokens out of a [P, Hkv, page_size, D] pool leaf: ``[..., Hkv, D]``
+    for ``page`` / ``off`` [...], the token at ``pool[page, :, off]`` —
+    read as rows of the leaf seen as [P·Hkv·page_size, D], the view
+    ``_paged_append`` writes through. (Indexed over page and row-in-page
+    alone, a [Hkv, D] window a token, the TPU compiler copies the whole
+    leaf into a head-minor layout first: two pool-shaped copies a layer a
+    step, seen in a compile for a described v5e.)"""
+    rows_of, row = _paged_rows(pool, page, off)
+    return rows_of[row]
 
 
 @register_layer
@@ -1092,7 +1130,34 @@ class SelfAttentionLayer(FeedForwardLayerConf):
     [Hkv * D]; statistics in float32, ``qk_norm_eps``) — the decoders
     that bound their attention logits this way. Both off by default: a
     saved configuration and its programs stay as they were.
+    ``qk_norm="head"`` norms each head of both over its own ``D`` channels
+    instead (one gain [D] for queries, one for keys).
     ``stream_query_block`` bounds what a long prime keeps of its scores.
+    ``head_dim`` gives the heads a width of their own (``Wq`` [F, H * D],
+    ``Wo`` [H * D, n_out]) where it is not ``n_out / n_heads``.
+
+    Learned sparse selection (``index_topk`` > 0; off by default, and
+    then no leaf, no cache and no program differs): an indexer beside the
+    projections — ``q^I = x Wiq`` (``index_n_heads`` x ``index_head_dim``),
+    one index key a token ``k^I = LayerNorm(x Wik)`` (``ik_gamma``,
+    ``ik_beta``, eps ``qk_norm_eps``), both rotated over their whole width where ``rope`` is on
+    (half-split pairs, ``rope_base``), ``w = x Wiw Hi^-1/2 Di^-1/2`` —
+    scores every earlier position ``I(t, s) = sum_j w_tj relu(q^I_tj .
+    k^I_s)``, and query t attends the ``min(index_topk, t + 1)`` of highest
+    I, ties to the lower index (``nn/layers/sparse_latent.py``). The index
+    key is a third cache leaf, ``kv_i`` [N, 1, L, max(Di, 128)] (zeros
+    past Di: ``_LEAF_LANES``), beside ``kv_k`` and ``kv_v``. Two forms of one function. MASKED (training forward, dense
+    streaming, so a serving engine's prime): queries in blocks of
+    ``stream_query_block``, a block's index scores against the key slots,
+    the exact top-k as a mask, grouped-query attention under it; a
+    stream's first chunk attends its own keys, slot for query, in up to
+    four causal groups. GATHERED (paged decode, a page table in the
+    state): the index keys of a row's whole context are read through the
+    table and scored, and only the selected positions' keys and values are
+    gathered out of the pool and attended. Products take operands as they
+    come and accumulate in float32; index scores and softmax are float32.
+    Either streaming form adds to ``attn_stats`` the positions whose
+    attention scores it computed (``stream_counters()``).
     """
 
     n_heads: int = 4
@@ -1107,8 +1172,17 @@ class SelfAttentionLayer(FeedForwardLayerConf):
     #: kernel skips out-of-window blocks). None = full attention.
     window: Optional[int] = None
     has_bias: bool = True
-    qk_norm: bool = False
+    #: False | True (over the whole projected width) | "head" (each head
+    #: over its own channels)
+    qk_norm: Any = False
     qk_norm_eps: float = 1e-6
+    #: a head's width where it is not ``n_out // n_heads``
+    head_dim: Optional[int] = None
+    #: the indexer (``index_topk`` 0: none): its heads, their width, and
+    #: how many positions a query keeps
+    index_n_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
     #: queries a streaming chunk attends at once (None: all of them, one
     #: [H, T, L] float32 score tensor). A prime of T positions against a
     #: cache of L keeps T * L * H * 4 bytes of scores and as many of
@@ -1132,22 +1206,62 @@ class SelfAttentionLayer(FeedForwardLayerConf):
             raise ValueError("SelfAttentionLayer needs RNN input [N,F,T]")
         return InputType.recurrent(self.n_out or it.size, it.timesteps)
 
+    @property
+    def head_width(self) -> int:
+        return self.head_dim or self.n_out // self.n_heads
+
+    @property
+    def selects(self) -> bool:
+        return self.index_topk > 0
+
     def paged_leaves(self):
         """The cache leaves a page pool holds for this layer: keys and
-        values, [Hkv, D] a token, tokens on axis 1 ([P, Hkv, page, D])."""
+        values, [Hkv, D] a token, tokens on axis 1 ([P, Hkv, page, D]);
+        with an indexer also the index key, [1, Di] a token."""
         hkv = self.n_kv_heads or self.n_heads
-        d = self.n_out // self.n_heads
-        return (PagedLeaf("kv_k", (hkv, d), 1),
-                PagedLeaf("kv_v", (hkv, d), 1))
+        d = self.head_width
+        leaves = (PagedLeaf("kv_k", (hkv, d), 1),
+                  PagedLeaf("kv_v", (hkv, d), 1))
+        if self.selects:
+            leaves += (PagedLeaf("kv_i", (1, self._index_leaf_width), 1),)
+        return leaves
+
+    @property
+    def _index_leaf_width(self) -> int:
+        return max(self.index_head_dim, _LEAF_LANES)
+
+    def _index_leaf(self, ki):
+        """An index key [..., Di] as its cache leaf holds it."""
+        pad = self._index_leaf_width - ki.shape[-1]
+        return jnp.pad(ki, [(0, 0)] * (ki.ndim - 1) + [(0, pad)])
+
+    def stream_counters(self):
+        """With an indexer, ``LatentAttentionLayer``'s declaration: a net
+        has one kind of selecting layer, so one ``health()`` key."""
+        if not self.selects:
+            return None
+        return _selection_counters(self)
+
+    def paged_read_tokens(self) -> Dict[str, int]:
+        """Tokens of each leaf one row's paged decode reads where the
+        layer selects: the whole context's index keys, the selected
+        positions' keys and values."""
+        if not self.selects:
+            return {}
+        top = min(self.index_topk, self.cache_length)
+        return {"kv_k": top, "kv_v": top, "kv_i": self.cache_length}
 
     def init(self, key, it):
         if self.n_in is None:
             self.n_in = it.size
         if self.n_out is None:
             self.n_out = self.n_in
-        if self.n_out % self.n_heads:
+        if self.head_dim is None and self.n_out % self.n_heads:
             raise ValueError(f"n_out {self.n_out} not divisible by "
                              f"n_heads {self.n_heads}")
+        if self.qk_norm not in (False, True, "head"):
+            raise ValueError(f"qk_norm is False, True or 'head', got "
+                             f"{self.qk_norm!r}")
         if self.n_kv_heads is not None and self.n_kv_heads < 1:
             raise ValueError(f"n_kv_heads must be >= 1, got "
                              f"{self.n_kv_heads}")
@@ -1155,7 +1269,7 @@ class SelfAttentionLayer(FeedForwardLayerConf):
         if self.n_heads % hkv:
             raise ValueError(f"n_heads {self.n_heads} not divisible by "
                              f"n_kv_heads {hkv}")
-        d = self.n_out // self.n_heads
+        d = self.head_width
         if self.rope and d % 2:
             raise ValueError(f"rope needs an even head dim, got {d} "
                              f"(n_out {self.n_out} / n_heads "
@@ -1165,18 +1279,41 @@ class SelfAttentionLayer(FeedForwardLayerConf):
                 raise ValueError("window attention requires causal=True")
             if self.window < 1:
                 raise ValueError(f"window must be >= 1, got {self.window}")
+        if self.selects:
+            if not self.causal or self.window is not None:
+                raise ValueError("an indexer selects among the earlier "
+                                 "positions: causal=True, no window")
+            if self.index_n_heads < 1 or self.index_head_dim < 1 or (
+                    self.rope and self.index_head_dim % 2):
+                raise ValueError(
+                    f"index_topk {self.index_topk} needs index_n_heads "
+                    f"and an (even, with rope) index_head_dim, got "
+                    f"{self.index_n_heads} x {self.index_head_dim}")
         keys = jax.random.split(key, 4)
         p = {}
         for i, name in enumerate(("q", "k", "v", "o")):
-            n_in = self.n_in if name != "o" else self.n_out
-            n_out = hkv * d if name in ("k", "v") else self.n_out
+            n_in = self.n_in if name != "o" else self.n_heads * d
+            n_out = hkv * d if name in ("k", "v") else \
+                self.n_out if name == "o" else self.n_heads * d
             p["W" + name] = init_weights(keys[i], (n_in, n_out), n_in,
                                          n_out, self.weight_init, self.dist)
             if self.has_bias:
                 p["b" + name] = jnp.zeros((n_out,), jnp.float32)
-        if self.qk_norm:
-            p["q_norm"] = jnp.ones((self.n_out,), jnp.float32)
+        if self.qk_norm == "head":
+            p["q_norm"] = jnp.ones((d,), jnp.float32)
+            p["k_norm"] = jnp.ones((d,), jnp.float32)
+        elif self.qk_norm:
+            p["q_norm"] = jnp.ones((self.n_heads * d,), jnp.float32)
             p["k_norm"] = jnp.ones((hkv * d,), jnp.float32)
+        if self.selects:
+            hi, di = self.index_n_heads, self.index_head_dim
+            ikeys = jax.random.split(jax.random.fold_in(key, 1), 3)
+            for k, (name, width) in zip(ikeys, (("Wiq", hi * di),
+                                                ("Wik", di), ("Wiw", hi))):
+                p[name] = init_weights(k, (self.n_in, width), self.n_in,
+                                       width, self.weight_init, self.dist)
+            p["ik_gamma"] = jnp.ones((di,), jnp.float32)
+            p["ik_beta"] = jnp.zeros((di,), jnp.float32)
         return p, {}
 
     def apply(self, params, x, state, *, train=False, rng=None, mask=None,
@@ -1188,21 +1325,32 @@ class SelfAttentionLayer(FeedForwardLayerConf):
         n, f, t = x.shape
         h = self.n_heads
         hkv = self.n_kv_heads or h
-        d = self.n_out // h
+        d = self.head_width
         xt = jnp.transpose(x, (0, 2, 1))                    # [N,T,F]
+        per_head = self.qk_norm == "head"
 
         def proj(name, heads):
             y = xt @ params["W" + name]
             if self.has_bias:
                 y = y + params["b" + name]
-            if self.qk_norm and name in ("q", "k"):
+            normed = self.qk_norm and name in ("q", "k")
+            if normed and not per_head:
                 with jax.named_scope("attn.qk_norm"):
                     y = _rms_norm(y, params[name + "_norm"],
                                   self.qk_norm_eps)
-            return y.reshape(n, t, heads, d).transpose(0, 2, 1, 3)
+            y = y.reshape(n, t, heads, d)
+            if normed and per_head:
+                with jax.named_scope("attn.qk_norm"):
+                    y = _rms_norm(y, params[name + "_norm"],
+                                  self.qk_norm_eps)
+            return y.transpose(0, 2, 1, 3)
 
-        q = proj("q", h)                                    # [N,H,T,D]
-        k, v = proj("k", hkv), proj("v", hkv)               # [N,Hkv,T,D]
+        # the scopes name the selecting layer's parts in a profile; a
+        # layer without an indexer lowers as it always did
+        with self._scope("gqa.project"):
+            q = proj("q", h)                                # [N,H,T,D]
+            k, v = proj("k", hkv), proj("v", hkv)           # [N,Hkv,T,D]
+        idx = self._index_project(params, xt) if self.selects else None
         if self.rope and not stream:
             pos = jnp.arange(t)
             q = self._rope(q, pos)
@@ -1211,7 +1359,16 @@ class SelfAttentionLayer(FeedForwardLayerConf):
             # cache the Hkv-sized K/V (the GQA memory win), expand at
             # attend time inside _stream_attend
             o, state = self._stream_attend(q, k, v, state, mask,
-                                           pad_left=pad_left)
+                                           pad_left=pad_left, idx=idx)
+        elif self.selects:
+            pos = jnp.arange(t, dtype=jnp.int32)[None]
+            qi, ki, w = idx
+            key_valid = None if mask is None else \
+                jnp.asarray(mask).reshape(n, t).astype(bool)
+            o, _ = self._attend_selected(
+                q, k, v, (self._rope_index(qi, pos), w),
+                self._rope_index(ki, pos)[:, None], pos, key_valid,
+                aligned=True)
         else:
             k, v = self._expand_kv(k, v)
             # variable-length batches: mask KEYS with -inf score bias
@@ -1219,14 +1376,161 @@ class SelfAttentionLayer(FeedForwardLayerConf):
             o = blockwise_attention(q, k, v, causal=self.causal,
                                     block_size=self.block_size,
                                     key_mask=mask, window=self.window)
-        o = o.transpose(0, 2, 1, 3).reshape(n, t, self.n_out)
-        o = o @ params["Wo"]
-        if self.has_bias:
-            o = o + params["bo"]
+        with self._scope("gqa.project"):
+            o = o.transpose(0, 2, 1, 3).reshape(n, t, h * d)
+            o = o @ params["Wo"]
+            if self.has_bias:
+                o = o + params["bo"]
         y = jnp.transpose(o, (0, 2, 1))                     # [N,F,T]
         return _act.get(self.activation)(y), state
 
-    def _stream_attend(self, q, k, v, state, mask=None, pad_left=None):
+    def _scope(self, name: str):
+        return jax.named_scope(name) if self.selects \
+            else contextlib.nullcontext()
+
+    # -- learned sparse selection (index_topk > 0) -------------------------
+    def _index_project(self, params, xt):
+        """The indexer's parts of a chunk before positions enter:
+        ``(q^I [N,T,Hi,Di], k^I [N,T,Di], w [N,T,Hi])``."""
+        n, t, _ = xt.shape
+        hi, di = self.index_n_heads, self.index_head_dim
+        with jax.named_scope("gqa.index"):
+            qi = (xt @ params["Wiq"]).reshape(n, t, hi, di)
+            ki = _sl.layer_norm(xt @ params["Wik"], params["ik_gamma"],
+                                params["ik_beta"], self.qk_norm_eps)
+            w = (xt @ params["Wiw"]) * (hi ** -0.5 * di ** -0.5)
+        return qi, ki, w.astype(xt.dtype)
+
+    def _rope_index(self, x, positions):
+        """The layer's rotation over the whole index width: x
+        [N, T, (Hi,) Di], positions [N|1, T] (pads' -1 clamped)."""
+        if not self.rope:
+            return x
+        half = x.shape[-1] // 2
+        inv = self.rope_base ** (-jnp.arange(half, dtype=jnp.float32)
+                                 / half)
+        cos, sin = _sl.rope_tables(jnp.maximum(positions, 0), inv)
+        return _sl.rope_half(x, cos, sin)
+
+    def _attend_selected(self, q, kc, vc, idx, ic, q_pos, key_valid=None,
+                         aligned=False):
+        """The masked form: queries q [N,H,T,D] with their index parts
+        ``idx`` = (q^I [N,T,Hi,Di], w [N,T,Hi]) against kc / vc
+        [N,Hkv,L,D] and the index keys ic [N,1,L,Di]; query t may see the
+        slots ``<= q_pos[t]`` (q_pos [N|1, T]) that ``key_valid`` [N|1, L]
+        admits, and attends the ``index_topk`` of them its index scores
+        put first. Queries go in blocks of ``stream_query_block``
+        (``sparse_latent.QUERY_BLOCK`` where unset), a block's key slots
+        in spans of ``_KEY_SPAN`` joined by the running maximum: a
+        block's [N, H, B, span] scores exist at once, the chunk's never. ``aligned``
+        says the keys are the chunk's own, slot for query: the blocks
+        then go in up to four groups, each against the prefix of the keys
+        that ends where its last query stands. Returns ``(o [N,H,T,D],
+        scored)``, ``scored`` the (query row, slot) pairs whose attention
+        scores it computed."""
+        qi, w = idx
+        n, h, t, d = q.shape
+        hkv, L = kc.shape[1], kc.shape[2]
+        reps = h // hkv
+        slot = jnp.arange(L, dtype=jnp.int32)
+        q_pos = jnp.broadcast_to(q_pos, (n, t)).astype(jnp.int32)
+        b, pad, groups = _sl.query_groups(
+            t, L, aligned, self.stream_query_block or _sl.QUERY_BLOCK)
+
+        def blocks(a, axis):
+            width = [(0, 0)] * a.ndim
+            width[axis] = (0, pad)
+            a = jnp.pad(a, width)
+            a = a.reshape(a.shape[:axis] + ((t + pad) // b, b)
+                          + a.shape[axis + 1:])
+            return jnp.moveaxis(a, axis, 0)
+
+        def attend(args, seen):
+            """One block of queries against the first ``seen`` slots."""
+            qb, qib, wb, pos = args
+            valid = slot[None, None, :seen] <= pos[..., None]   # [N,B,s]
+            if key_valid is not None:
+                valid = valid & key_valid[:, None, :seen]
+            with jax.named_scope("gqa.index"):
+                scores = _sl.index_scores(
+                    qib, ic[:, 0, :seen, :qib.shape[-1]], wb)
+            with jax.named_scope("gqa.select"):
+                sel = _sl.top_k_mask(scores, valid, self.index_topk)
+            with jax.named_scope("gqa.attend"):
+                qg = qb.reshape(n, hkv, reps, b, d)
+                o = m = z = None
+                for lo in range(0, seen, _KEY_SPAN):
+                    hi = min(seen, lo + _KEY_SPAN)
+                    s = jnp.einsum("ngrtd,ngld->ngrtl", qg, kc[:, :, lo:hi],
+                                   preferred_element_type=jnp.float32)
+                    s = jnp.where(sel[:, None, None, :, lo:hi],
+                                  s * d ** -0.5, _sl.MASKED)
+                    top = jnp.max(s, axis=-1, keepdims=True)
+                    e = jnp.exp(s - top)
+                    part = jnp.einsum("ngrtl,ngld->ngrtd",
+                                      e.astype(vc.dtype), vc[:, :, lo:hi],
+                                      preferred_element_type=jnp.float32)
+                    total = jnp.sum(e, axis=-1, keepdims=True)
+                    if o is None:
+                        o, m, z = part, top, total
+                    else:
+                        new = jnp.maximum(m, top)
+                        was, now = jnp.exp(m - new), jnp.exp(top - new)
+                        o, z, m = (o * was + part * now,
+                                   z * was + total * now, new)
+                return (o / z).reshape(n, h, b, d).astype(q.dtype)
+
+        parts = (blocks(q, 2), blocks(qi, 1), blocks(w, 1),
+                 blocks(q_pos, 1))
+        out, at = [], 0
+        for per, seen in groups:
+            out.append(jax.lax.map(
+                lambda args, seen=seen: attend(args, seen),
+                tuple(a[at:at + per] for a in parts)))
+            at += per
+        o = jnp.moveaxis(jnp.concatenate(out), 0, 2)     # [N,H,nb,B,D]
+        return (o.reshape(n, h, t + pad, d)[:, :, :t],
+                n * sum(per * b * seen for per, seen in groups))
+
+    def _attend_gathered(self, q, kp, vp, ip, table, idx, q_pos):
+        """The gathered form behind a page table: every row scores the
+        index keys of its whole context (read through ``table``), keeps
+        the ``index_topk`` best of the positions ``<= q_pos``, gathers
+        those positions' keys and values out of the pools kp / vp
+        [P,Hkv,ps,D] and attends them and nothing else. Returns
+        ``(o [N,H,T,D], scored)``."""
+        qi, w = idx
+        n, h, t, d = q.shape
+        hkv, ps = kp.shape[1], kp.shape[2]
+        n_blk, L = table.shape[1], self.cache_length
+        with jax.named_scope("gqa.index"):
+            ki = ip[table].reshape(n, n_blk * ps, -1)[:, :L,
+                                                      :qi.shape[-1]]
+            scores = _sl.index_scores(qi, ki, w)                 # [N,T,L]
+        with jax.named_scope("gqa.select"):
+            live = jnp.arange(L)[None, None, :] <= q_pos[..., None]
+            top = min(self.index_topk, L)
+            best, sel = jax.lax.top_k(
+                jnp.where(live, scores, -jnp.inf), top)          # [N,T,k]
+            chosen = best > -jnp.inf
+        with jax.named_scope("gqa.gather"):
+            rows = jnp.arange(n)[:, None, None]
+            page = table[rows, jnp.minimum(sel // ps, n_blk - 1)]
+            off = sel % ps
+            kg = _paged_gather(kp, page, off)               # [N,T,k,Hkv,D]
+            vg = _paged_gather(vp, page, off)
+        with jax.named_scope("gqa.attend"):
+            qg = q.reshape(n, hkv, h // hkv, t, d)
+            s = jnp.einsum("ngrtd,ntkgd->ngrtk", qg, kg,
+                           preferred_element_type=jnp.float32)
+            s = jnp.where(chosen[:, None, None], s * d ** -0.5, _sl.MASKED)
+            a = jax.nn.softmax(s, axis=-1).astype(vg.dtype)
+            o = jnp.einsum("ngrtk,ntkgd->ngrtd", a, vg,
+                           preferred_element_type=jnp.float32)
+        return o.reshape(n, h, t, d).astype(q.dtype), n * t * top
+
+    def _stream_attend(self, q, k, v, state, mask=None, pad_left=None,
+                       idx=None):
         """Incremental decode: append k/v to the carried cache, attend q
         against it. Positions past cache_length are a caller error (the
         dynamic_update_slice would clamp) — size cache_length to the max
@@ -1257,7 +1561,7 @@ class SelfAttentionLayer(FeedForwardLayerConf):
             # pool + table in place of a dense cache — read through the
             # table, append one token per row in place
             return self._stream_attend_paged(q, k, v, state, mask=mask,
-                                             pad_left=pad_left)
+                                             pad_left=pad_left, idx=idx)
         n, _, t, d = q.shape
         hkv = k.shape[1]                 # cache holds n_kv_heads heads
         L = self.cache_length
@@ -1303,6 +1607,17 @@ class SelfAttentionLayer(FeedForwardLayerConf):
             return self._stream_attend_rolling(
                 q, k, v, state, kc, vc, pos, mask, fresh=fresh,
                 m0=m0, q_pos=q_pos, n_new=n_new, vec=vec)
+        if self.selects:
+            if mask is not None or vec or state.get("kv_mask") is not None:
+                raise ValueError(
+                    "a SelfAttentionLayer with an indexer streams "
+                    "maskless chunks at a shared position (left-pad with "
+                    "pad_left), and per-row positions only behind a page "
+                    "table (the serving engine's paged decode)")
+            return self._stream_attend_selecting(
+                q, k, v, idx, state, kc, vc, pos, m0=m0, q_pos=q_pos,
+                n_new=n_new,
+                fresh=fresh and state.get("kv_pos") is None)
         z = jnp.zeros((), pos.dtype)
         if vec:
             # per-row scatter at each row's own slots (advanced indexing
@@ -1356,8 +1671,40 @@ class SelfAttentionLayer(FeedForwardLayerConf):
             out["kv_mask"] = km
         return o, out
 
+    def _stream_attend_selecting(self, q, k, v, idx, state, kc, vc, pos, *,
+                                 m0, q_pos, n_new, fresh):
+        """Dense streaming with an indexer: the chunk's keys, values and
+        index keys go to the [N, ., L, .] caches at the shared position
+        (left pads dropped), then the masked form: of a stream's FIRST
+        chunk (``fresh``: a prime) against the chunk's own keys, slot for
+        query, the pads masked; of a later chunk against the whole
+        cache."""
+        n, _, t, _ = q.shape
+        L = self.cache_length
+        qi, ki, w = idx
+        ipos = (q_pos if m0 is None else jnp.maximum(q_pos, 0))[None]
+        qi, ki = self._rope_index(qi, ipos), self._rope_index(ki, ipos)
+        ki = self._index_leaf(ki)[:, None]               # [N, 1, T, W]
+        ic = state.get("kv_i")
+        if ic is None:
+            ic = jnp.zeros((n, 1, L, ki.shape[-1]), ki.dtype)
+        slots = q_pos if m0 is None else jnp.where(m0, q_pos, L)
+        kc, vc, ic = (c.at[:, :, slots, :].set(new.astype(c.dtype),
+                                               mode="drop")
+                      for c, new in ((kc, k), (vc, v), (ic, ki)))
+        if fresh:
+            o, scored = self._attend_selected(
+                q, k, v, (qi, w), ki,
+                jnp.arange(t, dtype=jnp.int32)[None],
+                None if m0 is None else m0[None], aligned=True)
+        else:
+            o, scored = self._attend_selected(q, kc, vc, (qi, w), ic,
+                                              q_pos[None])
+        return o, {**_attended(state, scored), "kv_k": kc, "kv_v": vc,
+                   "kv_i": ic, "kv_pos": pos + n_new}
+
     def _stream_attend_paged(self, q, k, v, state, mask=None,
-                             pad_left=None):
+                             pad_left=None, idx=None):
         """Direct paged decode: K/V live in the block-paged pool
         (``kv_page_k``/``kv_page_v`` — [P, Hkv, page_size, D]) and the
         per-row page table (``kv_page_table`` — [N, n_max], 0 = null
@@ -1425,6 +1772,11 @@ class SelfAttentionLayer(FeedForwardLayerConf):
         table = state["kv_page_table"]
         ksc = state.get("kv_page_scale_k")
         quant = ksc is not None
+        if self.selects and (prime or quant):
+            raise ValueError(
+                "a SelfAttentionLayer with an indexer keeps a third, "
+                "unquantized leaf a token (kv_i): the int8 pool and its "
+                "prime through the pool know keys and values only")
         pos = state.get("kv_pos")
         if pos is None or getattr(pos, "ndim", 0) < 1:
             raise ValueError(
@@ -1481,6 +1833,20 @@ class SelfAttentionLayer(FeedForwardLayerConf):
             kp = _paged_append(kp, page, off, kt.astype(kp.dtype))
             vp = _paged_append(vp, page, off, vt.astype(vp.dtype))
         impl, interpret = self.paged_read
+        if self.selects:
+            # the gathered form, whatever ``paged_read`` says: the kernel
+            # walks whole live pages and cannot skip tokens
+            qi, ki, w = idx
+            ip = _paged_append(
+                state["kv_page_i"], page, off,
+                self._index_leaf(self._rope_index(ki, q_pos))[
+                    :, :, None].astype(state["kv_page_i"].dtype))
+            o, scored = self._attend_gathered(
+                q, kp, vp, ip, table, (self._rope_index(qi, q_pos), w),
+                q_pos)
+            return o, {**_attended(state, scored), "kv_page_k": kp,
+                       "kv_page_v": vp, "kv_page_i": ip,
+                       "kv_pos": pos + n_new}
         if impl == "pallas" and not prime:
             from deeplearning4j_tpu.serving.paged_kernel import (
                 paged_attention)
@@ -2296,6 +2662,31 @@ def stream_counters(layer) -> Optional[StreamCounters]:
     return declare() if declare is not None else None
 
 
+def _selection_counters(layer) -> StreamCounters:
+    """What a layer that attends ``layer.index_topk`` selected positions
+    counts (one declaration for every such layer: a net has one kind of
+    them, so one ``health()`` key): on the device the positions whose
+    attention scores its forms computed (``_attended``); on the host, from
+    a dispatch's queries by the positions each may see, how many there
+    were, how many positions lay before them, and how many of those the
+    selection keeps."""
+    def selected(contexts) -> Dict[str, int]:
+        contexts = np.asarray(contexts, np.int64)
+        return {"query_positions": len(contexts),
+                "context_positions": int(contexts.sum()),
+                "selected_positions": int(
+                    np.minimum(contexts, layer.index_topk).sum())}
+
+    return StreamCounters("attn_stats", "sparse_attn",
+                          ("attended_positions",), host=selected)
+
+
+def _attended(state, scored: int):
+    """``state`` with ``scored`` more positions in ``attn_stats``."""
+    return {**state, "attn_stats": jnp.asarray(scored, jnp.int32)
+            + state.get("attn_stats", 0)}
+
+
 def _rms_norm(x, gamma, eps: float):
     """RMSNorm over the last axis, float32 statistics."""
     xf = x.astype(jnp.float32)
@@ -2548,19 +2939,7 @@ class LatentAttentionLayer(FeedForwardLayerConf):
                 PagedLeaf("kv_i", (self.index_head_dim,), 0))
 
     def stream_counters(self):
-        return StreamCounters(
-            "attn_stats", "sparse_attn", ("attended_positions",),
-            host=self._selected_counts)
-
-    def _selected_counts(self, contexts) -> Dict[str, int]:
-        """A dispatch's queries by the positions each may see: how many
-        there were, how many positions lay before them, and how many of
-        those the selection keeps."""
-        contexts = np.asarray(contexts, np.int64)
-        return {"query_positions": len(contexts),
-                "context_positions": int(contexts.sum()),
-                "selected_positions": int(
-                    np.minimum(contexts, self.index_topk).sum())}
+        return _selection_counters(self)
 
     def paged_read_tokens(self) -> Dict[str, int]:
         """Tokens of each leaf one row's paged decode reads (the serving
@@ -2570,21 +2949,8 @@ class LatentAttentionLayer(FeedForwardLayerConf):
         return {"kv_c": top, "kv_r": top, "kv_i": self.cache_length}
 
     def _query_groups(self, t: int, slots: int, aligned: bool):
-        """How ``_attend_per_head`` takes ``t`` queries against ``slots``
-        key slots: ``(block, pad, [(blocks, seen), ...])`` — queries in
-        blocks of ``block`` (``pad`` rows added to fill the last), and
-        per group of consecutive blocks how many it has and how many
-        leading slots their scores span. Unaligned: one group, every
-        slot. Aligned (slot for query): up to four groups, each against
-        the prefix that ends where its last query stands."""
-        b = min(_sl.QUERY_BLOCK, t)
-        pad = -t % b
-        n_blocks = (t + pad) // b
-        groups = next(g for g in (4, 2, 1) if n_blocks % g == 0) \
-            if aligned else 1
-        per = n_blocks // groups
-        return b, pad, [(per, min(slots, (g + 1) * per * b) if aligned
-                         else slots) for g in range(groups)]
+        """``sparse_latent.query_groups`` in blocks of ``QUERY_BLOCK``."""
+        return _sl.query_groups(t, slots, aligned, _sl.QUERY_BLOCK)
 
     @property
     def softmax_scale(self) -> float:
@@ -2679,13 +3045,8 @@ class LatentAttentionLayer(FeedForwardLayerConf):
         with jax.named_scope("dsa.index"):
             qi = (cq @ p["Wiq"]).reshape(n, t, self.index_n_heads,
                                          self.index_head_dim)
-            ki = xt @ p["Wik"]
-            kf = ki.astype(jnp.float32)
-            mean = jnp.mean(kf, axis=-1, keepdims=True)
-            var = jnp.mean((kf - mean) ** 2, axis=-1, keepdims=True)
-            ki = ((kf - mean) * jax.lax.rsqrt(var + self.eps)
-                  * p["ik_gamma"].astype(jnp.float32)
-                  + p["ik_beta"].astype(jnp.float32)).astype(xt.dtype)
+            ki = _sl.layer_norm(xt @ p["Wik"], p["ik_gamma"], p["ik_beta"],
+                                self.eps)
             w = (xt @ p["Wiw"]) * (self.index_n_heads ** -0.5
                                    * self.index_head_dim ** -0.5)
         return {"q": q, "ckv": ckv, "kr": kva[..., self.kv_lora_rank:],
@@ -2789,12 +3150,6 @@ class LatentAttentionLayer(FeedForwardLayerConf):
         return (o.reshape(n, t + pad, h * dv)[:, :t],
                 n * sum(per * b * seen for per, seen in groups))
 
-    @staticmethod
-    def _counted(state, scored: int):
-        """``state`` with ``scored`` more positions in ``attn_stats``."""
-        return {**state, "attn_stats": jnp.asarray(scored, jnp.int32)
-                + state.get("attn_stats", 0)}
-
     def _stream_dense(self, p, proj, state, mask, pad_left):
         """Dense streaming: append the chunk's three leaves to the
         [N, L, .] caches at a shared scalar position (left pads dropped,
@@ -2847,7 +3202,7 @@ class LatentAttentionLayer(FeedForwardLayerConf):
         else:
             o, scored = self._attend_per_head(p, q, q_pos[None],
                                               tuple(caches))
-        out = {**self._counted(state, scored), "kv_pos": pos + n_new}
+        out = {**_attended(state, scored), "kv_pos": pos + n_new}
         out.update({leaf.key: c for leaf, c in zip(leaves, caches)})
         return o, out
 
@@ -2908,7 +3263,7 @@ class LatentAttentionLayer(FeedForwardLayerConf):
             o = jnp.einsum("nqhc,chd->nqhd", o_lat, w_kvb[..., dn:],
                            preferred_element_type=jnp.float32
                            ).astype(c.dtype)
-        out = {**self._counted(state, n * t * top), "kv_pos": pos + t}
+        out = {**_attended(state, n * t * top), "kv_pos": pos + t}
         out.update({leaf.page_key: pool
                     for leaf, pool in zip(leaves, pools)})
         return o.reshape(n, t, h * dv), out
@@ -2918,9 +3273,12 @@ class LatentAttentionLayer(FeedForwardLayerConf):
 @dataclass
 class RoutedExpertsLayer(FeedForwardLayerConf):
     """A layer of routed experts that is told which experts it holds:
-    the router is the model's (``router_experts`` outputs, grouped
-    sigmoid choice with a selection bias, ``top_k`` a token, gates scaled
-    by ``scale``; nn/layers/routed_experts.py), the expert matrices are
+    the router is the model's (``router_experts`` outputs; ``scoring``
+    ``"sigmoid"``, the default: grouped sigmoid choice with a selection
+    bias ``br``; ``"softmax"``: softmax over all outputs, no groups and
+    no bias leaf; ``top_k`` a token, the chosen gates renormalised where
+    ``norm_topk`` and scaled by ``scale``; nn/layers/routed_experts.py),
+    the expert matrices are
     those of ``held`` = (first, count) alone, and the layer gives
     ``shared(x) + sum over held i of g_i E_i(x)``, every expert a gated
     (SiLU) feed-forward ``hidden`` wide. What the experts held elsewhere
@@ -2930,12 +3288,15 @@ class RoutedExpertsLayer(FeedForwardLayerConf):
 
     Streaming (``rnn_time_step``) computes the held part by the grouped
     product, tile by tile over tokens laid out by expert, dropping no
-    token; left pads route nowhere. It carries ``moe_stats`` int32 [4] in
+    token; left pads route nowhere. It carries ``moe_stats`` int32 [6] in
     its state — tokens routed, (token, held expert) pairs, rows the
     grouped product computed (whole tiles), the fullest expert's load in
-    one call (a maximum, the others sums) — which the serving engine
-    reads in ``health()["experts"]``. The training forward runs every
-    held expert over every token (differentiable)."""
+    one call (a maximum, the others sums), and over the calls of one
+    position a row (decode steps) how many there were and how many held
+    experts got at least one token in them (the expert weights such a
+    step cannot do without, whatever computes the product) — which the
+    serving engine reads in ``health()["experts"]``. The training forward
+    runs every held expert over every token (differentiable)."""
 
     hidden: int = 64
     router_experts: int = 8
@@ -2945,10 +3306,17 @@ class RoutedExpertsLayer(FeedForwardLayerConf):
     top_groups: int = 1
     scale: float = 1.0
     shared: int = 1
+    scoring: str = "sigmoid"
+    norm_topk: bool = True
 
     supports_streaming = True
 
     def __post_init__(self):
+        if self.scoring not in ("sigmoid", "softmax") or (
+                self.scoring == "softmax" and self.groups != 1):
+            raise ValueError(
+                f"scoring is 'sigmoid' or 'softmax' (which has no "
+                f"groups), got {self.scoring!r} with {self.groups} groups")
         self.held = (int(self.held[0]), int(self.held[1]))
         first, count = self.held
         if not (0 <= first and count >= 1
@@ -2963,7 +3331,8 @@ class RoutedExpertsLayer(FeedForwardLayerConf):
     def stream_counters(self):
         return StreamCounters(
             "moe_stats", "experts",
-            ("tokens", "held_pairs", "rows_computed", "max_expert_load"),
+            ("tokens", "held_pairs", "rows_computed", "max_expert_load",
+             "decode_calls", "decode_experts_touched"),
             maxima=("max_expert_load",))
 
     def output_type(self, it):
@@ -2983,10 +3352,11 @@ class RoutedExpertsLayer(FeedForwardLayerConf):
             return init_weights(k, shape, a, b, self.weight_init, self.dist)
 
         p = {"Wr": w(ks[0], (f, self.router_experts), f,
-                     self.router_experts),
-             "br": jnp.zeros((self.router_experts,), jnp.float32),
-             "Wg": w(ks[1], (g, f, i), f, i), "Wu": w(ks[2], (g, f, i), f, i),
-             "Wd": w(ks[3], (g, i, self.n_out), i, self.n_out)}
+                     self.router_experts)}
+        if self.scoring == "sigmoid":
+            p["br"] = jnp.zeros((self.router_experts,), jnp.float32)
+        p.update(Wg=w(ks[1], (g, f, i), f, i), Wu=w(ks[2], (g, f, i), f, i),
+                 Wd=w(ks[3], (g, i, self.n_out), i, self.n_out))
         if self.shared:
             s = i * self.shared
             p.update(Ws_g=w(ks[4], (f, s), f, s), Ws_u=w(ks[5], (f, s), f, s),
@@ -3006,9 +3376,10 @@ class RoutedExpertsLayer(FeedForwardLayerConf):
         first, count = self.held
         with jax.named_scope("moe.route"):
             gates = _re.router_gates(
-                flat, params["Wr"], params["br"], groups=self.groups,
+                flat, params["Wr"], params.get("br"), groups=self.groups,
                 top_groups=self.top_groups, top_k=self.top_k,
-                scale=self.scale)[:, first:first + count]
+                scale=self.scale, scoring=self.scoring,
+                norm_topk=self.norm_topk)[:, first:first + count]
             if valid is not None:
                 gates = jnp.where(valid.reshape(-1, 1), gates, 0.0)
         with jax.named_scope("moe.experts"):
@@ -3021,11 +3392,16 @@ class RoutedExpertsLayer(FeedForwardLayerConf):
                 tokens = n * t if valid is None else jnp.sum(valid)
                 prev = state.get("moe_stats")
                 if prev is None:
-                    prev = jnp.zeros((4,), jnp.int32)
+                    prev = jnp.zeros((6,), jnp.int32)
+                # a call of one position a row is a decode step: the held
+                # experts with a token in it are the weights it needs
+                decode = int(t == 1)
+                touched = jnp.sum(jnp.any(gates > 0, axis=0))
                 state = {**state, "moe_stats": jnp.stack([
                     prev[0] + tokens, prev[1] + stats[0],
                     prev[2] + stats[1],
-                    jnp.maximum(prev[3], stats[2])]).astype(jnp.int32)}
+                    jnp.maximum(prev[3], stats[2]), prev[4] + decode,
+                    prev[5] + decode * touched]).astype(jnp.int32)}
             else:
                 y = _re.dense_experts(flat, gates, params["Wg"],
                                       params["Wu"], params["Wd"])

@@ -1,6 +1,6 @@
 """The arithmetic of ``RoutedExpertsLayer`` (nn/conf/layers.py): the
-grouped sigmoid router over ALL of a model's experts, and the product over
-the experts this device holds.
+router over ALL of a model's experts (grouped sigmoid, or softmax), and
+the product over the experts this device holds.
 
 The held experts' part is a grouped product: the (token, held expert)
 pairs are laid out expert by expert, each expert's run padded to whole
@@ -32,19 +32,31 @@ def gated_ffn(x, wg, wu, wd):
 
 
 def router_gates(x, wr, br, *, groups: int, top_groups: int, top_k: int,
-                 scale: float):
+                 scale: float, scoring: str = "sigmoid",
+                 norm_topk: bool = True):
     """Gates [T, R] float32 over all R experts of the router, 0 where a
-    token did not choose the expert. ``sigma = sigmoid(x W_r)`` in
-    float32 whatever the compute dtype (a choice among 256 scores is
-    not made in 8 bits of mantissa); choice by ``sigma + b``: the
-    ``top_groups`` groups whose two best sum highest, then the ``top_k``
-    best experts of those, ties to the lower index; gates
-    ``sigma_i / sum sigma_i * scale``."""
+    token did not choose the expert. The scores are computed in float32
+    whatever the compute dtype (a choice among hundreds of scores is not
+    made in 8 bits of mantissa). Two scorings:
+
+    - ``"sigmoid"``: ``sigma = sigmoid(x W_r)``; choice by ``sigma + b``:
+      the ``top_groups`` groups whose two best sum highest, then the
+      ``top_k`` best experts of those, ties to the lower index; gates
+      ``sigma_i``;
+    - ``"softmax"``: ``p = softmax(x W_r)`` over all R; the ``top_k``
+      largest, ties to the lower index; gates ``p_i``; no groups and no
+      bias (``br`` is not read).
+
+    The chosen gates are renormalised to sum 1 where ``norm_topk``, and
+    scaled by ``scale``."""
     t, r = x.shape[0], wr.shape[1]
-    sig = jax.nn.sigmoid(jnp.matmul(
-        x.astype(jnp.float32), wr.astype(jnp.float32),
-        precision=lax.Precision.HIGHEST))
-    choice = sig + br.astype(jnp.float32)
+    logits = jnp.matmul(x.astype(jnp.float32), wr.astype(jnp.float32),
+                        precision=lax.Precision.HIGHEST)
+    if scoring == "softmax":
+        score = choice = jax.nn.softmax(logits, axis=-1)
+    else:
+        score = jax.nn.sigmoid(logits)
+        choice = score + br.astype(jnp.float32)
     if groups > 1:
         per = choice.reshape(t, groups, r // groups)
         group_score = jnp.sum(lax.top_k(per, 2)[0], axis=-1)
@@ -54,10 +66,11 @@ def router_gates(x, wr, br, *, groups: int, top_groups: int, top_k: int,
         choice = jnp.where(jnp.repeat(keep, r // groups, axis=1), choice,
                            -jnp.inf)
     chosen = lax.top_k(choice, top_k)[1]                          # [T, k]
-    picked = jnp.take_along_axis(sig, chosen, axis=1)
-    gates = picked / jnp.sum(picked, axis=-1, keepdims=True) * scale
+    picked = jnp.take_along_axis(score, chosen, axis=1)
+    if norm_topk:
+        picked = picked / jnp.sum(picked, axis=-1, keepdims=True)
     return jnp.zeros((t, r), jnp.float32).at[
-        jnp.arange(t)[:, None], chosen].set(gates)
+        jnp.arange(t)[:, None], chosen].set(picked * scale)
 
 
 def dense_experts(x, gates, wg, wu, wd):

@@ -1,6 +1,8 @@
-"""Pieces of ``LatentAttentionLayer`` (nn/conf/layers.py) that are plain
-functions of arrays: YaRN rotary frequencies, the two pairing conventions
-of the rotation, and the exact top-k selection as a mask.
+"""Pieces of the layers that select what they attend (nn/conf/layers.py:
+``LatentAttentionLayer``, and ``SelfAttentionLayer`` with an indexer) that
+are plain functions of arrays: YaRN rotary frequencies, the two pairing
+conventions of the rotation, the index scores, the exact top-k selection
+as a mask, and how a prime's queries go in blocks.
 
 The selection is a search for the k-th largest score's bit pattern, 32
 counting passes over the row, and no sort: a row of 8,192 scores for each
@@ -17,14 +19,44 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-#: queries of a per-head block (``LatentAttentionLayer._query_groups``): a
-#: block's [N, H, 128, L] float32 scores are what exists at once, 0.5 GB
-#: at 128 heads against 8,192 slots
+#: queries of a per-head block (``query_groups``): a block's
+#: [N, H, 128, L] float32 scores are what exists at once, 0.5 GB at 128
+#: heads against 8,192 slots
 QUERY_BLOCK = 128
 
 #: what a masked score is set to: finite, so that a row with no valid
 #: position (a left pad) is garbage and not NaN
 MASKED = -1e30
+
+
+def query_groups(t: int, slots: int, aligned: bool, block: int):
+    """How ``t`` queries are taken against ``slots`` key slots:
+    ``(block, pad, [(blocks, seen), ...])`` — queries in blocks of
+    ``block`` (``pad`` rows added to fill the last), and per group of
+    consecutive blocks how many it has and how many leading slots their
+    scores span. Unaligned: one group, every slot. Aligned (slot for
+    query): up to four groups, each against the prefix that ends where
+    its last query stands, so that a block's scores span on average 5/8
+    of the chunk and not all of it."""
+    b = min(block, t)
+    pad = -t % b
+    n_blocks = (t + pad) // b
+    groups = next(g for g in (4, 2, 1) if n_blocks % g == 0) \
+        if aligned else 1
+    per = n_blocks // groups
+    return b, pad, [(per, min(slots, (g + 1) * per * b) if aligned
+                     else slots) for g in range(groups)]
+
+
+def layer_norm(x, gamma, beta, eps: float):
+    """LayerNorm over the last axis, float32 statistics, x's dtype out
+    (the index key's norm)."""
+    xf = x.astype(jnp.float32)
+    mean = jnp.mean(xf, axis=-1, keepdims=True)
+    var = jnp.mean((xf - mean) ** 2, axis=-1, keepdims=True)
+    return ((xf - mean) * lax.rsqrt(var + eps)
+            * gamma.astype(jnp.float32)
+            + beta.astype(jnp.float32)).astype(x.dtype)
 
 
 def yarn_inv_freq(dim: int, base: float, factor: float, original: int,

@@ -566,7 +566,7 @@ class GenerationEngine:
                 leaf_keys,
                 [(self._pool.total_pages,
                   getattr(l, "n_kv_heads", None) or l.n_heads,
-                  self._ps, l.n_out // l.n_heads)
+                  self._ps, l.head_width)
                  for l in kv_layers] if plain else (),
                 kv_dtype=self._kv_dtype, decode_impl=paging.decode_impl,
                 kernel_interpret=paging.kernel_interpret,
@@ -2163,7 +2163,7 @@ class GenerationEngine:
             if getattr(l, "supports_streaming", False) \
                     and getattr(l, "cache_length", 0):
                 hkv = getattr(l, "n_kv_heads", None) or l.n_heads
-                out.append((n, int(hkv), int(l.n_out // l.n_heads)))
+                out.append((n, int(hkv), int(l.head_width)))
         return sorted(out)
 
     def _init_quant_store(self) -> None:

@@ -92,14 +92,20 @@ SERVING_BLOCK_FETCHES = "dl4jtpu_serving_block_fetches_total"
 #: expert) pairs among them (tokens x top_k x held / router_experts on
 #: average), ``rows_computed`` by the grouped product (whole tiles: 1 -
 #: held_pairs / rows_computed is its padding), ``max_expert_load`` the
-#: most pairs one expert took in one dispatch. Counted inside the device
+#: most pairs one expert took in one dispatch, and over the layers' calls
+#: of one position a row (decode steps) ``decode_calls``, how many there
+#: were, and ``decode_experts_touched``, the held experts that got at
+#: least one token in them, summed (the expert weights a decode step
+#: cannot do without, counted from the gates whatever computes the
+#: product). Counted inside the device
 #: programs and joined on the device behind every dispatch, outside the
 #: donated state; ``health()`` fetches the sum (from any thread, with no
 #: step lock: complete up to the last dispatch that finished) and the
 #: cycle never does.
 #:
 #: ``sparse_attn`` — a net with sparse-selection attention
-#: (``LatentAttentionLayer``), summed over those layers. Host counts from
+#: (``LatentAttentionLayer``, or ``SelfAttentionLayer`` with an indexer),
+#: summed over those layers. Host counts from
 #: each dispatch's rows: ``query_positions`` real queries (prompt tokens
 #: fed, live decode rows), ``context_positions`` the positions they could
 #: see (their own included), ``selected_positions`` what the selection

@@ -20,4 +20,5 @@ from deeplearning4j_tpu.zoo.text_lstm import TextGenerationLSTM
 from deeplearning4j_tpu.zoo.transformer import TextGenerationTransformer  # noqa: F401
 from deeplearning4j_tpu.zoo.sparse_latent_moe import SparseLatentMoETransformer  # noqa: F401
 from deeplearning4j_tpu.zoo.hybrid_linear import HybridLinearTransformer  # noqa: F401
+from deeplearning4j_tpu.zoo.sparse_gqa_moe import SparseGQAMoETransformer  # noqa: F401
 from deeplearning4j_tpu.zoo.imagenet import ImageNetLabels  # noqa: F401

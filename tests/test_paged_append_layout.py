@@ -19,11 +19,16 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from deeplearning4j_tpu.nn.conf.layers import _paged_append
+from deeplearning4j_tpu.nn.conf.layers import (_LEAF_LANES, _paged_append,
+                                                _paged_gather)
 
-#: (cell, pool leaf shape [P, Hkv, page_size, D]), 32 rows each
+#: (cell, pool leaf shape [P, Hkv, page_size, D]), 32 rows each; the
+#: keye configuration's keys and values, and its index key as it is kept
+#: (one "head", 64 wide in a row of 128 lanes)
 POOLS = [("olmo-hybrid-7b", (4993, 30, 16, 128)),
-         ("starcoder2-3b", (2817, 2, 16, 128))]
+         ("starcoder2-3b", (2817, 2, 16, 128)),
+         ("keye-vl-2.0-30b-a3b", (12545, 4, 16, 128)),
+         ("keye-vl-2.0-30b-a3b index key", (12545, 1, 16, 128))]
 ROWS = 32
 
 
@@ -99,3 +104,60 @@ def test_the_reading_sees_the_copies_of_the_head_wide_window(
         lambda pool, page, off, rows: pool.at[page, :, off, :].set(rows),
         shape, 1, jnp.bfloat16, one_chip)
     assert len(pool_copies(hlo, shape)) == 2
+
+
+# ------------------------------------------------- the selecting layer's
+def append_then_read(pool, page, off, rows, table):
+    """What a decode step of the selecting layer does with its index
+    leaf: append the chunk's keys, then read a row's pages through the
+    table."""
+    pool = _paged_append(pool, page, off, rows)
+    return pool, pool[table]
+
+
+def compile_append_then_read(width, sharding):
+    shape = (12545, 1, 16, width)
+
+    def spec(dims, dt):
+        return jax.ShapeDtypeStruct(dims, dt, sharding=sharding)
+
+    return shape, jax.jit(append_then_read, donate_argnums=(0,)).lower(
+        spec(shape, jnp.bfloat16), spec((16, 1), jnp.int32),
+        spec((16, 1), jnp.int32), spec((16, 1, 1, width), jnp.bfloat16),
+        spec((16, 784), jnp.int32)).compile().as_text()
+
+
+def test_the_index_leaf_a_lane_tile_wide_is_appended_and_read_in_place(
+        one_chip, no_compile_cache):
+    """The 64-wide index key is kept in rows of ``_LEAF_LANES``: the
+    runtime then holds the leaf row-major, and the step's append and page
+    gather copy nothing pool-shaped. The control beside it: the leaf 64
+    wide is held page-minor and copied there and back (PERF.md, Open
+    questions: the latent layer's 64-wide leaf has that)."""
+    assert _LEAF_LANES == 128
+    shape, hlo = compile_append_then_read(_LEAF_LANES, one_chip)
+    assert pool_copies(hlo, shape) == []
+    narrow, hlo = compile_append_then_read(64, one_chip)
+    assert len(pool_copies(hlo, narrow)) >= 1
+
+
+def compile_gather(gather, shape, sharding):
+    def spec(dims, dt):
+        return jax.ShapeDtypeStruct(dims, dt, sharding=sharding)
+
+    return jax.jit(gather).lower(
+        spec(shape, jnp.bfloat16), spec((16, 1, 2048), jnp.int32),
+        spec((16, 1, 2048), jnp.int32)).compile().as_text()
+
+
+def test_the_selected_tokens_are_gathered_with_no_copy_of_the_pool(
+        one_chip, no_compile_cache):
+    """``_paged_gather`` reads the selected 2,048 tokens of 16 rows as
+    rows of the leaf; indexed over page and row-in-page alone (a [Hkv, D]
+    window a token) the compiler copies the whole leaf first."""
+    shape = POOLS[2][1]
+    assert pool_copies(compile_gather(_paged_gather, shape, one_chip),
+                       shape) == []
+    windowed = compile_gather(lambda pool, page, off: pool[page, :, off],
+                              shape, one_chip)
+    assert len(pool_copies(windowed, shape)) == 1
