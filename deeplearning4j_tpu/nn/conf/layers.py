@@ -2292,6 +2292,9 @@ class RnnOutputLayer(BaseOutputLayerConf):
     """Per-timestep dense + loss over [N,C,T] (ref: conf/layers/RnnOutputLayer.java)."""
 
     has_bias: bool = True
+    #: whether the STREAMING form answers for the last position only,
+    #: whatever the caller asked (``LastStepOutputLayer``)
+    last_step_only = False
 
     def output_type(self, it):
         return InputType.recurrent(self.n_out, it.timesteps)
@@ -2861,6 +2864,21 @@ class LastStepOutputLayer(RnnOutputLayer):
         with jax.named_scope("head.last"):
             y, _ = super().apply(params, x[:, :, -1:], state)
         return y[:, :, 0], state
+
+
+def narrows_to_last(layer) -> bool:
+    """Whether a streaming call whose caller reads the chunk's last
+    position only (``rnn_time_step(last_only=True)``) may hand `layer`
+    the last position of its input: an ``RnnOutputLayer`` answers
+    position t from position t alone. A ``LastStepOutputLayer`` has
+    narrowed itself already and is left as it is."""
+    return isinstance(layer, RnnOutputLayer) and not layer.last_step_only
+
+
+def last_position(y):
+    """``[N, C]`` of an output over a time axis ``[N, C, T]``: its last
+    position. An output that has no time axis is returned as it is."""
+    return y[:, :, -1] if y.ndim == 3 else y
 
 
 @register_layer

@@ -24,7 +24,7 @@ from deeplearning4j_tpu.nn.conf.graph_conf import LayerVertex
 from deeplearning4j_tpu.nn.conf.inputs import InputType
 from deeplearning4j_tpu.nn.conf.layers import (
     STREAM_STATE_KEYS, BaseOutputLayerConf, CenterLossOutputLayer,
-    paged_reads, stream_capacity)
+    last_position, narrows_to_last, paged_reads, stream_capacity)
 from deeplearning4j_tpu.nn.conf.network import ComputationGraphConfiguration
 from deeplearning4j_tpu.nn.score import LazyScore
 from deeplearning4j_tpu.nn.updater import normalize_gradients
@@ -589,7 +589,7 @@ class ComputationGraph(LazyScore):
     # ------------------------------------------------------------------
     def _forward(self, params, state, inputs: Dict[str, Any], *, train, rng,
                  fmasks: Optional[Dict[str, Any]] = None, carry_rnn=False,
-                 stream=False, pad=None, preout_of=None):
+                 stream=False, pad=None, preout_of=None, last_only=False):
         """Topo-order forward (ref: feedForward :1361). Returns
         (vertex_activations dict, new_state, masks dict). `preout_of` is a
         vertex name or a collection of names whose output layers should
@@ -601,7 +601,12 @@ class ComputationGraph(LazyScore):
         (single-input graphs): non-streaming vertices see an ordinary key
         mask; streaming cache layers get pad_left for packed slot
         accounting (pads never enter caches) — see
-        SelfAttentionLayer._stream_attend."""
+        SelfAttentionLayer._stream_attend.
+
+        `last_only` (rnn_time_step's: the caller will read the chunk's
+        last position only) hands each head of `_last_only_heads` the
+        last position of its input, so its product and its softmax run
+        over one column and no [N, V, T] block exists."""
         preout_set = ({preout_of} if isinstance(preout_of, str)
                       else set(preout_of or ()))
         # inference honors the bf16 compute policy too (also applied by
@@ -619,6 +624,7 @@ class ComputationGraph(LazyScore):
                 jnp.arange(a.shape[-1]) >= pad, (a.shape[0], a.shape[-1]))
                 for name, a in inputs.items()}
         new_state: Dict[str, Any] = {}
+        narrowed = self._last_only_heads() if last_only else ()
         for i, name in enumerate(self._topo):
             v = self.conf.vertices[name]
             ins = self.conf.vertex_inputs.get(name, [])
@@ -682,6 +688,8 @@ class ComputationGraph(LazyScore):
                         # MultiLayerNetwork._forward)
                         extra["pad_left"] = pad
                         m_i = None
+                if name in narrowed:
+                    xs = [xs[0][:, :, -1:]]
                 y, s_new = v.apply(params[name], xs, v_state, train=train,
                                    rng=rng_i, mask=m_i, **extra)
                 acts[name] = y
@@ -1296,7 +1304,7 @@ class ComputationGraph(LazyScore):
 
 
     def rnn_time_step(self, *inputs, masks=None, pad_left=None,
-                      donate_state=False):
+                      donate_state=False, last_only=False):
         """Stateful streaming inference over the graph, carrying RNN h/c in
         self.state across calls (ref: ComputationGraph.rnnTimeStep).
         `masks` maps network-input name -> this chunk's [N, T] key mask
@@ -1307,7 +1315,13 @@ class ComputationGraph(LazyScore):
         graphs only) marks the first pad_left positions as LEFT padding
         with packed accounting — pads never enter caches nor consume
         streaming positions, so any prompt length primes in one dispatch
-        at a bucketed shape (see MultiLayerNetwork.rnn_time_step)."""
+        at a bucketed shape (see MultiLayerNetwork.rnn_time_step).
+
+        `last_only=True` says the caller will read the chunk's last
+        position only (a prime; see MultiLayerNetwork.rnn_time_step):
+        every output over a time axis comes back as [N, C], and a
+        per-position head (`_last_only_heads`) computes that one column
+        alone. The state the call leaves is the same either way."""
         # the process-wide stream-cache sharding keys the cache
         # (flipping it retraces for every net on next use), and so does
         # the page-pool read this net's own attention layers hold.
@@ -1318,22 +1332,27 @@ class ComputationGraph(LazyScore):
         from deeplearning4j_tpu.nn.conf import layers as _L
         padded = pad_left is not None
         donate = donate_state and jax.default_backend() != "cpu"
-        key = ("rnn_step", padded, donate, self.conf.dtype,
+        last_only = bool(last_only)
+        key = ("rnn_step", padded, donate, last_only, self.conf.dtype,
                _L._STREAM_CACHE_SHARDING, self._paged_reads())
         if key not in self._jit_cache:
+            read = last_position if last_only else (lambda y: y)
+
             if padded:
                 def fwd(params, state, ins, rng, pad):
                     acts, new_state, _ = self._forward(
                         params, state, ins, train=False, rng=rng,
-                        fmasks=None, carry_rnn=True, stream=True, pad=pad)
-                    return [_f32_head(acts[o]) for o in
+                        fmasks=None, carry_rnn=True, stream=True, pad=pad,
+                        last_only=last_only)
+                    return [_f32_head(read(acts[o])) for o in
                             self.conf.network_outputs], new_state
             else:
                 def fwd(params, state, ins, rng, fmasks):
                     acts, new_state, _ = self._forward(
                         params, state, ins, train=False, rng=rng,
-                        fmasks=fmasks, carry_rnn=True, stream=True)
-                    return [_f32_head(acts[o]) for o in
+                        fmasks=fmasks, carry_rnn=True, stream=True,
+                        last_only=last_only)
+                    return [_f32_head(read(acts[o])) for o in
                             self.conf.network_outputs], new_state
 
             self._jit_cache[key] = jax.jit(
@@ -1434,6 +1453,19 @@ class ComputationGraph(LazyScore):
             updates[name] = new_pos
         return {**pos, **updates}
 
+
+    def _last_only_heads(self):
+        """The output vertices that `rnn_time_step(last_only=True)` hands
+        the last position of their input: per-position heads
+        (``layers.narrows_to_last``) with no preprocessor before them
+        that feed no other vertex. Any other output over a time axis is
+        computed whole and its last position taken from the result."""
+        fed = {s for ins in self.conf.vertex_inputs.values() for s in ins}
+        return {o for o in self.conf.network_outputs
+                if o not in fed
+                and isinstance(self.conf.vertices[o], LayerVertex)
+                and self.conf.vertices[o].preprocessor is None
+                and narrows_to_last(self.conf.vertices[o].layer)}
 
     def _paged_reads(self):
         """This graph's part of its streaming jit keys: how each of its

@@ -30,6 +30,8 @@ from deeplearning4j_tpu.datasets.iterators import ArrayDataSetIterator, DataSetI
 from deeplearning4j_tpu.nn.conf.layers import (
     STREAM_STATE_KEYS,
     check_stream_budget,
+    last_position,
+    narrows_to_last,
     AutoEncoder,
     BaseOutputLayerConf,
     CenterLossOutputLayer,
@@ -147,7 +149,7 @@ class MultiLayerNetwork(LazyScore):
     # ------------------------------------------------------------------
     def _forward(self, params, state, x, *, train, rng, fmask=None,
                  carry_rnn=False, stream=False, pad=None,
-                 upto: Optional[int] = None):
+                 upto: Optional[int] = None, last_only=False):
         """Pure forward pass. Returns (activation_list, new_state).
 
         activation_list[i] is the OUTPUT of layer i (post preprocessor+layer).
@@ -156,6 +158,11 @@ class MultiLayerNetwork(LazyScore):
         non-streaming layers (LSTM h/c carry-through on masked steps) see
         an ordinary key mask, while streaming cache layers get pad_left
         for packed slot accounting (pads never enter caches).
+
+        `last_only` (rnn_time_step's: the caller will read the chunk's
+        last position only) hands a per-position head
+        (``layers.narrows_to_last``, no preprocessor before it) the last
+        position of its input, so no [N, V, T] block exists.
         """
         acts = []
         new_state = {}
@@ -202,6 +209,9 @@ class MultiLayerNetwork(LazyScore):
                     # packed accounting replaces the mask for cache layers
                     extra["pad_left"] = pad
                     m_i = None
+            if last_only and i == len(self.layers) - 1 and pre is None \
+                    and narrows_to_last(layer):
+                h = h[:, :, -1:]
             h, s_new = layer.apply(p_i, h, li_state, train=train,
                                    rng=rng_i, mask=m_i, **extra)
             mask = layer.output_mask(mask, its[i])
@@ -393,7 +403,7 @@ class MultiLayerNetwork(LazyScore):
 
     def _get_output_fn(self, train: bool, carry_rnn: bool,
                        stream: bool = False, padded: bool = False,
-                       donate: bool = False):
+                       donate: bool = False, last_only: bool = False):
         # the process-wide stream-cache sharding config is part of the
         # key: flipping it retraces the step for EVERY net on next use
         # (a stale compiled step would silently keep the old layout);
@@ -406,25 +416,28 @@ class MultiLayerNetwork(LazyScore):
         # it would just warn, so resolve it off there and share the
         # non-donating trace
         donate = donate and jax.default_backend() != "cpu"
-        key = ("out", train, carry_rnn, stream, padded, donate,
+        key = ("out", train, carry_rnn, stream, padded, donate, last_only,
                self.conf.dtype,
                _L._STREAM_CACHE_SHARDING if stream else None,
                _L.paged_reads(self.layers) if stream else None)
         if key not in self._jit_cache:
+            read = last_position if last_only else (lambda y: y)
             if padded:
                 # left-padded packed chunk: pad count is a TRACED scalar,
                 # so every prompt length shares this one compiled shape
                 def fwd(params, state, x, rng, pad):
                     acts, new_state = self._forward(
                         params, state, x, train=train, rng=rng, fmask=None,
-                        carry_rnn=carry_rnn, stream=stream, pad=pad)
-                    return head(acts[-1]), new_state
+                        carry_rnn=carry_rnn, stream=stream, pad=pad,
+                        last_only=last_only)
+                    return head(read(acts[-1])), new_state
             else:
                 def fwd(params, state, x, rng, fmask):
                     acts, new_state = self._forward(
                         params, state, x, train=train, rng=rng, fmask=fmask,
-                        carry_rnn=carry_rnn, stream=stream)
-                    return head(acts[-1]), new_state
+                        carry_rnn=carry_rnn, stream=stream,
+                        last_only=last_only)
+                    return head(read(acts[-1])), new_state
 
             self._jit_cache[key] = jax.jit(
                 fwd, donate_argnums=(1,) if donate else ())
@@ -763,7 +776,7 @@ class MultiLayerNetwork(LazyScore):
     # RNN streaming state (ref: rnnTimeStep :~2300, rnnClearPreviousState)
     # ------------------------------------------------------------------
     def rnn_time_step(self, x, mask=None, pad_left=None,
-                      donate_state=False):
+                      donate_state=False, last_only=False):
         """Stateful streaming inference: feeds one (or more) timesteps,
         carrying h/c (and attention KV caches) across calls
         (ref: rnnTimeStep). `mask` is this chunk's [N, T] key mask for
@@ -783,8 +796,21 @@ class MultiLayerNetwork(LazyScore):
         direct-paged decode path sets it so the page pools update IN
         PLACE (the O(one-token) append) instead of being copied each
         step. The caller must hold no references to the pre-call state
-        leaves — the returned state is the only live copy."""
+        leaves — the returned state is the only live copy.
+
+        `last_only=True` is the caller saying what it will read: the
+        chunk's last position and nothing else (a prime reads the
+        distribution that follows the prompt; a verify chunk reads every
+        position and does not ask). An output over a time axis then comes
+        back as [N, C] and a per-position head (``RnnOutputLayer``) is
+        handed the last position of its input, so neither the program
+        nor the host holds an [N, C, T] block of which one column is
+        read. A program of its own per shape (part of the jit key); the
+        state the call leaves is the same either way, and a head that
+        answers [N, C] already (``LastStepOutputLayer``) compiles the
+        program it compiled before."""
         x = jnp.asarray(x)
+        last_only = bool(last_only)
         if pad_left is not None:
             if mask is not None:
                 raise ValueError("pad_left and mask are mutually exclusive")
@@ -795,14 +821,16 @@ class MultiLayerNetwork(LazyScore):
             new_pos = check_stream_budget(self, x.shape[-1], self.layers,
                                           pad=pad_left)
             fn = self._get_output_fn(False, True, stream=True, padded=True,
-                                     donate=donate_state)
+                                     donate=donate_state,
+                                     last_only=last_only)
             out, new_state = fn(self.params, self.state, x,
                                 jax.random.PRNGKey(0),
                                 jnp.asarray(pad_left, jnp.int32))
         else:
             new_pos = check_stream_budget(self, x.shape[-1], self.layers)
             fn = self._get_output_fn(False, True, stream=True,
-                                     donate=donate_state)
+                                     donate=donate_state,
+                                     last_only=last_only)
             out, new_state = fn(self.params, self.state, x,
                                 jax.random.PRNGKey(0),
                                 None if mask is None else jnp.asarray(mask))

@@ -113,7 +113,8 @@ popped: page reservation, prefix lookup and install), ``prefill.input`` /
 ``prefill.forward`` / ``prefill.fetch`` (``util.decoding.prime_prompt``:
 the padded prompt as the net takes it — int32 ids, or the host-built
 one-hot of a net that takes none; upload and launch; the result coming
-back), ``engine.seat`` (first draw, arena join, page-table update),
+back, the last position's ``[1, V]`` alone), ``engine.seat`` (first
+draw, arena join, page-table update),
 ``decode.input`` (token vector, position mirrors, paged-view install,
 under speculation the host draft; the one-hot block only for a net that
 takes no ids), ``decode.forward`` (the
@@ -136,9 +137,14 @@ counts at the same boundaries: ``decode_dispatch.rows``, ``sample``
 from their row, ``block_fetches`` = plain cycles that fetched [S, V]),
 ``prefill`` (tokens fed, padded widths dispatched; tokens the prefix
 cache served instead are ``prefix_cache.reused_tokens``) and ``host_io``
-(bytes of the numpy arrays that cross around ``rnn_time_step``, and
-``input_form``, which says what goes up: ``"ids"``, int32, for a net that
-takes ids — every zoo transformer — and ``"one-hot"``, the float32
+(bytes of the numpy arrays that cross around ``rnn_time_step``; under
+``prefill`` also ``results`` and ``result_positions``, the primes whose
+result was fetched and the positions those results held — equal, since
+a prime asks its streaming calls for the last position only and ``[1,
+V]`` comes back whatever the head, where a ``[1, V, P]`` block would
+count P; and ``input_form``, which says what goes up: ``"ids"``, int32,
+for a net that takes ids — every zoo transformer — and ``"one-hot"``,
+the float32
 ``[B, V, T]`` block, otherwise; the engine asks the net,
 ``util.decoding.takes_ids``). Layers declare what they count
 (``stream_counters()`` beside ``paged_leaves()``) and ``health()`` shows
@@ -295,14 +301,20 @@ class _HostIO(RoundTrip):
     ``"prefill"``), handed to ``util/decoding`` as `io`: each step
     switches the cycle to its phase (``decode.input`` …), and the numpy
     arrays handed to ``rnn_time_step`` and fetched from it are counted
-    where they cross. ``health()["host_io"][kind]`` reads the bytes;
-    ``width`` sums the time axis of what was handed over — a prime's
-    padded bucket — where the kind asks for it."""
+    where they cross. ``health()["host_io"][kind]`` reads the bytes.
+    Where the kind asks for it (`widths`: a prime) the time axes are
+    counted too: ``width`` sums what was handed over — a prime's padded
+    bucket — and ``results`` / ``result_positions`` the results fetched
+    and the positions they held: one a result since a prime asks for its
+    last position only (``[1, V]``), its whole bucket where a result
+    came back ``[1, V, P]``."""
 
-    __slots__ = ("h2d_bytes", "d2h_bytes", "width", "_widths", "_phase")
+    __slots__ = ("h2d_bytes", "d2h_bytes", "width", "results",
+                 "result_positions", "_widths", "_phase")
 
     def __init__(self, kind: str, widths: bool = False):
         self.h2d_bytes = self.d2h_bytes = self.width = 0
+        self.results = self.result_positions = 0
         self._widths = widths
         self._phase = {name.split(".")[1]: name for name in PHASES
                        if name.startswith(kind + ".")}
@@ -317,9 +329,16 @@ class _HostIO(RoundTrip):
 
     def d2h(self, p: np.ndarray) -> None:
         self.d2h_bytes += p.nbytes
+        if self._widths:
+            self.results += 1
+            self.result_positions += p.shape[2] if p.ndim == 3 else 1
 
     def as_dict(self) -> dict:
-        return {"h2d_bytes": self.h2d_bytes, "d2h_bytes": self.d2h_bytes}
+        out = {"h2d_bytes": self.h2d_bytes, "d2h_bytes": self.d2h_bytes}
+        if self._widths:
+            out.update(results=self.results,
+                       result_positions=self.result_positions)
+        return out
 
 
 def _seat_rows(arena, primed, slot):

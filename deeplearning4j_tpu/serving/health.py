@@ -65,7 +65,13 @@ SERVING_DISPATCH_LATENCY = "dl4jtpu_serving_decode_dispatch_seconds"
 #: arrays that cross the host boundary around ``rnn_time_step``, by
 #: ``phase`` (decode / prefill) and ``direction`` (h2d / d2h);
 #: ``health()["host_io"]["input_form"]`` says what the h2d bytes are:
-#: ``"ids"`` (int32, 4 bytes a token) or ``"one-hot"`` (float32 [B, V, T])
+#: ``"ids"`` (int32, 4 bytes a token) or ``"one-hot"`` (float32 [B, V, T]).
+#: ``health()["host_io"]["prefill"]`` also counts ``results`` (primes
+#: whose result was fetched) and ``result_positions`` (the positions those
+#: results held): a prime reads its last position only and says so to the
+#: streaming call (``rnn_time_step(last_only=True)``), so ``[1, V]`` comes
+#: back and the two are equal; a ``[1, V, P]`` result would count P, and
+#: the d2h bytes with it (no registry series: read from the payload)
 SERVING_DECODE_ROWS = "dl4jtpu_serving_decode_rows_total"
 SERVING_PREFILL_TOKENS = "dl4jtpu_serving_prefill_tokens_total"
 SERVING_HOST_IO_BYTES = "dl4jtpu_serving_host_io_bytes_total"

@@ -29,7 +29,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from deeplearning4j_tpu.nn.conf.layers import reorder_stream_state
+from deeplearning4j_tpu.nn.conf.layers import (
+    last_position, reorder_stream_state)
 
 
 def _one_hot(rows: np.ndarray, vocab: int) -> np.ndarray:
@@ -63,21 +64,26 @@ def _encode(net, rows, vocab: int) -> np.ndarray:
 
 
 def _last(p):
-    """The last position's ``[B, V]`` of a head's output: what a head
-    that answers for the last position only (``LastStepOutputLayer``)
-    returns as it is, and the last column of a full ``[B, V, T]``."""
-    return p if p.ndim == 2 else p[:, :, -1]
+    """The last position's ``[B, V]`` of a streaming call's output: the
+    ``[B, V]`` of a call that answered for the last position only (the
+    caller asked, ``rnn_time_step(last_only=True)``, or the head is a
+    ``LastStepOutputLayer``) as it is, and the last column of a full
+    ``[B, V, T]`` (``layers.last_position``, which the nets apply
+    inside the program where the caller asked)."""
+    return last_position(p)
 
 
 class RoundTrip:
     """What a caller may watch of one host round trip through
     ``rnn_time_step``: its three steps — ``"input"`` built on the host,
     ``"forward"`` dispatched, the result's ``"fetch"`` — and the numpy
-    arrays that cross. This one watches nothing. The serving engine hands
-    in its own as `io` (``serving.engine._HostIO``: a phase span per step,
-    the bytes counted) for the one dispatch its cycle makes, on the
-    cycling thread; any other caller on that thread (a draft net) passes
-    none and stays inside the phase that is open."""
+    arrays that cross (a prime's result is ``[1, V]``: ``prime_prompt``
+    asks for the last position only). This one watches nothing. The
+    serving engine hands in its own as `io` (``serving.engine._HostIO``:
+    a phase span per step, the bytes counted, and for a prime the
+    positions its result held) for the one dispatch its cycle makes, on
+    the cycling thread; any other caller on that thread (a draft net)
+    passes none and stays inside the phase that is open."""
 
     def step(self, name: str) -> None:
         pass
@@ -374,17 +380,19 @@ def _prime_chunks(n: int, chunk_max: int = None):
 
 
 def _prime(net, ids, vocab: int, chunk_max: int = None,
-           io: RoundTrip = _UNWATCHED):
+           io: RoundTrip = _UNWATCHED, last_only: bool = False):
     """Feed the seed through rnn_time_step in bucketed chunks; returns
     the final chunk's output (its last position is the next-token
     distribution). Stateful streaming makes chunked == one-shot priming
     (pinned by the streaming-vs-full-forward tests). `io` sees input
-    and forward once per chunk."""
+    and forward once per chunk. `last_only` is rnn_time_step's, asked of
+    every chunk (one program a chunk shape; an earlier chunk's answer is
+    read by nobody)."""
     at, out = 0, None
     for c in _prime_chunks(len(ids), chunk_max):
         io.step("input")
         x = _encode(net, np.asarray(ids[at:at + c])[None, :], vocab)
-        out = _forward(net, x, io)
+        out = _forward(net, x, io, last_only=last_only)
         at += c
     return out
 
@@ -430,7 +438,7 @@ def _prime_bucket_cap(net):
 
 
 def _prime_padded(net, ids, vocab: int, chunk_max: int = None,
-                  io: RoundTrip = _UNWATCHED):
+                  io: RoundTrip = _UNWATCHED, last_only: bool = False):
     """Single-dispatch priming: LEFT-pad the prompt to its power-of-two
     bucket and feed ONE rnn_time_step(pad_left=...) with packed pad
     accounting — pads never enter the streaming caches nor consume
@@ -440,20 +448,20 @@ def _prime_padded(net, ids, vocab: int, chunk_max: int = None,
     capacity (padding past it would trip static capacity checks); a
     prompt longer than that capacity — legal for rolling-window streams,
     whose length is unbounded — falls back to chunked priming, which has
-    no minimum chunk shape."""
+    no minimum chunk shape. `last_only` is rnn_time_step's."""
     io.step("input")
     L = len(ids)
     P = _width_bucket(L)
     cap = _prime_bucket_cap(net)
     if cap is not None and P > cap:
         if cap < L:            # no padded bucket can hold this prompt
-            return _prime(net, ids, vocab, chunk_max, io)
+            return _prime(net, ids, vocab, chunk_max, io, last_only)
         P = cap                # pad exactly to capacity: still one shape
     pad = P - L
     x = _encode(net, np.asarray([0] * pad + list(ids))[None, :], vocab)
     if x.ndim == 3:
         x[:, :, :pad] = 0.0   # pads carry no token (masked anyway)
-    return _forward(net, x, io, pad_left=pad)
+    return _forward(net, x, io, pad_left=pad, last_only=last_only)
 
 
 def prime_prompt(net, ids, vocab_size: int, padded: bool = False,
@@ -463,15 +471,20 @@ def prime_prompt(net, ids, vocab_size: int, padded: bool = False,
     state and return the next-token distribution [V]. `padded=True`
     primes in ONE left-padded bucketed dispatch (_prime_padded);
     otherwise chunked priming (_prime) — exactness is identical, pinned
-    by the padded-prime tests. Does NOT clear previous state: the
+    by the padded-prime tests. A prime reads the last position of what
+    it fed and nothing else, and says so to every streaming call it
+    makes (``rnn_time_step(last_only=True)``): the head answers for, and
+    the host fetches, ``[1, V]`` — at a vocabulary of 49,152 a 4,096
+    bucket's full float32 answer would be 805 MB to read 197 KB of. Does
+    NOT clear previous state: the
     caller owns the stream lifecycle (sample_stream clears first; the
     serving engine primes into a fresh state it then joins to its slot
     arena). `io` (``RoundTrip``) sees the prime as input (padding; the
     one-hot only for a net that takes no ids), then forward (upload and
     launch; chunked priming alternates the two per chunk), then one fetch
     (the result coming back), which is left for the caller to end."""
-    out = (_prime_padded(net, ids, vocab_size, chunk_max, io) if padded
-           else _prime(net, ids, vocab_size, chunk_max, io))
+    out = (_prime_padded if padded else _prime)(
+        net, ids, vocab_size, chunk_max, io, last_only=True)
     io.step("fetch")
     return _last(_probs(out, io))[0]
 

@@ -165,7 +165,7 @@ class TestPaddedPrimeServing:
 
     def _padded_traces(self, net):
         from deeplearning4j_tpu.nn.conf import layers as L
-        fn = net._jit_cache.get(("rnn_step", True, False,
+        fn = net._jit_cache.get(("rnn_step", True, False, True,
                                  net.conf.dtype,
                                  L._STREAM_CACHE_SHARDING,
                                  net._paged_reads()))

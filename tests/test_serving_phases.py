@@ -266,9 +266,12 @@ class TestScriptedRun:
             # ids come down and the [S, V, 1] block stays put
             "decode": {"h2d_bytes": cycles * 2 * i32,
                        "d2h_bytes": cycles * 2 * i32},
-            # [1, P] int32 up and [1, V, P] float32 down per prime
+            # [1, P] int32 up and, since a prime asks for its last
+            # position only, [1, V] float32 down per prime: three primes,
+            # one position each ([1, V, P] was (8 + 16 + 2) positions)
             "prefill": {"h2d_bytes": (8 + 16 + 2) * i32,
-                        "d2h_bytes": (8 + 16 + 2) * V * f32}}
+                        "d2h_bytes": 3 * V * f32,
+                        "results": 3, "result_positions": 3}}
 
     def test_the_registry_reads_the_same_counts_at_scrape_time(self, net):
         reg = MetricsRegistry()
@@ -318,7 +321,9 @@ class TestOtherPaths:
         assert h["prefill"]["fed_tokens"] == \
             h["prefill"]["bucket_tokens"] == 5
         assert h["host_io"]["prefill"] == {"h2d_bytes": 5 * 4,
-                                           "d2h_bytes": 1 * V * 4}
+                                           "d2h_bytes": 1 * V * 4,
+                                           "results": 1,
+                                           "result_positions": 1}
 
     def test_a_speculative_engine_runs_the_same_phases(self, net):
         eng = GenerationEngine(

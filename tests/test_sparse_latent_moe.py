@@ -383,7 +383,8 @@ def test_prefill_then_paged_decode_through_submit_follows_the_reference(
     assert health["prefill"]["fed_tokens"] == fed
     assert health["host_io"]["prefill"] == {
         "h2d_bytes": 4 * health["prefill"]["bucket_tokens"],
-        "d2h_bytes": 4 * CFG["vocab_size"] * len(prompts)}
+        "d2h_bytes": 4 * CFG["vocab_size"] * len(prompts),
+        "results": len(prompts), "result_positions": len(prompts)}
     cycles = health["decode_dispatch"]["count"]
     assert health["host_io"]["decode"] == {"h2d_bytes": 4 * 3 * cycles,
                                            "d2h_bytes": 4 * 3 * cycles}

@@ -908,11 +908,14 @@ class TestBucketedDecoding:
 
     def _stream_traces(self, net):
         from deeplearning4j_tpu.nn.conf import layers as L
-        fn = net._jit_cache.get(
-            ("rnn_step", False, False, net.conf.dtype,
+        # a prime asks for its last position only (last_only: its own
+        # program a shape); a decode step and a beam's prime do not
+        fns = [net._jit_cache.get(
+            ("rnn_step", False, False, last_only, net.conf.dtype,
              L._STREAM_CACHE_SHARDING, net._paged_reads()))
-        assert fn is not None, "rnn_step jit key drifted from the tests"
-        return fn._cache_size()
+            for last_only in (False, True)]
+        assert fns[0] is not None, "rnn_step jit key drifted from the tests"
+        return sum(fn._cache_size() for fn in fns if fn is not None)
 
     def test_prime_chunks(self):
         from deeplearning4j_tpu.util.decoding import _prime_chunks
