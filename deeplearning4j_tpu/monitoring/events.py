@@ -64,9 +64,11 @@ DEFAULT_CAPACITY = 2048
 #: ``resilience`` (remesh/checkpoint_save/checkpoint_commit/rollback/
 #: restart/preemption/divergence), ``flight`` (recorder dumps),
 #: ``transport`` (cross-process fleet mailbox/journal: admit/revoke/
-#: duplicate/quarantine/nack/replace — serving/fleet/transport.py).
+#: duplicate/quarantine/nack/replace — serving/fleet/transport.py),
+#: ``compile`` (the compile ledger: one ``build`` a program jax built —
+#: monitoring/runtime.py).
 KNOWN_CATEGORIES = ("serving", "fleet", "resilience", "flight",
-                    "transport")
+                    "transport", "compile")
 
 
 class Event:

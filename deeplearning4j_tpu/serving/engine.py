@@ -721,6 +721,10 @@ class GenerationEngine:
         self._own_events = deque(maxlen=10)
         self._stop = threading.Event()
         self._worker: Optional[threading.Thread] = None
+        #: ``time.perf_counter()`` when start() first started the loop
+        #: thread: the end of set-up, on the clock of the request traces
+        #: and of the compile ledger (health()["setup"])
+        self._started_at: Optional[float] = None
         self._broken: Optional[BaseException] = None
         # ONE lock serializes every arena/net touch: step() may run from
         # the background loop while warmup/manual drivers call in
@@ -910,6 +914,7 @@ class GenerationEngine:
                "queue_depth": self.queue_depth(),
                "active_slots": self.active_slots(),
                "slots": self.slots,
+               "setup": {"started_at": self._started_at},
                "decode_dispatch": {
                    "count": self._dispatches,
                    "mean_ms": round(
@@ -2728,6 +2733,8 @@ class GenerationEngine:
             return self
         self._worker = threading.Thread(target=self._engine_loop,
                                         daemon=True)
+        if self._started_at is None:
+            self._started_at = time.perf_counter()
         self._worker.start()
         return self
 

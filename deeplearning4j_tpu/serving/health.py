@@ -84,6 +84,14 @@ SERVING_HOST_IO_BYTES = "dl4jtpu_serving_host_io_bytes_total"
 SERVING_SAMPLE_ROWS = "dl4jtpu_serving_sample_rows_total"
 SERVING_BLOCK_FETCHES = "dl4jtpu_serving_block_fetches_total"
 
+#: ``engine.health()["setup"]`` (every engine; no registry series):
+#: ``started_at``, the ``time.perf_counter()`` reading taken when
+#: ``start()`` first started the loop thread (``None`` before that; a
+#: later ``start()`` leaves it). It is the clock of the request traces and
+#: of the compile ledger (monitoring/runtime.py), so what a deployment's
+#: set-up did before it and the first requests it serves after it line up
+#: on one axis. A plain attribute read: no lock, no device call.
+#:
 #: ``engine.health()`` keys that are no registry series (read where the
 #: payload is read; a net without such layers has none of them). The
 #: layers DECLARE them (``nn.conf.layers.StreamCounters``: the key of
