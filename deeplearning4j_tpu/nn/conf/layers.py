@@ -1077,6 +1077,17 @@ _LEAF_LANES = 128
 _KEY_SPAN = 4096
 
 
+#: the widest table, in multiples of ``index_topk``, whose paged decode
+#: reads every slot under the selection's mask (``"masked"``); a wider
+#: one sorts its scores and gathers the kept positions (``"gathered"``).
+#: The masked read grows with the table, the gathered one past its sort
+#: does not. A layer alone on a v5e (16 rows, 32 heads on 4 KV heads of
+#: 128, topk 2,048), masked against gathered: 2.16 / 4.25 ms at 12,544
+#: slots (6.1 x topk), 5.05 / 6.24 at 25,088 (12.25 x), 11.89 / 10.05 at
+#: 50,176 (24.5 x), 22.96 / 18.66 at 100,352 (49 x): they cross near 17 x
+_MASKED_READ_RATIO = 16
+
+
 def _paged_gather(pool, page, off):
     """Tokens out of a [P, Hkv, page_size, D] pool leaf: ``[..., Hkv, D]``
     for ``page`` / ``off`` [...], the token at ``pool[page, :, off]`` —
@@ -1087,6 +1098,29 @@ def _paged_gather(pool, page, off):
     step, seen in a compile for a described v5e.)"""
     rows_of, row = _paged_rows(pool, page, off)
     return rows_of[row]
+
+
+def _attend_spans(score, value, width):
+    """Attention over ``width`` key slots in spans of ``_KEY_SPAN`` joined
+    by the running maximum: ``score(lo, hi)`` gives a span's masked
+    float32 scores [..., hi - lo], ``value(e, lo, hi)`` the product of
+    their exponentials with the span's values. A span whose slots are all
+    masked weighs exp(MASKED - max) = 0 once a later or earlier span holds
+    a kept slot."""
+    o = m = z = None
+    for lo in range(0, width, _KEY_SPAN):
+        hi = min(width, lo + _KEY_SPAN)
+        s = score(lo, hi)
+        top = jnp.max(s, axis=-1, keepdims=True)
+        e = jnp.exp(s - top)
+        part, total = value(e, lo, hi), jnp.sum(e, axis=-1, keepdims=True)
+        if o is None:
+            o, m, z = part, top, total
+        else:
+            new = jnp.maximum(m, top)
+            was, now = jnp.exp(m - new), jnp.exp(top - new)
+            o, z, m = o * was + part * now, z * was + total * now, new
+    return o / z
 
 
 @register_layer
@@ -1151,11 +1185,14 @@ class SelfAttentionLayer(FeedForwardLayerConf):
     ``stream_query_block``, a block's index scores against the key slots,
     the exact top-k as a mask, grouped-query attention under it; a
     stream's first chunk attends its own keys, slot for query, in up to
-    four causal groups. GATHERED (paged decode, a page table in the
-    state): the index keys of a row's whole context are read through the
-    table and scored, and only the selected positions' keys and values are
-    gathered out of the pool and attended. Products take operands as they
-    come and accumulate in float32; index scores and softmax are float32.
+    four causal groups; behind a page table (paged decode) the same
+    selection and attention over the table's whole mapped view, its
+    pages read whole. GATHERED (paged decode, where ``selected_read``
+    says so: a table wider than ``_MASKED_READ_RATIO`` x
+    ``index_topk``): the index keys of a row's whole context are read
+    through the table and scored, and only the selected positions' keys
+    and values are gathered out of the pool and attended. Products take
+    operands as they come and accumulate in float32; index scores and softmax are float32.
     Either streaming form adds to ``attn_stats`` the positions whose
     attention scores it computed (``stream_counters()``).
     """
@@ -1242,13 +1279,27 @@ class SelfAttentionLayer(FeedForwardLayerConf):
             return None
         return _selection_counters(self)
 
+    @property
+    def selected_read(self) -> Optional[str]:
+        """How a paged decode reads the positions the indexer kept
+        (None without an indexer): ``"masked"``, the whole mapped view
+        under the selection's mask, where the table is at most
+        ``_MASKED_READ_RATIO`` times ``index_topk``; else
+        ``"gathered"``, the kept positions alone."""
+        if not self.selects:
+            return None
+        return "masked" if self.cache_length <= \
+            _MASKED_READ_RATIO * self.index_topk else "gathered"
+
     def paged_read_tokens(self) -> Dict[str, int]:
         """Tokens of each leaf one row's paged decode reads where the
-        layer selects: the whole context's index keys, the selected
-        positions' keys and values."""
+        layer selects: the whole context's index keys; the whole
+        context's keys and values too where it reads them masked, the
+        selected positions' where it gathers them."""
         if not self.selects:
             return {}
-        top = min(self.index_topk, self.cache_length)
+        top = self.cache_length if self.selected_read == "masked" else \
+            min(self.index_topk, self.cache_length)
         return {"kv_k": top, "kv_v": top, "kv_i": self.cache_length}
 
     def init(self, key, it):
@@ -1458,27 +1509,17 @@ class SelfAttentionLayer(FeedForwardLayerConf):
                 sel = _sl.top_k_mask(scores, valid, self.index_topk)
             with jax.named_scope("gqa.attend"):
                 qg = qb.reshape(n, hkv, reps, b, d)
-                o = m = z = None
-                for lo in range(0, seen, _KEY_SPAN):
-                    hi = min(seen, lo + _KEY_SPAN)
+
+                def score(lo, hi):
                     s = jnp.einsum("ngrtd,ngld->ngrtl", qg, kc[:, :, lo:hi],
                                    preferred_element_type=jnp.float32)
-                    s = jnp.where(sel[:, None, None, :, lo:hi],
-                                  s * d ** -0.5, _sl.MASKED)
-                    top = jnp.max(s, axis=-1, keepdims=True)
-                    e = jnp.exp(s - top)
-                    part = jnp.einsum("ngrtl,ngld->ngrtd",
-                                      e.astype(vc.dtype), vc[:, :, lo:hi],
-                                      preferred_element_type=jnp.float32)
-                    total = jnp.sum(e, axis=-1, keepdims=True)
-                    if o is None:
-                        o, m, z = part, top, total
-                    else:
-                        new = jnp.maximum(m, top)
-                        was, now = jnp.exp(m - new), jnp.exp(top - new)
-                        o, z, m = (o * was + part * now,
-                                   z * was + total * now, new)
-                return (o / z).reshape(n, h, b, d).astype(q.dtype)
+                    return jnp.where(sel[:, None, None, :, lo:hi],
+                                     s * d ** -0.5, _sl.MASKED)
+
+                o = _attend_spans(score, lambda e, lo, hi: jnp.einsum(
+                    "ngrtl,ngld->ngrtd", e.astype(vc.dtype), vc[:, :, lo:hi],
+                    preferred_element_type=jnp.float32), seen)
+                return o.reshape(n, h, b, d).astype(q.dtype)
 
         parts = (blocks(q, 2), blocks(qi, 1), blocks(w, 1),
                  blocks(q_pos, 1))
@@ -1528,6 +1569,54 @@ class SelfAttentionLayer(FeedForwardLayerConf):
             o = jnp.einsum("ngrtk,ntkgd->ngrtd", a, vg,
                            preferred_element_type=jnp.float32)
         return o.reshape(n, h, t, d).astype(q.dtype), n * t * top
+
+    def _attend_paged_masked(self, q, kp, vp, ip, table, idx, q_pos):
+        """The masked form behind a page table: ``_attend_gathered``'s
+        arguments and result, the selection a mask (``top_k_mask``) over
+        the table's whole mapped view and no position gathered. Keys and
+        values are read a page at a time as the pool holds it, all heads
+        of a page in one slice, and taken as rows of the view
+        [N, n_blk·Hkv·page_size, D]: every query head is scored against
+        every row, the rows of the other heads masked with the unselected
+        positions, in spans of ``_KEY_SPAN`` rows joined by the running
+        maximum (``_attend_spans``, the prime's join). Scoring four heads'
+        rows where one is kept costs less than laying the view out head
+        by head: alone on a v5e at the longdocs cell's shapes a page
+        gather takes 0.64 ms a pool this way, 0.92 a page of one head at
+        a time (four times the slices), and 0.64 plus a 0.61 copy with
+        the heads moved forward after it."""
+        qi, w = idx
+        n, h, t, d = q.shape
+        hkv, ps = kp.shape[1], kp.shape[2]
+        n_blk, L = table.shape[1], self.cache_length
+        rows = n_blk * hkv * ps
+        with jax.named_scope("gqa.index"):
+            ki = ip[table].reshape(n, n_blk * ps, -1)[:, :L, :qi.shape[-1]]
+            scores = _sl.index_scores(qi, ki, w)                 # [N,T,L]
+        with jax.named_scope("gqa.select"):
+            live = jnp.arange(L)[None, None, :] <= q_pos[..., None]
+            sel = jnp.pad(_sl.top_k_mask(scores, live, self.index_topk),
+                          ((0, 0), (0, 0), (0, n_blk * ps - L)))
+            # a row of the view is (page, head, offset)
+            sel = jnp.broadcast_to(
+                sel.reshape(n, t, n_blk, 1, ps),
+                (n, t, n_blk, hkv, ps)).reshape(n, 1, t, rows)
+            own = (jnp.arange(rows) // ps % hkv)[None, :] == \
+                (jnp.arange(h) // (h // hkv))[:, None]           # [H, R]
+        with jax.named_scope("gqa.gather"):
+            kr = kp[table].reshape(n, rows, d)
+            vr = vp[table].reshape(n, rows, d)
+        with jax.named_scope("gqa.attend"):
+            def score(lo, hi):
+                s = jnp.einsum("nhtd,nrd->nhtr", q, kr[:, lo:hi],
+                               preferred_element_type=jnp.float32)
+                ok = sel[..., lo:hi] & own[None, :, None, lo:hi]
+                return jnp.where(ok, s * d ** -0.5, _sl.MASKED)
+
+            o = _attend_spans(score, lambda e, lo, hi: jnp.einsum(
+                "nhtr,nrd->nhtd", e.astype(vr.dtype), vr[:, lo:hi],
+                preferred_element_type=jnp.float32), rows)
+        return o.astype(q.dtype), n * t * L
 
     def _stream_attend(self, q, k, v, state, mask=None, pad_left=None,
                        idx=None):
@@ -1834,14 +1923,16 @@ class SelfAttentionLayer(FeedForwardLayerConf):
             vp = _paged_append(vp, page, off, vt.astype(vp.dtype))
         impl, interpret = self.paged_read
         if self.selects:
-            # the gathered form, whatever ``paged_read`` says: the kernel
-            # walks whole live pages and cannot skip tokens
+            # one of the two forms of ``selected_read``, whatever
+            # ``paged_read`` says: the kernel cannot read the index leaf
             qi, ki, w = idx
             ip = _paged_append(
                 state["kv_page_i"], page, off,
                 self._index_leaf(self._rope_index(ki, q_pos))[
                     :, :, None].astype(state["kv_page_i"].dtype))
-            o, scored = self._attend_gathered(
+            attend = self._attend_paged_masked \
+                if self.selected_read == "masked" else self._attend_gathered
+            o, scored = attend(
                 q, kp, vp, ip, table, (self._rope_index(qi, q_pos), w),
                 q_pos)
             return o, {**_attended(state, scored), "kv_page_k": kp,
@@ -2965,6 +3056,10 @@ class LatentAttentionLayer(FeedForwardLayerConf):
         the selected positions' latents and rotated keys."""
         top = min(self.index_topk, self.cache_length)
         return {"kv_c": top, "kv_r": top, "kv_i": self.cache_length}
+
+    #: how a paged decode reads the selected positions
+    #: (``SelfAttentionLayer.selected_read``): it gathers them
+    selected_read = "gathered"
 
     def _query_groups(self, t: int, slots: int, aligned: bool):
         """``sparse_latent.query_groups`` in blocks of ``QUERY_BLOCK``."""

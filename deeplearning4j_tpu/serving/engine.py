@@ -594,6 +594,11 @@ class GenerationEngine:
             for l in kv_layers:
                 if hasattr(l, "paged_read"):
                     l.paged_read = read
+            # how the selecting layers' decode reads what they keep (one
+            # kind of them a net, one cache_length; None: none selects)
+            self._selected_read = next(
+                (r for r in (getattr(l, "selected_read", None)
+                             for l in kv_layers) if r), None)
             if paging.prefix_cache:
                 if any(getattr(l, "carries_recurrent_state", False)
                        for l in layers):
@@ -949,6 +954,8 @@ class GenerationEngine:
                 "bytes_moved_total": self._kv_bytes_total,
                 "dispatches": self._dispatches,
             }
+            if self._selected_read is not None:
+                out["kv_traffic"]["selected_read"] = self._selected_read
         if self._prefix is not None:
             out["prefix_cache"] = {"entries": len(self._prefix),
                                    "hits": self._prefix.hits,

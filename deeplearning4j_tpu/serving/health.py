@@ -131,8 +131,10 @@ SERVING_BLOCK_FETCHES = "dl4jtpu_serving_block_fetches_total"
 #: chunk's own slots in causal groups (5/8 of width x width for a bucket
 #: of four groups; pads too), in a later chunk (after a prefix hit, or
 #: chunked priming) every cache slot of every row of its query blocks,
-#: in paged decode the gathered index_topk of all S rows. 1 - selected /
-#: attended is attention work the selection had already ruled out.
+#: in paged decode, by ``kv_traffic.selected_read``, every slot of the
+#: table (``masked``) or the gathered index_topk (``gathered``) of all S
+#: rows. 1 - selected / attended is attention work the selection had
+#: already ruled out.
 #:
 #: ``linear_attn`` — a net with linear-attention layers
 #: (``GatedDeltaNetLayer``), summed over those layers. From what they

@@ -8,9 +8,13 @@ the head axis a head-minor layout of the whole leaf and copies the leaf
 before and after it (PERF.md, PR 35: 16 copies of 613 MB a decode step
 of olmo-hybrid-7b, 120 of 23 MB of starcoder2-3b). Only a compile for
 the TPU shows that, so these cases lower the helper at the two serving
-cells' shapes for a described v5e and read the compiled HLO.
+cells' shapes for a described v5e and read the compiled HLO. The same
+holds for the selecting layer's reads of its pools: the gather of the
+kept tokens and the masked decode's view of whole pages copy no pool,
+and the view is not copied into another order of its axes.
 """
 
+import math
 import os
 import re
 
@@ -19,8 +23,8 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from deeplearning4j_tpu.nn.conf.layers import (_LEAF_LANES, _paged_append,
-                                                _paged_gather)
+from deeplearning4j_tpu.nn.conf.layers import (_LEAF_LANES, SelfAttentionLayer,
+                                                _paged_append, _paged_gather)
 
 #: (cell, pool leaf shape [P, Hkv, page_size, D]), 32 rows each; the
 #: keye configuration's keys and values, and its index key as it is kept
@@ -161,3 +165,67 @@ def test_the_selected_tokens_are_gathered_with_no_copy_of_the_pool(
     windowed = compile_gather(lambda pool, page, off: pool[page, :, off],
                               shape, one_chip)
     assert len(pool_copies(windowed, shape)) == 1
+
+
+def compile_masked_decode(sharding, form=None):
+    """The masked paged form (``_attend_paged_masked``, or ``form`` with
+    its arguments) at the keye cell's shapes: 16 rows of one query, a
+    784-page table (12,544 slots), topk 2,048, the K and V pools and the
+    index key's."""
+    layer = SelfAttentionLayer(
+        n_out=2048, n_heads=32, n_kv_heads=4, head_dim=128, rope=True,
+        has_bias=False, qk_norm="head", index_n_heads=16,
+        index_head_dim=64, index_topk=2048, cache_length=12544,
+        stream_query_block=128)
+    assert layer.selected_read == "masked"
+    form = form or (lambda *a: layer._attend_paged_masked(*a)[0])
+
+    def spec(dims, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dt, sharding=sharding)
+
+    kv, ik = POOLS[2][1], POOLS[3][1]
+    return layer, jax.jit(form).lower(
+        spec((16, 32, 1, 128)), spec(kv), spec(kv), spec(ik),
+        spec((16, 784), jnp.int32),
+        (spec((16, 1, 16, 64)), spec((16, 1, 16))),
+        spec((16, 1), jnp.int32)).compile().as_text()
+
+
+def view_moves(hlo):
+    """Copies and transposes (other than the identity) of an array of the
+    mapped K or V view's size, 16 x 784 x 4 x 16 x 128 elements, in any
+    order of its axes."""
+    moved = []
+    for dims, op, perm in re.findall(
+            r"= \w+\[([\d,]+)\](?:\{[^}]*\})? (copy|transpose)\([^)]*\)"
+            r"(?:, dimensions=\{([\d,]+)\})?", hlo):
+        shape = tuple(map(int, dims.split(",")))
+        identity = perm == ",".join(map(str, range(len(shape))))
+        if math.prod(shape) == 16 * 4 * 12544 * 128 and not identity:
+            moved.append((op, shape))
+    return moved
+
+
+def test_the_masked_decode_reads_whole_pages_with_no_copy(
+        one_chip, no_compile_cache):
+    """The masked form reads a page of all heads at a time, as the pool
+    holds it, and scores the rows of the view as they come: no
+    pool-shaped copy, and no copy or transpose of the
+    [16, 784, 4, 16, 128] view. The control: the same view with its head
+    axis moved forward for the prime's form (``_attend_selected``, keys
+    [N, Hkv, L, D]) is copied into that order once a pool."""
+    layer, hlo = compile_masked_decode(one_chip)
+    for _, shape in POOLS[2:]:
+        assert pool_copies(hlo, shape) == []
+    assert view_moves(hlo) == []
+
+    def head_major(pool, table):
+        return jnp.moveaxis(pool[table], 2, 1).reshape(16, -1, 12544, 128)
+
+    def moved(q, kp, vp, ip, table, idx, q_pos):
+        return layer._attend_selected(
+            q, head_major(kp, table), head_major(vp, table), idx,
+            head_major(ip, table), q_pos)[0]
+
+    _, control = compile_masked_decode(one_chip, moved)
+    assert view_moves(control) == [("copy", (16, 784, 4, 16, 128))] * 2

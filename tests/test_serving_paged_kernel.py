@@ -363,6 +363,8 @@ class TestChoosePagedRead:
         eng = GenerationEngine(rope_net, V, slots=2,
                                paging=PagedKVConfig(page_size=8))
         assert eng.health()["kv_traffic"]["decode_path"] == "direct-xla"
+        # no layer selects: no selected read to name
+        assert "selected_read" not in eng.health()["kv_traffic"]
         assert set(rope_net._paged_reads()) == {("xla", False)}
 
 

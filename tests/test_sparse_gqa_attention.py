@@ -27,12 +27,12 @@ Z = ref.Sizes(e=E, v=0, h=H, g=G, d=D, hi=HI, di=DI, topk=TOPK, dense=0,
               moe=0, experts=0, per_tok=0, layers=1)
 
 
-def make(topk=TOPK, block=16, **kw):
+def make(topk=TOPK, block=16, cap=CAP, **kw):
     return SelfAttentionLayer(
         n_out=E, n_heads=H, n_kv_heads=G, head_dim=D, rope=True,
         rope_base=THETA, has_bias=False, qk_norm="head",
         index_n_heads=HI, index_head_dim=DI, index_topk=topk,
-        cache_length=CAP, stream_query_block=block, **kw)
+        cache_length=cap, stream_query_block=block, **kw)
 
 
 def params_of(layer, seed=3, ties=False):
@@ -93,7 +93,9 @@ def test_the_leaves_are_the_declared_ones():
     assert decl.host([5, 20, 12]) == {
         "query_positions": 3, "context_positions": 37,
         "selected_positions": 5 + 12 + 12}
-    assert layer.paged_read_tokens() == {"kv_k": TOPK, "kv_v": TOPK,
+    # a table of 8 x topk is read whole, under the selection's mask
+    assert layer.selected_read == "masked"
+    assert layer.paged_read_tokens() == {"kv_k": CAP, "kv_v": CAP,
                                          "kv_i": CAP}
     again = layer_from_dict(json.loads(json.dumps(layer.to_dict())))
     assert again == layer and again.qk_norm == "head"
@@ -116,6 +118,7 @@ def test_with_every_new_field_at_its_default_the_layer_is_the_old_one():
     assert paged_leaves(old) == (PagedLeaf("kv_k", (G, d), 1),
                                  PagedLeaf("kv_v", (G, d), 1))
     assert stream_counters(old) is None and old.paged_read_tokens() == {}
+    assert old.selected_read is None
     same = SelfAttentionLayer(n_out=E, n_heads=H, n_kv_heads=G, rope=True,
                               qk_norm=True, cache_length=CAP, head_dim=d)
     p2, _ = same.init(jax.random.PRNGKey(1), InputType.recurrent(E, CAP))
@@ -233,10 +236,9 @@ def test_a_left_padded_prime_is_the_unpadded_prompt():
 
 @pytest.mark.parametrize("ties", [False, True], ids=["scores", "ties"])
 def test_a_prime_then_the_paged_decode_is_the_dense_stream(ties):
-    """A prime through the dense cache, then one more token (a) masked,
-    against the dense cache and (b) gathered, through a page table,
-    against the same cache scattered into pages: contexts of 40 (over
-    topk) in both rows."""
+    """A prime through the dense cache, then one more token (a) against
+    the dense cache and (b) through a page table, against the same cache
+    scattered into pages: contexts of 40 (over topk) in both rows."""
     layer = make()
     p = params_of(layer, ties=ties)
     x = x_of(41, n=2, seed=8)
@@ -258,8 +260,9 @@ def test_a_prime_then_the_paged_decode_is_the_dense_stream(ties):
     want = np.stack([reference(p, x[i])[0] for i in range(2)])
     assert np.allclose(paged[:, :, 0], want[:, :, 40], atol=2e-5)
     assert out["kv_pos"].tolist() == [41, 41]
-    # the gathered form computes the scores it keeps and no more
-    assert int(out["attn_stats"]) == 2 * TOPK
+    # the masked form (a table of 8 x topk) computes the scores of every
+    # slot of the table
+    assert int(out["attn_stats"]) == 2 * CAP
     back = gather_pages([out[l.page_key] for l in leaves], table,
                         length=CAP, axes=axes)
     for l, b in zip(leaves, back):
@@ -284,6 +287,88 @@ def test_a_context_under_topk_decodes_through_pages_too():
                         for l, pool in zip(leaves, pools)})
     paged, _ = stream(layer)(p, x[:, :, 8:], paged_state)
     assert np.allclose(paged, dense, atol=2e-5)
+
+
+def test_a_table_past_the_ratio_decodes_gathered_through_pages(monkeypatch):
+    """The prime-then-decode of above with the rule's constant under this
+    table's 8 x topk: the gathered form, the same token, and only the
+    kept positions scored."""
+    monkeypatch.setattr(L, "_MASKED_READ_RATIO", 4)
+    layer = make()
+    assert layer.selected_read == "gathered"
+    assert layer.paged_read_tokens() == {"kv_k": TOPK, "kv_v": TOPK,
+                                         "kv_i": CAP}
+    p = params_of(layer)
+    x = x_of(41, n=2, seed=8)
+    _, state = stream(layer)(p, x[:, :, :40], {})
+    dense, _ = stream(layer)(p, x[:, :, 40:], state)
+    leaves = layer.paged_leaves()
+    table = np.array([[3, 9, 1, 7, 5, 11] + [0] * 6,
+                      [2, 4, 6, 8, 10, 12] + [0] * 6], np.int32)
+    pools = scatter_pages(
+        [jnp.zeros(l.shape(14, 8), jnp.float32) for l in leaves],
+        [state[l.key] for l in leaves], table,
+        axes=tuple(l.token_axis + 1 for l in leaves))
+    paged_state = {"kv_pos": jnp.full((2,), 40, jnp.int32),
+                   "kv_page_table": jnp.asarray(table)}
+    paged_state.update({l.page_key: pool
+                        for l, pool in zip(leaves, pools)})
+    paged, out = stream(layer)(p, x[:, :, 40:], paged_state)
+    assert np.allclose(paged, dense, atol=2e-5)
+    assert int(out["attn_stats"]) == 2 * TOPK
+
+
+def paged_inputs(cap, t, seed, n=3, ps=8):
+    """Pools of random float32 keys, values and index keys behind a table
+    of distinct pages a row, one query chunk of ``t`` a row at positions
+    from 0 to the table's last slot."""
+    rng = np.random.default_rng(seed)
+    n_blk = -(-cap // ps)
+    pages = 1 + n * n_blk
+
+    def normal(*shape):
+        return jnp.asarray(rng.normal(size=shape), jnp.float32)
+
+    table = jnp.asarray(1 + rng.permutation(n * n_blk).reshape(n, n_blk),
+                        jnp.int32)
+    last = np.array([cap - t, min(5, cap - t), rng.integers(0, cap - t)])
+    q_pos = jnp.asarray(last[:, None] + np.arange(t), jnp.int32)
+    return (normal(n, H, t, D), normal(pages, G, ps, D),
+            normal(pages, G, ps, D), normal(pages, 1, ps, 128), table,
+            (normal(n, t, HI, DI), normal(n, t, HI)), q_pos)
+
+
+@pytest.mark.parametrize("cap,topk,t", [
+    (32, 48, 1), (98, 16, 1), (98, 16, 3),
+    (4 * (L._MASKED_READ_RATIO + 1), 4, 1)],
+    ids=["under_topk", "cells_ratio", "cells_ratio_chunk", "gathered_side"])
+def test_both_paged_forms_are_one_function(cap, topk, t):
+    """The masked and the gathered paged forms on the same pools, table,
+    index keys and positions: a table narrower than topk, one at the
+    longdocs cell's 6.1 x topk (a table of 104 slots for 98 positions), a
+    chunk of three queries there, and one wide enough for the rule to
+    take the gathered form. The same outputs to 1e-5; the masked form
+    scores every slot of the table, the gathered the kept ones."""
+    layer = make(topk, cap=cap)
+    args = paged_inputs(cap, t, seed=cap + topk + t)
+    masked, m_scored = layer._attend_paged_masked(*args)
+    gathered, g_scored = layer._attend_gathered(*args)
+    assert masked.shape == gathered.shape == (3, H, t, D)
+    assert np.abs(np.asarray(masked) - np.asarray(gathered)).max() < 1e-5
+    assert (m_scored, g_scored) == (3 * t * cap, 3 * t * min(topk, cap))
+
+
+def test_the_rule_picks_each_form_by_the_tables_width():
+    """``cache_length`` up to ``_MASKED_READ_RATIO`` x topk reads masked,
+    one slot more gathers; the longdocs cell's table (12,544 slots, topk
+    2,048) reads masked."""
+    ratio = L._MASKED_READ_RATIO
+    for topk in (16, 2048):
+        assert make(topk, cap=ratio * topk).selected_read == \
+            "masked"
+        assert make(topk, cap=ratio * topk + 1).selected_read == \
+            "gathered"
+    assert make(2048, cap=12544).selected_read == "masked"
 
 
 def test_what_the_selecting_layer_does_not_stream():

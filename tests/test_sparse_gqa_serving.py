@@ -1,7 +1,7 @@
 """The decoder of selecting grouped-query attention and softmax-routed
 experts (``zoo/sparse_gqa_moe.py``) at a small size on the CPU behind
 ``GenerationEngine.submit``: a prime through the dense cache, one scatter
-that seats three leaves a layer, the gathered decode through pages, against
+that seats three leaves a layer, the masked decode through pages, against
 the plain reference of the ``keye-vl-2.0-30b-a3b`` configuration and the
 one-shot ``sample_stream``; what the engine answers for this net's int8,
 kernel and speculation requests."""
@@ -148,6 +148,8 @@ def test_the_decode_path_and_the_counters(served):
     # the kernel walks whole pages and cannot skip tokens: xla, though
     # ``auto`` was asked
     assert health["kv_traffic"]["decode_path"] == "direct-xla"
+    # a table of CAP = 8 x topk slots is read whole, under the mask
+    assert health["kv_traffic"]["selected_read"] == "masked"
     assert health["prefix_cache"]["hits"] == 1
     assert health["prefix_cache"]["reused_tokens"] == 32
     fed = sum(len(p) for p in prompts) - 32
@@ -169,9 +171,9 @@ def test_the_decode_path_and_the_counters(served):
     assert sparse["selected_positions"] < sparse["context_positions"]
     # four fresh primes in buckets of 64, 64, 64 and 128 (one block of
     # queries each: its own slots), the prefix hit's 32 rows against the
-    # whole cache, and the gathered 16 of each of 3 rows a cycle
+    # whole cache, and every slot of the table for each of 3 rows a cycle
     assert sparse["attended_positions"] == 3 * (
-        3 * 64 * 64 + 128 * 128 + 32 * CAP + 16 * 3 * cycles)
+        3 * 64 * 64 + 128 * 128 + 32 * CAP + CAP * 3 * cycles)
 
 
 def test_what_the_engine_answers_for_int8_the_kernel_and_speculation(model):

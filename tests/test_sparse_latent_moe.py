@@ -378,6 +378,8 @@ def test_prefill_then_paged_decode_through_submit_follows_the_reference(
     assert health["prefix_cache"]["hits"] == 1
     assert health["prefix_cache"]["reused_tokens"] == 32
     assert health["kv_traffic"]["decode_path"] == "direct-xla"
+    # the latent layer's paged decode gathers what it keeps
+    assert health["kv_traffic"]["selected_read"] == "gathered"
     # ids in: 4 bytes a token up, the last position's row back
     fed = sum(len(p) for p in prompts) - 32
     assert health["prefill"]["fed_tokens"] == fed
