@@ -14,9 +14,11 @@ What a served model brings (two files and a configuration, found by the
 configuration's ``model``; nothing here knows a model's name):
 
 - ``models/<model>.py``: ``build_shell(cfg, max_length) -> (net, shapes)``,
-  a ``ComputationGraph`` that takes one-hot ``[N, V, T]`` and streams
-  through ``rnn_time_step``, initialised without drawing a weight, and the
-  shapes of its parameter tree (vertex -> leaf -> shape);
+  a ``ComputationGraph`` that streams through ``rnn_time_step``, fed by the
+  engine as the net asks (token ids ``[N, T]`` where a layer of it takes
+  ids, as every zoo transformer's does; else one-hot ``[N, V, T]``),
+  initialised without drawing a weight, and the shapes of its parameter
+  tree (vertex -> leaf -> shape);
 - ``reference/<model>.py``: ``param_specs(cfg)``, the leaves as ``(name,
   shape, mean, std)`` with ``name`` = ``<vertex>/<leaf>`` of that tree, and
   ``logits_at(cfg, params, ids, positions, low=False)``, the plain forward
@@ -25,12 +27,13 @@ configuration's ``model``; nothing here knows a model's name):
 - the configuration's keys ``vocab_size`` (the slice of the vocabulary
   held here: ``prompt_ids`` draws from it and the logits are over it),
   ``departures.served_max_context`` (no context of the table may pass
-  it) and ``engine.{slots, page_size, total_pages, kv_dtype, decode_impl,
-  prefix_cache, queue_limit}``. ``decode_impl`` also says which path
-  ``health()["kv_traffic"]["decode_path"]`` has to read after the window:
-  ``direct-xla`` for ``xla`` (a decoder whose cache the grouped-query
-  Mosaic kernel cannot read says that), ``direct-pallas`` for ``auto`` and
-  ``pallas``; a run that fell back to another path is not correct.
+  it; see Windows, below) and ``engine.{slots, page_size, total_pages,
+  kv_dtype, decode_impl, prefix_cache, queue_limit}``. ``decode_impl``
+  also says which path ``health()["kv_traffic"]["decode_path"]`` has to
+  read after the window: ``direct-xla`` for ``xla`` (a decoder whose cache
+  the grouped-query Mosaic kernel cannot read says that),
+  ``direct-pallas`` for ``auto`` and ``pallas``; a run that fell back to
+  another path is not correct.
 
 What a mix of this kind holds (``traffic/<traffic>.json``; the rules are
 ``tests/benchmark/test_benchmark_replay.py``'s, over every such cell):
@@ -46,6 +49,16 @@ wait for the first token of every request sent in the window;
 ``finished``) and ``checked_requests`` (how many finished requests the
 reference follows, beside the longest). ``dry_run`` may hold a smaller
 table for a test run.
+
+Windows. A configuration holds its source's ``sliding_window`` as the
+source gives it (a null is no window). Where the program serves the
+windowed layers as full causal attention, the file says so under
+``departures.sliding_window``, and then ``served_max_context`` may not
+pass the window: up to it the two are one function. A file without that
+departure says the windowed layers run as windows; its contexts may pass
+the window, its reference computes the windows, and the cell's
+``correct`` holds the program to them: that is the model's to meet, not
+this runner's.
 """
 
 from __future__ import annotations

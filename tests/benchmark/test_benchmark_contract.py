@@ -381,6 +381,12 @@ DUMMY_DECODER_CONFIG = {
                "kv_dtype": "bf16", "decode_impl": "xla",
                "prefix_cache": True, "queue_limit": 8}}
 
+#: the same decoder as a later PR would bring a model whose windowed layers
+#: run as windows: the source's window (16) under a served context of 64
+#: and no departure that says the windows are served as full attention.
+#: Its rules are run here, not its program
+DUMMY_WINDOWED_CONFIG = dict(DUMMY_DECODER_CONFIG, sliding_window=16)
+
 #: its mix, as ``runners/serve_closed_replay.py`` states a mix of that
 #: kind: the parameters, and the table ``traffic/draw_table.py`` draws
 #: from them (three clients, prompts in three prefill buckets; long enough
@@ -487,11 +493,26 @@ def _copy_with_dummies(tmp_path):
         "traffic": "serve_dummy", "chips": 1, "why": "test"})
     e2e["serve_out_tokens_per_s"]["workloads"].append(
         "dummydec.serve_dummy")
+    # the windowed decoder: a configuration and limits of its own; the
+    # model, its reference, the mix and the metric are the cut decoder's
+    (extra / "configs" / "dummywin.json").write_text(
+        json.dumps(DUMMY_WINDOWED_CONFIG))
+    (extra / "limits" / "dummywin.serve_dummy.json").write_text(json.dumps(
+        {"limits": {"served_token_gap_max": 0.07}}))
+    bench["configs"].append({
+        "name": "dummywin", "source": "nowhere",
+        "file": "extra_bench/configs/dummywin.json",
+        "reduced": DUMMY_WINDOWED_CONFIG["reduced"], "why": "test"})
+    bench["workloads"].append({
+        "name": "dummywin.serve_dummy", "config": "dummywin",
+        "traffic": "serve_dummy", "chips": 1, "why": "test"})
+    e2e["serve_out_tokens_per_s"]["workloads"].append(
+        "dummywin.serve_dummy")
     bench["per_layer"].append({
         "name": "dummydec.rows_per_dispatch", "unit": "rows",
         "better": "higher", "source": "program_counter",
         "layer": "serving engine", "moves": "serve_out_tokens_per_s",
-        "workloads": ["dummydec.serve_dummy"]})
+        "workloads": ["dummydec.serve_dummy", "dummywin.serve_dummy"]})
     (root / "BENCHMARK.json").write_text(json.dumps(bench))
     return str(root), bench
 
@@ -640,16 +661,20 @@ def test_a_run_on_another_decode_path_than_its_decode_impl_is_not_correct(
     assert harness.result(cell, record, [_Dev()])["correct"] is False
 
 
+#: a value of ``_write_config``'s ``changes`` that writes JSON's null
+NULL = object()
+
+
 def _write_config(root, bench, name, tag, changes):
     """The entry ``name`` again, for a changed copy of its file (``None``
-    drops a key)."""
+    drops a key, ``NULL`` writes a null)."""
     entry = next(c for c in bench["configs"] if c["name"] == name)
     data = harness._load_json(os.path.join(root, entry["file"]))
     for k, v in changes.items():
         if v is None:
             data.pop(k, None)
         else:
-            data[k] = v
+            data[k] = None if v is NULL else v
     path = entry["file"].replace(".json", f".{tag}.json")
     with open(os.path.join(root, path), "w") as f:
         json.dump(data, f)
@@ -697,21 +722,67 @@ def test_a_cut_configuration_says_what_it_cut(dummies, monkeypatch, case,
             test_configuration_files(entry)
 
 
+#: the departure that says a model's windowed layers are served as full
+#: causal attention, beside the served context of the dummy decoder (64)
+AS_FULL = {"served_max_context": 64,
+           "sliding_window": "served as full causal attention"}
+
+WINDOW_CASES = [
+    ("a window under the served context served as windows",
+     {"sliding_window": 16}, True),
+    ("that window served as full attention",
+     {"sliding_window": 16, "departures": AS_FULL}, False),
+    ("a null window", {"sliding_window": NULL}, True),
+    ("a null window beside the departure",
+     {"sliding_window": NULL, "departures": AS_FULL}, True),
+    ("full attention as far as the window",
+     {"sliding_window": 64, "departures": AS_FULL}, True),
+    ("full attention inside the window",
+     {"sliding_window": 128, "departures": AS_FULL}, True),
+]
+
+
+@pytest.mark.parametrize("case,changes,ok", WINDOW_CASES,
+                         ids=[c[0].replace(" ", "_") for c in WINDOW_CASES])
+def test_a_window_caps_the_served_context_only_where_served_as_full_attention(
+        dummies, monkeypatch, case, changes, ok):
+    """The replay rule on a copy of the cut decoder's configuration: a
+    numeric ``sliding_window`` holds ``served_max_context`` to it only
+    where ``departures`` says the windows are served as full causal
+    attention; a null is no window."""
+    import test_benchmark_replay
+    root, bench = dummies
+    bench = json.loads(json.dumps(bench))
+    entry = _write_config(root, bench, "dummydec",
+                          "window_" + case.replace(" ", "_"), changes)
+    bench["configs"] = [entry if c["name"] == "dummydec" else c
+                        for c in bench["configs"]]
+    monkeypatch.setattr(test_benchmark_replay, "ROOT", root)
+    monkeypatch.setattr(test_benchmark_replay, "BENCH", bench)
+    rule = test_benchmark_replay.\
+        test_the_schedule_is_data_and_stays_under_the_served_context
+    if ok:
+        rule("dummydec.serve_dummy")
+    else:
+        with pytest.raises(AssertionError):
+            rule("dummydec.serve_dummy")
+
+
 def test_a_later_prs_entries_pass_every_rule_of_this_directory(
         dummies, monkeypatch):
     """A later PR's configurations, cells and per-layer entries (a cut
-    decoder's among them), each with files of its own, against every test
-    of this directory that goes over the entries of ``BENCHMARK.json``
-    (the others name the accepted cells they are about): the rules of this
-    file, of a ``serve_closed_replay`` mix (``test_benchmark_replay.py``)
-    and of the two reader tables. None needs an edit to a file that is
-    there."""
+    decoder's and a windowed one's among them), each with files of its
+    own, against every test of this directory that goes over the entries
+    of ``BENCHMARK.json`` (the others name the accepted cells they are
+    about): the rules of this file, of a ``serve_closed_replay`` mix
+    (``test_benchmark_replay.py``) and of the two reader tables. None
+    needs an edit to a file that is there."""
     import test_benchmark_replay
     import test_benchmark_spans
     import test_benchmark_xplane
     root, bench = dummies
     assert len(bench["per_layer"]) == len(BENCH["per_layer"]) + 2
-    assert len(bench["workloads"]) == len(BENCH["workloads"]) + 3
+    assert len(bench["workloads"]) == len(BENCH["workloads"]) + 4
     me = sys.modules[__name__]
     for module in (me, test_benchmark_replay):
         monkeypatch.setattr(module, "ROOT", root)
@@ -731,7 +802,13 @@ def test_a_later_prs_entries_pass_every_rule_of_this_directory(
     test_command_names_no_file_outside_paths()
     serving = test_benchmark_replay.serving_cells(bench, root)
     assert serving == test_benchmark_replay.SERVING + [
-        "dummydec.serve_dummy"]
+        "dummydec.serve_dummy", "dummywin.serve_dummy"]
+    windowed = harness.Cell(bench, "dummywin.serve_dummy", root=root)
+    assert windowed.config["sliding_window"] < \
+        windowed.config["departures"]["served_max_context"]
+    assert windowed.config["sliding_window"] < \
+        windowed.traffic["drawn"]["max_context"]
+    assert "sliding_window" not in windowed.config["departures"]
     for name in serving:
         test_benchmark_replay.\
             test_the_schedule_is_data_and_stays_under_the_served_context(

@@ -18,8 +18,7 @@ CELL = "keye-vl-2.0-30b-a3b.longdocs_closed16"
 BENCH = harness.load_benchmark()
 
 #: huggingface.co/Kwai-Keye/Keye-VL-2.0-30B-A3B config.json, every key of
-#: the catalog row's ``config``, empty lists and groups included, but
-#: ``"sliding_window": null`` (``NULL_KEYS``, below)
+#: the catalog row's ``config``, empty lists, groups and its null included
 SOURCE = {
     "attention_bias": False, "decoder_sparse_step": 1, "head_dim": 128,
     "hidden_act": "silu", "hidden_size": 2048, "intermediate_size": 6144,
@@ -36,15 +35,13 @@ SOURCE = {
     "sa_config": {"indexer_head_dim": 64, "indexer_num_heads": 16,
                   "indexer_num_kv_heads": 1, "kv_chunk_size": 512,
                   "q_chunk_size": 512, "topk": 2048},
-    "tie_word_embeddings": False,
+    "sliding_window": None, "tie_word_embeddings": False,
     "use_sliding_window": False, "vocab_size": 151936}
 
-#: the source's one null. ``test_benchmark_replay.py`` reads any
-#: ``sliding_window`` key of a served configuration as a window's width
-#: and raises on ``None``, so the key cannot be in the file until a
-#: ``benchmark`` PR makes that rule ``is not None``; the file says so
-#: under ``departures`` and holds every number of the source
-NULL_KEYS = ["sliding_window"]
+#: source keys the file may not hold, each named under ``departures``:
+#: none. The one null, ``sliding_window``, is in the file as the source
+#: gives it (a null is no window to ``test_benchmark_replay.py``)
+NULL_KEYS = []
 
 
 @pytest.fixture(scope="module")
@@ -64,6 +61,7 @@ def test_every_source_key_is_there_verbatim_but_the_one_it_cut(cell):
     assert cfg["mlp_only_layers"] == []
     for key in NULL_KEYS:
         assert key not in cfg and "null" in cfg["departures"][key]
+    assert "sliding_window" not in cfg["departures"]
     assert cfg["use_sliding_window"] is False
     assert cfg["sa_config"] == SOURCE["sa_config"]
     assert cfg["rope_scaling"] == SOURCE["rope_scaling"]
@@ -187,8 +185,10 @@ READERS = ["longdocs.device_idle_share", "longdocs_step.mfu",
 
 
 def test_the_cells_entries_list_it():
+    """The cell's own entries, exactly; the set-up layer's readers,
+    which every cell may list, are ``test_benchmark_setup_readers.py``'s."""
     mine = {m["name"]: m for m in BENCH["per_layer"]
-            if CELL in m.get("workloads", [])}
+            if CELL in m.get("workloads", []) and m["layer"] != "set-up"}
     assert set(READERS) == set(mine) and len(READERS) == 11
     for name in READERS:
         assert mine[name]["workloads"] == [CELL]

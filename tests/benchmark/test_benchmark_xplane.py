@@ -292,23 +292,27 @@ def test_every_serving_metric_of_the_benchmark_has_that_test():
     configuration added later, or later cells of this one, is outside
     these tables by the cells it lists, whatever its name begins with. It
     lists only its own cells, and it brings its reader file and a
-    hand-count test of that reader in a new file of this directory."""
+    hand-count test of that reader in a new file of this directory. The
+    ``set-up`` layer's entries are outside them too: their readers hold
+    for any cell and are pinned, cells and all, in
+    ``test_benchmark_setup_readers.py``."""
     from benchmark import harness
     from test_benchmark_spans import BY_HAND, CHAT, COMPLETE
     assert (CHAT, COMPLETE) == (_CHAT, _COMPLETE)
     bench = harness.load_benchmark()
     entries = {m["name"]: m.get("workloads") for m in bench["per_layer"]
-               if "workloads" not in m
-               or {CHAT, COMPLETE} & set(m["workloads"])}
+               if m["layer"] != "set-up" and (
+                   "workloads" not in m
+                   or {CHAT, COMPLETE} & set(m["workloads"]))}
     spans = {metric: cell for cell, metric in BY_HAND}
     assert not set(SERVING_READERS) & set(spans)
     assert entries == {metric: [cell] for metric, cell in
                        {**SERVING_READERS, **spans}.items()}
 
 
-def _unpinned_entry(bench, cell):
-    bench["per_layer"].append(dict(bench["per_layer"][-1],
-                                   name="engine.unpinned",
+def _unpinned_entry(bench, cell, layer="serving engine"):
+    like = next(m for m in bench["per_layer"] if m["layer"] == layer)
+    bench["per_layer"].append(dict(like, name="engine.unpinned",
                                    workloads=[cell]))
 
 
@@ -335,9 +339,12 @@ def _later_cell_on_an_accepted_entry(bench, cell):
     (_later_cell_on_an_accepted_entry, False),
     # the same entry on a cell that came later is nobody's but its own
     (lambda bench, cell: _unpinned_entry(bench, "later.cell"), True),
+    # and the set-up layer's are test_benchmark_setup_readers.py's
+    (lambda bench, cell: _unpinned_entry(bench, cell, "set-up"), True),
 ], ids=["unpinned_entry", "pinned_name_without_its_entry",
         "span_reader_on_the_other_cell",
-        "later_cell_on_an_accepted_entry", "entry_of_a_later_cell"])
+        "later_cell_on_an_accepted_entry", "entry_of_a_later_cell",
+        "set_up_entry_on_the_cell"])
 def test_that_guard_holds_the_tables_and_the_entries_together(
         monkeypatch, change, ok):
     from benchmark import harness
